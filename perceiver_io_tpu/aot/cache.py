@@ -4,7 +4,7 @@ Tier 1 (:class:`ExecutableCache`): ``jax.experimental.serialize_executable``
 round-trips a ``Compiled`` object through bytes. Entries are keyed by a
 content *fingerprint* computed WITHOUT tracing or lowering — a warm start
 goes straight from (shapes, config) to a loaded executable, skipping the
-trace, the lower, and the remote backend compile entirely. The fingerprint
+trace, the lower, and the backend compile entirely. The fingerprint
 folds in everything that could change the compiled program:
 
 - package version + best-effort source of the traced callable (closure
@@ -22,11 +22,17 @@ which is caught, warned about, counted, and the entry deleted — then the
 normal compile runs. A cache problem can slow a cold start back to baseline;
 it can never refuse traffic or serve a wrong program.
 
-Tier 2 (:func:`enable_persistent_compilation_cache`): jax's own persistent
-compilation cache for everything that does not flow through an
-:class:`ExecutableCache` (the trainer step, ad-hoc tools): tracing/lowering
-still run, but the backend compile becomes a disk hit. Opt-in via
-``--compile_cache`` on the CLIs or ``PIT_COMPILE_CACHE=DIR`` for the benches.
+Tier 2 (:func:`configure_compile_cache`): jax's own persistent compilation
+cache, for every compile of the process (the trainer step, the kernels, the
+tools, and the serving programs too): tracing/lowering still run, but the
+backend compile becomes a disk hit. Always on, and placed from outside:
+``JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.cache/jax``. Every
+entry point calls :func:`configure_compile_cache` before its first compile.
+
+The two tiers do not stack: while tier 2 is active, tier-1 STORES are refused
+(see :meth:`ExecutableCache.store`), so a warm start is then a tier-2 hit per
+program — zero backend compiles, trace and lower still paid. Tier 1 stores
+only in a process that runs with ``JAX_ENABLE_COMPILATION_CACHE=false``.
 
 No jax import at module scope — entry points must stay free to pick their
 platform (``ensure_cpu_only``) before anything initializes a backend.
@@ -39,9 +45,7 @@ import inspect
 import os
 import pickle
 import re
-import sys
 import tempfile
-import threading
 import warnings
 from typing import Any, Dict, Iterable, List, Optional, Union
 
@@ -50,7 +54,7 @@ import numpy as np
 import perceiver_io_tpu.obs as obs
 
 _ENTRY_SUFFIX = ".pitx"
-_ENTRY_FORMAT = 1  # bump when the on-disk pickle layout changes
+_ENTRY_FORMAT = 2  # bump when the on-disk pickle layout changes
 
 
 # -- fingerprinting ----------------------------------------------------------
@@ -255,8 +259,16 @@ class ExecutableCache:
             if entry["format"] != _ENTRY_FORMAT:
                 raise ValueError(f"entry format {entry['format']} != "
                                  f"{_ENTRY_FORMAT}")
+            # bind the program to the devices it was compiled for: the
+            # default is EVERY device of the backend, and a one-device
+            # program loaded that way fails at its first call on any host
+            # with more than one device
+            import jax
+
+            by_id = {d.id: d for d in jax.devices()}
             compiled = serialize_executable.deserialize_and_load(
-                entry["payload"], entry["in_tree"], entry["out_tree"]
+                entry["payload"], entry["in_tree"], entry["out_tree"],
+                execution_devices=[by_id[i] for i in entry["device_ids"]],
             )
         except Exception as e:
             self._m_errors.inc()
@@ -282,12 +294,13 @@ class ExecutableCache:
         filled up mid-write) — the in-memory executable keeps serving.
 
         Refuses (once-warned) while jax's persistent compilation cache is
-        active in this process: that cache already serialized this very
-        executable for its own disk entry, and serializing it a SECOND time
-        intermittently corrupts this jaxlib's CPU runtime (measured — the
-        crash surfaces later, in unrelated compiles; PERF.md §Cold start
-        negative result). Loads stay enabled; the two tiers simply must not
-        both serialize the same compile.
+        active in this process. An executable that tier 2 handed back from
+        its own disk entry, serialized again here, reloads into a program
+        that fails at its first call (``NOT_FOUND: ... Function
+        dot_add_fusion.1 not found`` — reproduced on jaxlib 0.9.0's CPU
+        runtime, PR 22; earlier jaxlibs corrupted the heap instead). Loads
+        stay enabled; with both tiers on, new programs persist through
+        tier 2 alone.
         """
         if persistent_cache_active():
             global _DOUBLE_TIER_WARNED
@@ -295,12 +308,12 @@ class ExecutableCache:
                 _DOUBLE_TIER_WARNED = True
                 warnings.warn(
                     "AOT executable store skipped: jax's persistent "
-                    "compilation cache is active in this process, and "
-                    "double-serializing an executable (both tiers) "
-                    "destabilizes this jaxlib (PERF.md §Cold start). Use "
-                    "the AOT tier for serving processes and the persistent "
-                    "cache for trainer/tool processes, not both in one.",
-                    stacklevel=2)
+                    "compilation cache is active in this process, and an "
+                    "executable it served cannot be serialized a second "
+                    "time (aot/cache.py). New programs persist through the "
+                    "persistent cache alone; run with "
+                    "JAX_ENABLE_COMPILATION_CACHE=false to store AOT "
+                    "entries.", stacklevel=2)
             return False
         path = self.path(fp)
         try:
@@ -313,6 +326,9 @@ class ExecutableCache:
                 "payload": payload,
                 "in_tree": in_tree,
                 "out_tree": out_tree,
+                "device_ids": [
+                    d.id
+                    for d in compiled.runtime_executable().local_devices()],
             })
             fd, tmp = tempfile.mkstemp(
                 dir=self.directory, prefix=".tmp_", suffix=_ENTRY_SUFFIX)
@@ -398,73 +414,43 @@ def resolve_cache(
 
 # -- tier 2: jax's persistent compilation cache ------------------------------
 
-_TIER2_LOCK = threading.Lock()
-_TIER2_DIR: Optional[str] = None
 _DOUBLE_TIER_WARNED = False
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
 def persistent_cache_active() -> bool:
-    """True when jax's persistent compilation cache is on in this process
-    (whether enabled here or by the caller's own jax config)."""
-    with _TIER2_LOCK:
-        if _TIER2_DIR is not None:
-            return True
-    try:
-        import jax
+    """True when jax's persistent compilation cache is on in this process."""
+    import jax
 
-        return bool(jax.config.jax_compilation_cache_dir)
-    except Exception:
-        return False
+    return bool(jax.config.jax_enable_compilation_cache
+                and jax.config.jax_compilation_cache_dir)
 
 
-def enable_persistent_compilation_cache(directory: str) -> bool:
-    """Point jax's persistent compilation cache at ``directory`` (min compile
-    time 0, no size floor) so every backend compile in this process becomes a
-    disk write/hit — the second tier, for paths the AOT executable cache
-    can't cover (trainer steps, ad-hoc tools).
+def configure_compile_cache() -> str:
+    """Place jax's persistent compilation cache; returns its directory.
 
-    Fail-soft and idempotent; returns True when the cache is active. Safe to
-    call after the backend initialized (jax caches its "is the cache used"
-    decision at first compile, so we reset it).
+    THE one decision, called by every entry point before its first compile:
+    where ``JAX_COMPILATION_CACHE_DIR`` is set, jax's own handling of it
+    stands and no code names another directory; otherwise the cache is
+    ``<checkout>/.cache/jax``, derived from this package's location — never
+    the working directory, a temporary name, a pid or the time, because the
+    directory is where the next process looks, and one that moves never
+    hits. Every compile is kept (no minimum compile time or entry size): a
+    warm start counts ZERO backend compiles only if the small programs hit
+    too. Idempotent.
     """
-    global _TIER2_DIR
-    with _TIER2_LOCK:
-        if _TIER2_DIR == directory:
-            return True
-        try:
-            os.makedirs(directory, exist_ok=True)
-            import jax
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
 
-            jax.config.update("jax_compilation_cache_dir", directory)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-            try:
-                # private but load-bearing: jax latches its cache-enabled
-                # decision at the first compile; a process that already
-                # compiled something (backend probe) must re-evaluate
-                from jax._src import compilation_cache as _cc
-
-                _cc.reset_cache()
-            except Exception:
-                pass
-        except Exception as e:
-            warnings.warn(
-                f"persistent compilation cache {directory!r} unavailable "
-                f"({type(e).__name__}: {e}) — compiles will not persist "
-                "(everything still runs)", stacklevel=2)
-            return False
-        _TIER2_DIR = directory
-    print(f"[aot] persistent compilation cache: {directory}",
-          file=sys.stderr)
-    return True
-
-
-def maybe_enable_cache_from_env() -> Optional[str]:
-    """Bench/tool opt-in: ``PIT_COMPILE_CACHE=DIR`` enables the tier-2
-    persistent compilation cache so repeat sessions skip remote recompiles.
-    Returns the directory when enabled. Never touches stdout (the one-JSON-
-    line contracts) and never raises."""
-    directory = os.environ.get("PIT_COMPILE_CACHE")
+    directory = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not directory:
-        return None
-    return directory if enable_persistent_compilation_cache(directory) else None
+        directory = os.path.join(_CHECKOUT, ".cache", "jax")
+        if jax.config.jax_compilation_cache_dir != directory:
+            jax.config.update("jax_compilation_cache_dir", directory)
+            # jax latches whether the cache is in use at its first compile;
+            # a process that compiled before this call must look again
+            compilation_cache.reset_cache()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return directory

@@ -81,8 +81,8 @@ def add_trainer_args(parser: argparse.ArgumentParser) -> None:
                         "which the trainer now warns about)")
     g.add_argument("--steps_per_dispatch", type=int, default=1,
                    help="lax.scan N optimizer steps per device dispatch — "
-                        "amortizes per-call latency on remote/tunneled "
-                        "accelerators (PERF.md)")
+                        "amortizes per-call host latency when steps are very "
+                        "fast or the host is busy")
     g.add_argument("--selfprofile_every_n_steps", type=int, default=0,
                    help="in-loop device-trace watchdog: every N optimizer "
                         "steps capture a short jax.profiler trace, analyze "
@@ -118,7 +118,7 @@ def add_trainer_args(parser: argparse.ArgumentParser) -> None:
                         "(0 = skip only, never roll back)")
     g.add_argument("--dispatch_error_retries", type=int, default=0,
                    help="self-healing: retry a train dispatch that fails "
-                        "with a TRANSIENT error (tunnel drop, PJRT "
+                        "with a TRANSIENT error (connection drop, PJRT "
                         "UNAVAILABLE — never divergence or shape bugs) with "
                         "exponential backoff, up to N times per step. "
                         "Implies the per-dispatch host sync. 0 disables")
@@ -140,12 +140,6 @@ def add_trainer_args(parser: argparse.ArgumentParser) -> None:
                         "for 5 intervals is declared dead and this host "
                         "exits transient (75) instead of hanging in its "
                         "next collective. 0 = off")
-    g.add_argument("--compile_cache", default=None, metavar="DIR",
-                   help="cold start: persist XLA compilations here (jax's "
-                        "persistent compilation cache, min compile time 0) "
-                        "so restarts/resumes skip the remote compile of an "
-                        "unchanged step. Fail-soft: an unusable dir warns "
-                        "and trains uncached (PERF.md §Cold start)")
     g.add_argument("--publish_dir", default=None, metavar="DIR",
                    help="continuous deployment (perceiver_io_tpu.deploy): "
                         "atomically publish the current params here every "
@@ -347,7 +341,6 @@ def trainer_config(args) -> TrainerConfig:
         fit_attempts=getattr(args, "fit_attempts", 1),
         step_timeout_s=getattr(args, "step_timeout_s", None),
         peer_heartbeat_s=getattr(args, "peer_heartbeat_s", 0.0),
-        compile_cache=getattr(args, "compile_cache", None),
         publish_dir=getattr(args, "publish_dir", None),
         publish_every_n_steps=getattr(args, "publish_every_n_steps", 0),
     )
@@ -1115,14 +1108,7 @@ def maybe_initialize_distributed(args) -> None:
     )
     if wants_distributed:
         from perceiver_io_tpu.parallel import initialize_distributed
-        from perceiver_io_tpu.utils.platform import (
-            drop_unselected_plugin_backends,
-        )
 
-        # a registered-but-unselected PJRT plugin can initialize backends
-        # mid-initialize, detaching the distributed client (process_count
-        # silently stays 1 and every rank trains alone)
-        drop_unselected_plugin_backends()
         try:
             initialize_distributed(
                 coordinator_address=getattr(args, "coordinator_address", None),
@@ -1175,7 +1161,7 @@ def parse_with_resume(parser: argparse.ArgumentParser, argv):
                  # launcher topology/supervision describe THIS invocation
                  "spawn_hosts", "spawn_attempts", "elastic", "elastic_quorum",
                  # local paths: never inherit across hosts/invocations
-                 "compile_cache", "publish_dir", "publish_every_n_steps"}
+                 "publish_dir", "publish_every_n_steps"}
     defaults = {
         k: v for k, v in hparams.items() if k in known and k not in env_flags
     }

@@ -27,14 +27,14 @@ each micro-batch streams int8 weight bytes from HBM. The checkpoint stays
 f32 on disk; parity error vs the f32 oracle is bounded and measured
 (`tools/quant_bench.py`, PERF.md §Quantization).
 
-``--compile_cache DIR`` is the zero-recompile cold start
-(``perceiver_io_tpu.aot``, PERF.md §Cold start): every compiled bucket
-program is serialized to DIR keyed by a content fingerprint, and a warm
-restart deserializes the family instead of recompiling it — warmup then
-performs zero XLA compiles. (The serving process runs the AOT tier alone;
-jax's persistent compilation cache is the TRAINER/tools tier — running both
-on the same compile double-serializes the executable and destabilizes this
-jaxlib, a measured negative recorded in PERF.md §Cold start.)
+Cold start (``perceiver_io_tpu.aot``): jax's persistent compilation cache is
+always on, at ``JAX_COMPILATION_CACHE_DIR`` or ``<checkout>/.cache/jax``, so
+a warm restart's warmup performs zero backend compiles (every bucket program
+is a disk hit; trace and lower are still paid). ``--compile_cache DIR`` is
+the location of the serialized-EXECUTABLE entries, which skip trace and lower
+too — but an executable the persistent cache served cannot be serialized a
+second time (``aot/cache.py``), so entries are only WRITTEN by a process run
+with ``JAX_ENABLE_COMPILATION_CACHE=false``; existing entries always load.
 Warmup itself runs in the BACKGROUND by default
 (priority-ordered, smallest buckets first): the first request is answered as
 soon as its program is ready, not after the whole family is warm
@@ -94,7 +94,7 @@ before exit — never a half-swapped fleet.
 ``--metrics_port`` starts the localhost observability sidecar
 (``/metrics`` Prometheus text, ``/healthz``, ``/statz`` JSON snapshot, now
 including process self-metrics RSS/uptime/threads/GC at every scrape);
-``--heartbeat_deadline_s`` arms the wedged-tunnel dispatch heartbeat;
+``--heartbeat_deadline_s`` arms the wedged-dispatch heartbeat;
 ``--selfprofile_every`` turns on the in-loop device-trace watchdog. All
 telemetry output rides stderr/HTTP — stdout stays one JSON line per text.
 
@@ -252,12 +252,15 @@ def build_parser() -> argparse.ArgumentParser:
                    help="skip ahead-of-time bucket compilation (first "
                         "requests then pay the compiles)")
     g.add_argument("--compile_cache", default=None, metavar="DIR",
-                   help="zero-recompile cold start: persist every compiled "
-                        "bucket program here (serialized executables, "
-                        "perceiver_io_tpu.aot) — a warm restart deserializes "
-                        "instead of recompiling, and warmup performs zero "
-                        "XLA compiles. Fail-soft: a missing/unusable dir "
-                        "warns and serves uncached — never refuses traffic")
+                   help="directory of serialized bucket-program executables "
+                        "(perceiver_io_tpu.aot): a warm restart deserializes "
+                        "them, skipping trace, lower and compile. Entries "
+                        "load always and are written only when jax's "
+                        "persistent compile cache is off "
+                        "(JAX_ENABLE_COMPILATION_CACHE=false); with it on, "
+                        "that cache alone gives the zero-compile warm "
+                        "start. Fail-soft: an unusable dir warns and serves "
+                        "without it")
     g.add_argument("--blocking_warmup", action="store_true",
                    help="wait for the FULL bucket-program family before "
                         "serving (the pre-r10 behavior). Default: warmup "
@@ -393,7 +396,7 @@ def build_parser() -> argparse.ArgumentParser:
                    help="transient dispatch/completion failures re-dispatch "
                         "the micro-batch with exponential backoff up to this "
                         "many times before failing its requests (the error "
-                        "taxonomy never retries fatal errors). 0 disables")
+                        "classification never retries fatal errors). 0 disables")
     r.add_argument("--breaker_failures", type=int, default=0,
                    help="circuit breaker: open after this many CONSECUTIVE "
                         "dispatch failures (or a heartbeat stall) and "
@@ -412,7 +415,7 @@ def build_parser() -> argparse.ArgumentParser:
     o.add_argument("--heartbeat_deadline_s", type=float, default=None,
                    help="dispatch heartbeat deadline: if no dispatch "
                         "completes within this many seconds while work is in "
-                        "flight (wedged tunnel), /healthz flips unhealthy and "
+                        "flight (a wedged dispatch), /healthz flips unhealthy and "
                         "a thread-stack diagnostic is dumped to stderr. "
                         "Default: off")
     o.add_argument("--selfprofile_every", type=int, default=0,
@@ -511,6 +514,11 @@ def main(argv: Optional[Sequence[str]] = None):
                 "--autoscale needs --autoscale_rps_per_replica — seed it "
                 "from a measured tools/load_bench.py capacity fit "
                 "(slo_sustainable_rps / replicas), never a guess")
+    if args.replicas > 0 and not args.cpu:
+        raise SystemExit(
+            "--replicas N needs --cpu: replica processes are not pinned to "
+            "chips yet (ROADMAP.md), so on a TPU host every child would "
+            "claim every chip and all but one would fail or hang")
     if (args.priority_classes or args.client_quota_rps) \
             and args.replicas <= 0:
         raise SystemExit("--priority_classes/--client_quota_rps need "
@@ -524,6 +532,12 @@ def main(argv: Optional[Sequence[str]] = None):
         from perceiver_io_tpu.utils.platform import ensure_cpu_only
 
         ensure_cpu_only()
+    if args.replicas <= 0:
+        # a fleet parent never compiles (and stays off jax: its replicas
+        # need the devices); each replica places its own cache
+        from perceiver_io_tpu.aot import configure_compile_cache
+
+        configure_compile_cache()
 
     import perceiver_io_tpu.obs as obs
     from perceiver_io_tpu.data.tokenizer import load_tokenizer
@@ -678,13 +692,6 @@ def _stop_deployer(deployer, timeout_s: float) -> None:
 
 def _serve(args, MLMServer, load_tokenizer, load_mlm_checkpoint,
            drain_state=None):
-    # Deliberately tier 1 ONLY in the serve process: the AOT executable
-    # cache covers every compile serving performs (the bucket programs), and
-    # enabling jax's persistent compilation cache IN ADDITION measurably
-    # destabilizes this jaxlib — both tiers serialize the same executable,
-    # and the double serialization intermittently corrupts the CPU runtime
-    # (PERF.md §Cold start records the negative result). Trainers/tools,
-    # which have no AOT tier, use tier 2 via --compile_cache there.
     tokenizer = load_tokenizer(args.tokenizer)
     model, params, max_seq_len = load_mlm_checkpoint(
         args.checkpoint, tokenizer, step=args.step,

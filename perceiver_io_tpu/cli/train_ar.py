@@ -22,6 +22,7 @@ from typing import Optional, Sequence
 import jax
 import numpy as np
 
+from perceiver_io_tpu.aot import configure_compile_cache
 from perceiver_io_tpu.cli import common
 from perceiver_io_tpu.data.imdb import IMDBDataModule
 from perceiver_io_tpu.training import TrainState, make_ar_steps
@@ -97,6 +98,7 @@ def main(argv: Optional[Sequence[str]] = None):
     args = apply_preset(common.parse_with_resume(build_parser(), argv))
     if common.maybe_spawn_hosts(args, argv):
         return None
+    configure_compile_cache()
     common.maybe_initialize_distributed(args)
     common.validate_bucket_args(args)
 

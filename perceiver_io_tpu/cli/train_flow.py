@@ -15,6 +15,7 @@ from typing import Optional, Sequence
 
 import jax
 
+from perceiver_io_tpu.aot import configure_compile_cache
 from perceiver_io_tpu.cli import common
 from perceiver_io_tpu.data.flow import FlowDataModule
 from perceiver_io_tpu.models.flow import build_optical_flow_model
@@ -53,6 +54,7 @@ def main(argv: Optional[Sequence[str]] = None):
     args = common.parse_with_resume(build_parser(), argv)
     if common.maybe_spawn_hosts(args, argv):
         return None  # training ran in the spawned processes
+    configure_compile_cache()
     common.maybe_initialize_distributed(args)
     image_shape = (args.image_height, args.image_width, args.image_channels)
 

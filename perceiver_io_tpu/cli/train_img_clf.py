@@ -13,6 +13,7 @@ from typing import Optional, Sequence
 
 import jax
 
+from perceiver_io_tpu.aot import configure_compile_cache
 from perceiver_io_tpu.cli import common
 from perceiver_io_tpu.data.mnist import MNISTDataModule
 from perceiver_io_tpu.training import TrainState, make_classifier_steps
@@ -40,6 +41,7 @@ def main(argv: Optional[Sequence[str]] = None):
     args = common.parse_with_resume(build_parser(), argv)
     if common.maybe_spawn_hosts(args, argv):
         return None  # training ran in the spawned processes
+    configure_compile_cache()
     common.maybe_initialize_distributed(args)
 
     data = MNISTDataModule(

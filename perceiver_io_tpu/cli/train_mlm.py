@@ -20,6 +20,7 @@ from typing import Optional, Sequence
 import jax
 import numpy as np
 
+from perceiver_io_tpu.aot import configure_compile_cache
 from perceiver_io_tpu.cli import common
 from perceiver_io_tpu.data.imdb import IMDBDataModule
 from perceiver_io_tpu.data.tokenizer import MASK_TOKEN
@@ -145,14 +146,13 @@ def make_predict_hook(predict_fn, collator, samples: Sequence[str], k: int):
     return hook
 
 
-def main(argv: Optional[Sequence[str]] = None):
-    args = apply_preset(common.parse_with_resume(build_parser(), argv))
-    if common.maybe_spawn_hosts(args, argv):
-        return None  # training ran in the spawned processes
-    common.maybe_initialize_distributed(args)
-    # after distributed init: the multi-host guard reads jax.process_count()
-    common.validate_bucket_args(args)
-
+def build_trainer(args: argparse.Namespace, mesh=None):
+    """Data module, model, optimizer state, mesh and the :class:`Trainer`
+    for parsed (preset-applied) ``args`` — everything :func:`main` does
+    short of fitting. Returns ``(trainer, data)``; ``chip_smoke.py`` inspects
+    the compiled step of exactly the trainer the CLI would run. ``mesh``
+    replaces the one the mesh flags describe (the flags always span every
+    device; a one-device run on a four-chip host needs a mesh of one)."""
     data = IMDBDataModule(
         root=args.root,
         max_seq_len=args.max_seq_len,
@@ -185,7 +185,8 @@ def main(argv: Optional[Sequence[str]] = None):
     capacity = args.loss_gather_capacity
     if capacity < 0:
         capacity = mlm_gather_capacity(args.max_seq_len)
-    mesh = common.mesh_from_args(args)
+    if mesh is None:
+        mesh = common.mesh_from_args(args)
     fused = args.fused_head
     if fused == "auto":
         # the flash-CE kernel is a single-device op (ops/pallas_ce.py):
@@ -227,8 +228,20 @@ def main(argv: Optional[Sequence[str]] = None):
         ),
         tokens_per_example=args.max_seq_len,
     )
+    return trainer, data
+
+
+def main(argv: Optional[Sequence[str]] = None):
+    args = apply_preset(common.parse_with_resume(build_parser(), argv))
+    if common.maybe_spawn_hosts(args, argv):
+        return None  # training ran in the spawned processes
+    configure_compile_cache()
+    common.maybe_initialize_distributed(args)
+    # after distributed init: the multi-host guard reads jax.process_count()
+    common.validate_bucket_args(args)
+    trainer, data = build_trainer(args)
     with trainer:
-        state = common.run_fit(
+        common.run_fit(
             trainer, data.train_dataloader(), data.val_dataloader()
         )
     return trainer.run_dir

@@ -16,6 +16,7 @@ from typing import Optional, Sequence
 
 import jax
 
+from perceiver_io_tpu.aot import configure_compile_cache
 from perceiver_io_tpu.cli import common
 from perceiver_io_tpu.data.av import AVDataModule
 from perceiver_io_tpu.models.multimodal import build_multimodal_autoencoder
@@ -78,6 +79,7 @@ def main(argv: Optional[Sequence[str]] = None):
     args = common.parse_with_resume(build_parser(), argv)
     if common.maybe_spawn_hosts(args, argv):
         return None  # training ran in the spawned processes
+    configure_compile_cache()
     common.maybe_initialize_distributed(args)
     video_shape = (
         args.video_frames, args.video_size, args.video_size, args.video_channels

@@ -29,6 +29,7 @@ from typing import Optional, Sequence
 
 import jax
 
+from perceiver_io_tpu.aot import configure_compile_cache
 from perceiver_io_tpu.cli import common
 from perceiver_io_tpu.data.imdb import IMDBDataModule
 from perceiver_io_tpu.training import TrainState, make_classifier_steps
@@ -129,6 +130,7 @@ def main(argv: Optional[Sequence[str]] = None):
     args = common.parse_with_resume(build_parser(), argv)
     if common.maybe_spawn_hosts(args, argv):
         return None  # training ran in the spawned processes
+    configure_compile_cache()
     common.maybe_initialize_distributed(args)
     if args.mlm_checkpoint and args.clf_checkpoint:
         raise SystemExit("--mlm_checkpoint and --clf_checkpoint are exclusive")

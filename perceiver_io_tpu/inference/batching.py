@@ -436,8 +436,8 @@ class ContinuousBatcher(ARGenerator):
                        "admitted": 0, "retired": 0}
         self._closed = threading.Event()
         self.flight = DecodeFlightRecorder(name)
-        # the dispatcher's watchdog: a wedged round (device hang, tunnel
-        # stall) dumps the flight-recorder tail with the thread stacks —
+        # the dispatcher's watchdog: a wedged round (a device call that
+        # never returns) dumps the flight-recorder tail with the thread stacks —
         # the "why was my stream stuck" evidence. None = no monitor.
         self._hb = obs.Heartbeat(
             f"{name}-arena-dispatch", deadline_s=heartbeat_deadline_s,

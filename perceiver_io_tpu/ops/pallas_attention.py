@@ -47,10 +47,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# the TPUCompilerParams -> CompilerParams rename landed in newer jax; alias
-# whichever spelling this build ships
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 Array = jax.Array
 
 # Finite stand-in for -inf: exp() underflows to exactly 0 against any live
@@ -88,7 +84,7 @@ LONG_KV_SAFE_SBLK_D = 256 * 512
 LONG_KV_MAX_D = 512
 # The q bump additionally keeps the per-block probs area t_blk·s_blk inside
 # the measured compile region: 1024·1024 and 512·2048 elements compile,
-# 1024·2048 is a remote-compile OOM (long-context kv sweep, PERF.md r3).
+# 1024·2048 is a scoped-VMEM compile OOM (long-context kv sweep).
 LONG_KV_SAFE_PROBS = 1024 * 1024
 
 # Auto KV-block sizing (``kv_block_size=None``): streaming more keys per
@@ -102,7 +98,7 @@ LONG_KV_SAFE_PROBS = 1024 * 1024
 # kv2048 wins 9-12% at (1,256,131k,4,128)/(8,256,8k,4,128) AND re-measures
 # ahead at in-8h itself (7.44-7.65 vs 7.81-7.85 ms, interleaved ×2), so the
 # d≤128 tier is now 2048. kv4096 measured a further ~3% at t=256 shapes but
-# is a REAL remote-compile OOM at in-8h's t=512 (probs area 512·4096 = 2M >
+# is a REAL scoped-VMEM compile OOM at in-8h's t=512 (probs area 512·4096 = 2M >
 # the 1M boundary — the guard below must shrink it, so the tier stays 2048);
 # d=512 kv ≥ 1024 is the flow sweep's measured scoped-VMEM OOM, so deep
 # heads stay at 512. The measured KV-side footprint envelope is now
@@ -287,7 +283,7 @@ def _fused_attention_fwd_impl(
             pltpu.VMEM((t_blk, _LANES), jnp.float32),  # running denominator
             pltpu.VMEM((t_blk, d), jnp.float32),  # PV accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # batch/head/query-block grid steps are independent; only the KV
             # axis carries the softmax recurrence
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
@@ -408,7 +404,7 @@ def _fused_attention_bwd_impl(
         out_specs=qo_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((t_blk, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -432,7 +428,7 @@ def _fused_attention_bwd_impl(
                    jax.ShapeDtypeStruct(v.shape, v.dtype)),
         scratch_shapes=[pltpu.VMEM((s_blk, d), jnp.float32),
                         pltpu.VMEM((s_blk, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -49,10 +49,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# the TPUCompilerParams -> CompilerParams rename landed in newer jax; alias
-# whichever spelling this build ships
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 Array = jax.Array
 
 _LANES = 128
@@ -222,7 +218,7 @@ def _fused_ce_fwd_impl(
             pltpu.VMEM((r_blk, _LANES), jnp.float32),  # running sum
             pltpu.VMEM((r_blk, _LANES), jnp.float32),  # label logit
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -259,7 +255,7 @@ def _fused_ce_bwd_impl(
         out_specs=pl.BlockSpec((r_blk, c), lambda ri, vi: (ri, 0)),
         out_shape=jax.ShapeDtypeStruct((r, c), x.dtype),
         scratch_shapes=[pltpu.VMEM((r_blk, c), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -290,7 +286,7 @@ def _fused_ce_bwd_impl(
             pltpu.VMEM((c, v_blk), jnp.float32),
             pltpu.VMEM((1, v_blk), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -100,19 +100,8 @@ def initialize_distributed(
 ) -> None:
     """Multi-host bring-up: ``jax.distributed.initialize`` (auto-detected on
     TPU pods; explicit coordinator for manual launches). Safe to skip on a
-    single host."""
-    import os
-
-    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
-        # CPU-backend multi-process collectives need an implementation
-        # selected (newer jax defaults to gloo; this build defaults to
-        # 'none', where any cross-process psum raises "Multiprocess
-        # computations aren't implemented"). Pre-init only — harmless if
-        # this jax has no such knob.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            pass
+    single host. CPU-backend multi-process collectives need nothing here:
+    jax 0.9.0 selects gloo by default."""
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,

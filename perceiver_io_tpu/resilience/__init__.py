@@ -7,7 +7,7 @@ chaos substrate that proves it works without real hardware failures:
 - :mod:`faults` — deterministic, test-seedable fault injection (transient
   errors, wedged-dispatch hangs, host slowdowns, NaN corruption) behind
   no-op-by-default hooks at the dispatch sites; env-gated via ``PIT_FAULTS``.
-- :mod:`retry` — the error taxonomy (transient vs fatal, with the measured
+- :mod:`retry` — the error classification (transient vs fatal, with the measured
   scoped-VMEM-OOM carve-out) and capped exponential backoff with jitter.
 - :mod:`breaker` — a circuit breaker (closed → open on consecutive failures
   or heartbeat stalls → half-open probe), exported to the metrics registry

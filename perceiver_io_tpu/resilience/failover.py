@@ -1,7 +1,7 @@
 """Router-side failover policy: which replica errors displace a request to
 another replica, and how many placements one request may burn.
 
-The engine-side taxonomy (:mod:`perceiver_io_tpu.resilience.retry`) answers
+The engine-side classification (:mod:`perceiver_io_tpu.resilience.retry`) answers
 "is retrying *this dispatch* sane?"; this module answers the router's
 question one level up: "is retrying *on a different replica* sane?" The two
 differ in exactly three places:
@@ -15,8 +15,8 @@ differ in exactly three places:
   already gave up. (It must be carved out explicitly — it subclasses
   ``TimeoutError``, which the transient classifier would happily retry.)
 - **a dead replica is transient-class**: ``kill -9`` surfaces router-side as
-  connection reset/refused/EOF on the RPC socket — the tunnel-drop signature
-  the taxonomy already classifies transient — so in-flight requests on a
+  connection reset/refused/EOF on the RPC socket — the dropped-connection signature
+  the classification already classifies transient — so in-flight requests on a
   killed replica re-route instead of failing their callers. The request was
   ACCEPTED by the router; acceptance is the router's delivery promise.
 

@@ -1,6 +1,6 @@
-"""Error taxonomy + exponential backoff with jitter.
+"""Error classification + exponential backoff with jitter.
 
-The taxonomy answers ONE question for every exception escaping a device
+The classification answers ONE question for every exception escaping a device
 dispatch (or an HTTP fetch): *is retrying sane?* It is deliberately
 conservative and string-based — jaxlib surfaces every PJRT failure as
 ``XlaRuntimeError`` with an absl status prefix, and importing jaxlib types
@@ -12,7 +12,7 @@ Classification rules, in order:
 - injected faults carry their class (``InjectedTransientError`` /
   ``InjectedFatalError``) — the chaos suite's ground truth;
 - connection-ish OS errors (reset/aborted/broken pipe/timeout) are transient
-  — the tunnel's failure signature;
+  — a dropped connection's signature;
 - ``XlaRuntimeError``-family messages are transient only under status
   prefixes that name infrastructure (UNAVAILABLE, ABORTED, CANCELLED,
   DEADLINE_EXCEEDED, UNKNOWN, INTERNAL) — **RESOURCE_EXHAUSTED is fatal**:
@@ -54,7 +54,7 @@ _TRANSIENT_STATUS_PREFIXES = (
     "UNAVAILABLE", "ABORTED", "CANCELLED", "DEADLINE_EXCEEDED", "UNKNOWN",
     "INTERNAL",
 )
-# connection-level failure text (tunnel drops surface these inside URLError /
+# connection-level failure text (dropped connections surface these inside URLError /
 # XlaRuntimeError messages as well as bare OSErrors)
 _TRANSIENT_MESSAGE_MARKERS = (
     "connection reset", "connection aborted", "broken pipe", "socket closed",
@@ -62,7 +62,7 @@ _TRANSIENT_MESSAGE_MARKERS = (
 )
 _RUNTIME_ERROR_TYPES = ("XlaRuntimeError", "PjRtError", "JaxRuntimeError")
 # deterministic failures that can surface under infra-looking status
-# prefixes: the remote-compile scoped-VMEM OOMs (CLAUDE.md / PERF.md r3)
+# prefixes: the compile-time scoped-VMEM OOMs of an oversized kernel block
 _FATAL_MESSAGE_MARKERS = ("scoped vmem", "scoped allocation", "out of memory")
 
 
@@ -88,8 +88,8 @@ def classify_error(exc: BaseException) -> str:
     mro_names = {c.__name__ for c in type(exc).__mro__}
     if mro_names.intersection(_RUNTIME_ERROR_TYPES):
         if any(m in lowered for m in _FATAL_MESSAGE_MARKERS):
-            # deterministic compiler failures ride infra-looking prefixes on
-            # the remote-compile path (PERF.md r3) — never retry these
+            # deterministic compiler failures can ride infra-looking status
+            # prefixes — never retry these
             return FATAL
         head = msg.lstrip().split(":", 1)[0].strip()
         if head in _TRANSIENT_STATUS_PREFIXES:

@@ -13,7 +13,7 @@ class while the rest of the fleet's tail stays flat:
   priority="gold")``) or inherit the controller's default.
 - **per-client token buckets** — each distinct ``client`` id draws from its
   own bucket (``rate_per_s`` sustained, ``burst`` ceiling). An empty bucket
-  sheds the request *at admission* with a taxonomy-honest
+  sheds the request *at admission* with a honestly classified
   :class:`~perceiver_io_tpu.resilience.RejectedError` (``reason="quota"``):
   the failover policy treats it exactly like an engine-side rejection, and
   the shed burns the CLIENT'S class SLO, nobody else's.

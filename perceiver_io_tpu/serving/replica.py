@@ -75,7 +75,7 @@ _MAX_SESSIONS = 1024  # FIFO-evicted; a session is one encode's latents
 
 class RemoteEngineError(RuntimeError):
     """A replica-side engine error mirrored across the RPC boundary; carries
-    the remote classification as the ``transient`` attribute the taxonomy
+    the remote classification as the ``transient`` attribute the classification
     honors (``classify_error``), so failover decisions survive the hop."""
 
     def __init__(self, message: str, transient: bool):
@@ -629,7 +629,7 @@ class _TrackedHTTPServer(ThreadingHTTPServer):
     ``server_close`` only closes the listener; with pooled persistent
     router connections (r22), handler threads keep serving on their open
     sockets after shutdown — a "closed" replica would keep answering. The
-    dead-replica contract (ConnectionError, the failover taxonomy's
+    dead-replica contract (ConnectionError, the failover classification's
     reroute class) requires close to cut every live connection, matching
     the uds server's close semantics."""
 
@@ -877,7 +877,7 @@ class ReplicaServer:
 class HttpReplicaClient:
     """Router-side handle to one replica process. Transport failures (dead
     replica, mid-request ``kill -9``) surface as ``ConnectionError`` with the
-    taxonomy's transient markers — the failover policy re-routes them.
+    classification's transient markers — the failover policy re-routes them.
 
     Requests ride POOLED persistent HTTP/1.1 connections with TCP_NODELAY
     set on both sides: the previous one-urllib-connection-per-call pattern
@@ -973,7 +973,7 @@ class HttpReplicaClient:
         else:
             conn.close()
         if status >= 400:
-            # taxonomy bodies ride error statuses (the body was fully read,
+            # classification bodies ride error statuses (the body was fully read,
             # so the connection above stayed reusable)
             raise_wire_error(data, self.name)
         return data
@@ -1571,6 +1571,9 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
         from perceiver_io_tpu.utils.platform import ensure_cpu_only
 
         ensure_cpu_only()
+    from perceiver_io_tpu.aot import configure_compile_cache
+
+    configure_compile_cache()
     if args.events_jsonl:
         obs.configure_event_log(
             args.events_jsonl,

@@ -21,7 +21,7 @@ the failover blip, which is the whole point.
 Child-process hygiene reuses the r4 ``--spawn_hosts`` wiring lessons
 (``cli/common.py``): children write to LOG FILES, never undrained pipes (a
 chatty child deadlocks a pipe at ~64KB); the CPU backend is pinned via the
-child's env; SIGTERM gives a child its graceful drain (the replica CLI's
+child's env (the only fleet there is, see :class:`ReplicaSupervisor`); SIGTERM gives a child its graceful drain (the replica CLI's
 signal handler) before SIGKILL.
 """
 
@@ -82,9 +82,12 @@ class ReplicaSupervisor:
     ``argv_builder(name, port) -> argv`` builds each child's full command
     (default: the ``serving.replica`` CLI via :func:`default_replica_argv`
     with ``extra_args``). ``cpu=True`` pins ``JAX_PLATFORMS=cpu`` in the
-    children (the offline fleet; on a real TPU the one local chip cannot
-    host N replicas anyway — multi-chip fleets run one replica per chip via
-    explicit ``argv_builder`` device selection).
+    children (the offline fleet). ``cpu=False`` is REFUSED: every child
+    would inherit this process's environment and claim every chip of the
+    host, and a chip belongs to one process. Pinning each child to its own
+    chip through its environment has not been shown to work on a multi-chip
+    host yet (ROADMAP.md); until it has, a TPU host runs its replicas in one
+    process (``LocalReplica``), one per device.
     """
 
     # pitlint PIT-LOCK: fleet membership is mutated by add_replica/retire
@@ -112,13 +115,20 @@ class ReplicaSupervisor:
     ):
         if count < 1:
             raise ValueError(f"count must be >= 1, got {count}")
+        if not cpu:
+            raise NotImplementedError(
+                "ReplicaSupervisor(cpu=False): replica processes are not "
+                "pinned to chips yet, so each of the "
+                f"{count} children would claim every chip of the host and "
+                "all but one would fail or hang. Pass cpu=True (the CPU "
+                "fleet) or run in-process replicas on the TPU host "
+                "(ROADMAP.md: per-chip pinning).")
         self.count = count
         self.transport = transport
         self._argv_builder = argv_builder or (
             lambda name, port: default_replica_argv(
                 name, port, extra=extra_args, transport=transport)
         )
-        self._cpu = cpu
         self._policy = restart_policy or RetryPolicy(
             max_retries=max_restarts, base_s=0.25, max_s=5.0)
         self.max_restarts = max_restarts
@@ -153,8 +163,7 @@ class ReplicaSupervisor:
 
     def _env(self) -> Dict[str, str]:
         env = dict(os.environ)
-        if self._cpu:
-            env["JAX_PLATFORMS"] = "cpu"
+        env["JAX_PLATFORMS"] = "cpu"
         # children must resolve the package even when the parent imported it
         # from a path not on the default sys.path (cli/common.py pattern)
         import perceiver_io_tpu

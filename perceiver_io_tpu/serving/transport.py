@@ -30,7 +30,7 @@ a per-request serialize+copy+syscall tax that starves the decode batcher
 Contract parity — all three transports speak the SAME fabric contract as the
 HTTP twin (pinned by the parametrized suite in ``tests/test_transport.py``):
 
-- the error taxonomy crosses the wire (``raise_wire_error`` bodies:
+- the error classification crosses the wire (``raise_wire_error`` bodies:
   breaker_open/rejected/deadline/affinity_lost/engine+transient);
 - trace headers propagate (``TraceContext.to_headers`` rides the request
   frame; the replica's ``replica_serve`` span parents to the router's);
@@ -727,7 +727,7 @@ class UdsReplicaClient:
         header = dict(header, id=rid)
         p = conn.send(rid, header, payload)
         # the replica enforces timeout_s server-side (DeadlineExceeded comes
-        # back as a taxonomy frame); the client-side wait is a safety net
+        # back as a classification frame); the client-side wait is a safety net
         # set BEYOND it so the server's verdict always wins the race
         wait_s = (timeout_s if timeout_s is not None else self.timeout_s)
         if not p.event.wait(timeout=wait_s + 5.0):

@@ -354,6 +354,17 @@ def _read_manager(directory: str, monitor: str, mode: str) -> ocp.CheckpointMana
     )
 
 
+def _committed_steps(directory: str):
+    """Steps with a committed directory under ``directory`` (orbax names a
+    finished step by its bare number; in-flight saves carry a tmp suffix)."""
+    try:
+        names = os.listdir(directory)
+    except OSError:
+        return []
+    return [int(n) for n in names
+            if n.isdigit() and os.path.isdir(os.path.join(directory, n))]
+
+
 def restore_train_state(
     directory: str, like_state, step: Optional[int] = None,
     monitor: str = "val_loss", mode: str = "min",
@@ -377,25 +388,20 @@ def restore_train_state(
     )
     last_dir = os.path.join(os.path.abspath(directory), LAST_SUBDIR)
     if prefer_latest and step is None:
-        # open each manager once: construction re-scans the directory (and
-        # synchronizes cross-host), so probing and restoring reuse the handle
-        import contextlib
-
-        with contextlib.ExitStack() as stack:
-            last_mngr = None
-            candidates = []
-            if os.path.isdir(last_dir):
-                last_mngr = stack.enter_context(
-                    ocp.CheckpointManager(last_dir))
-                candidates += [(int(s), "last") for s in last_mngr.all_steps()]
-            # a PLAIN (rank-free) manager for the main slot: prefer_latest
-            # never needs best_fn, and a ranked manager eagerly json-parses
-            # every step's metrics at construction — a truncated step from a
-            # killed-mid-save run would crash the scan before the per-step
-            # fallback below could skip it
-            mngr = stack.enter_context(
-                ocp.CheckpointManager(os.path.abspath(directory)))
-            candidates += [(int(s), "main") for s in mngr.all_steps()]
+        # Candidates come from the directory listing and each is restored
+        # by a step-level checkpointer: a CheckpointManager scans EVERY step
+        # at construction (the installed orbax json-parses each step's
+        # metrics even for a rank-free manager), so one truncated step from
+        # a killed-mid-save run would crash the scan before the per-step
+        # fallback below could skip it.
+        with ocp.Checkpointer(
+                ocp.CompositeCheckpointHandler()) as checkpointer:
+            candidates = [
+                (s, source)
+                for source, d in (("last", last_dir),
+                                  ("main", os.path.abspath(directory)))
+                for s in _committed_steps(d)
+            ]
             # newest step first; on a tie the last/ slot wins (it is by
             # construction at least as new as the ranked save of that step)
             candidates.sort(key=lambda c: (c[0], c[1] == "last"), reverse=True)
@@ -403,11 +409,12 @@ def restore_train_state(
                 raise FileNotFoundError(f"no checkpoints in {directory}")
             errors = []
             for cand_step, source in candidates:
-                use = last_mngr if source == "last" else mngr
                 cand_dir = last_dir if source == "last" \
                     else os.path.abspath(directory)
                 try:
-                    restored = use.restore(cand_step, args=restore_args)["state"]
+                    restored = checkpointer.restore(
+                        os.path.join(cand_dir, str(cand_step)),
+                        args=restore_args)["state"]
                 except Exception as e:  # corrupt/partial step dir
                     errors.append(e)
                     warnings.warn(

@@ -2,8 +2,20 @@
 
 Set before jax initializes any backend so SPMD/mesh tests can exercise an
 8-device mesh without TPU hardware (the JAX-native way to test sharding,
-SURVEY.md §4). Real-TPU runs happen only via bench.py / the driver.
+SURVEY.md §4). The chip is reached only through ``chip_smoke.py`` /
+``bench.py`` on a machine that has one.
+
+jax's persistent compilation cache is OFF for the test process AND the
+processes it spawns (the environment variable is inherited): entry points
+still place it (``aot.configure_compile_cache`` — where it would live is
+tested), but nothing is read or written, so the AOT executable-cache tests
+keep storing (stores are refused while the persistent cache is active) and
+no test leaves compiles under the checkout.
 """
+
+import os
+
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
 from perceiver_io_tpu.utils.platform import ensure_cpu_only
 

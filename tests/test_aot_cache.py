@@ -228,14 +228,15 @@ def test_resolve_cache_passthrough(tmp_path):
 
 
 def test_store_refused_while_persistent_cache_active(tmp_path, monkeypatch):
-    """The two tiers must never both serialize one compile (the measured
-    jaxlib-corruption negative, PERF.md §Cold start): with jax's persistent
-    compilation cache active in-process, AOT stores are refused with one
-    warning — loads stay enabled, serving stays up."""
+    """The two tiers must never both serialize one compile (an executable
+    the persistent cache served, serialized again, reloads broken —
+    aot/cache.py): with jax's persistent compilation cache active
+    in-process, AOT stores are refused with one warning — loads stay
+    enabled, serving stays up."""
     from perceiver_io_tpu.aot import cache as cache_mod
 
     c = ExecutableCache.open(str(tmp_path / "c"))
-    monkeypatch.setattr(cache_mod, "_TIER2_DIR", "/somewhere")
+    monkeypatch.setattr(cache_mod, "persistent_cache_active", lambda: True)
     monkeypatch.setattr(cache_mod, "_DOUBLE_TIER_WARNED", False)
     import jax.numpy as jnp
 

@@ -2,7 +2,7 @@
 
 Tier-1 coverage runs IN-PROCESS over trivial jitted engines behind
 ``LocalReplica`` shims (the test_fabric idiom): WFQ/token-bucket units, the
-admission gate's shed taxonomy, the noisy-neighbor isolation pin, the
+admission gate's shed classification, the noisy-neighbor isolation pin, the
 policy's hold-down/hysteresis state machine with injected clocks, the
 end-to-end scale-up/scale-down loop over a live router, the spawn-failure
 backoff chaos drill, and the supervisor's drain-then-SIGTERM retire path
@@ -678,6 +678,10 @@ def test_serve_cli_autoscale_flag_validation():
         serve.main([*base, "--replicas", "2", "--autoscale"])
     with pytest.raises(SystemExit, match="--replicas"):
         serve.main([*base, "--priority_classes", "gold:2,bronze:1"])
+    # a process fleet off --cpu is refused before anything is spawned:
+    # unpinned replica processes would all claim the same chips
+    with pytest.raises(SystemExit, match="--cpu"):
+        serve.main([*base, "--replicas", "2"])
 
 
 @pytest.mark.slow  # tier-1 budget (r17): a real load_bench schedule run is
@@ -695,7 +699,7 @@ def test_load_bench_autoscale_schedule_contract():
 
     proc = subprocess.run(
         [sys.executable, os.path.join(ROOT, "tools", "load_bench.py"),
-         "--cpu", "--replicas", "1", "--autoscale", "--schedule", "step",
+         "--cpu", "--preset", "tiny", "--replicas", "1", "--autoscale", "--schedule", "step",
          "--schedule_period_s", "3", "--max_replicas", "3"],
         capture_output=True, text=True, timeout=600,
     )

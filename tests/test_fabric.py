@@ -163,7 +163,7 @@ def test_router_least_loaded_routing_skewed(x):
 
 def test_router_failover_zero_lost_accepted(x):
     """Kill one of three replicas with traffic in flight: every accepted
-    request must still be answered (re-routed via the transient taxonomy),
+    request must still be answered (re-routed via the transient classification),
     none duplicated, none lost — the tier-1 twin of the kill -9 drill."""
     reps = [_make_replica(f"fo{i}") for i in range(3)]
     router = _router(reps)
@@ -551,7 +551,7 @@ def test_replica_http_rpc_roundtrip(x):
     finally:
         server.close()
         rep.app.close()
-    # the dead-server signature is the failover taxonomy's transient class
+    # the dead-server signature is the failover classification's transient class
     with pytest.raises(ConnectionError):
         client.call("infer", [x])
 
@@ -793,7 +793,7 @@ def test_chaos_drill_kill9_under_load_bench_traffic():
     and the supervisor restarts the victim."""
     proc = subprocess.run(
         [sys.executable, os.path.join(ROOT, "tools", "load_bench.py"),
-         "--cpu", "--replicas", "3", "--replica_mode", "process",
+         "--cpu", "--preset", "tiny", "--replicas", "3", "--replica_mode", "process",
          "--kill_replica_at", "0.5", "--kill_point", "0",
          "--duration_s", "2", "--rate_factors", "0.8",
          "--calibration_waves", "2", "--calibration_wave_size", "12"],

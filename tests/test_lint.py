@@ -119,7 +119,7 @@ def test_jit_purity_treats_ops_models_modules_as_traced():
 # -- PIT-CONTRACT -------------------------------------------------------------
 
 
-def test_contract_flags_stdout_and_bare_probes_in_tools_only():
+def test_contract_flags_stdout_in_tools_only():
     src = """
     import sys
     import jax
@@ -131,25 +131,27 @@ def test_contract_flags_stdout_and_bare_probes_in_tools_only():
         print("log line", file=sys.stderr)
     """
     found = _check(ToolContractRule(), src, "tools/somebench.py")
-    assert sum("bare jax.default_backend" in f.message for f in found) == 1
     assert sum("print() to stdout" in f.message for f in found) == 2
-    assert len(found) == 3  # the stderr print passes
+    # the stderr print passes, and so does the plain backend query: a local
+    # chip answers jax.default_backend() at once, no wrapper needed
+    assert len(found) == 2
     # identical code outside tools/ is not this rule's business
     assert _check(ToolContractRule(), src, "perceiver_io_tpu/x.py") == []
 
 
-def test_contract_sanctions_emit_json_line_and_deadline_helpers():
+def test_contract_sanctions_emit_json_line_and_plain_device_queries():
     src = """
+    import sys
     import jax
     from perceiver_io_tpu.utils.jsonline import emit_json_line
 
-    def probe_backend():
-        return jax.devices()  # the sanctioned helper's own implementation
-
     def main():
-        emit_json_line({"metric": "x", "value": 1})
+        if jax.devices()[0].platform != "tpu":
+            sys.stdout.write("no chip")
+        emit_json_line({"metric": "x", "value": jax.device_count()})
     """
-    assert _check(ToolContractRule(), src, "tools/somebench.py") == []
+    found = _check(ToolContractRule(), src, "tools/somebench.py")
+    assert len(found) == 1 and "sys.stdout" in found[0].message
 
 
 # -- PIT-FAULT ----------------------------------------------------------------

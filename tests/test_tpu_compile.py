@@ -1,0 +1,127 @@
+"""Compile the main path's Pallas kernels for a described TPU v5e chip.
+
+Interpret mode (every other kernel test) cannot see what the chip's compiler
+refuses: a block the TPU lowering's tiling rule rejects, a scoped-VMEM
+overflow, an unaligned slice. The TPU compiler is installed here and compiles
+for a chip that is described, not attached — so these cases guard every PR at
+no chip time. Each asserts a ``tpu_custom_call`` in the compiled text: the
+kernel lowered through Mosaic, it did not fall back.
+
+The topology is described INSIDE a module-scoped fixture (never at import, in
+a ``skipif`` or in ``parametrize``): only one process may load the TPU
+library, and every xdist worker imports every test file. All such tests live
+in this one file, so one worker loads the library; they compile in the test's
+own process, with the persistent compilation cache off around them (a compile
+for a described chip is written to it but cannot be read back).
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _sum_sq(fn, grad):
+    def loss(*args):
+        return jnp.sum(fn(*args).astype(jnp.float32) ** 2)
+
+    if grad:
+        return lambda *args: jax.grad(
+            loss, argnums=tuple(range(len(args))))(*args)
+    return loss
+
+
+def _attention(b, t, s, h, d, grad=False, causal_offset=None):
+    from perceiver_io_tpu.ops.pallas_attention import fused_attention
+
+    fn = functools.partial(
+        fused_attention, causal_offset=causal_offset, interpret=False)
+    qkv = [((b, t, h, d), jnp.bfloat16), ((b, s, h, d), jnp.bfloat16),
+           ((b, s, h, d), jnp.bfloat16)]
+    return _sum_sq(fn, grad), qkv
+
+
+def _flash_ce(rows, c, vocab, grad=False):
+    from perceiver_io_tpu.ops.pallas_ce import pallas_linear_ce_integer
+
+    def loss(x, w, bias, labels):
+        return jnp.sum(
+            pallas_linear_ce_integer(x, w, bias, labels, interpret=False))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else loss
+    return fn, [((rows, c), jnp.bfloat16), ((c, vocab), jnp.bfloat16),
+                ((vocab,), jnp.float32), ((rows,), jnp.int32)]
+
+
+def _qmm(m, k, n, bits, group_size=None):
+    from perceiver_io_tpu.ops.pallas_matmul import dequant_matmul
+
+    fn = functools.partial(
+        dequant_matmul, group_size=group_size, interpret=False)
+    scale = (n,) if group_size is None else (k // group_size, n)
+    return fn, [((m, k), jnp.bfloat16),
+                ((k, n), jnp.int8 if bits == 8 else jnp.int4),
+                (scale, jnp.float32)]
+
+
+# flagship widths: (batch 64, 256 latents, 512 tokens, 4 heads) at the
+# reference's head depth 16 and flagship_tpu's 128; 10240 = 64 x 160 gathered
+# decode rows; the MLP (512 -> 2048) and vocab-head (512 -> 10003) matmuls
+_MLP = (2048, 512, 2048)
+_HEAD = (512, 512, 10003)
+CASES = {
+    "attn-fwd-d16": lambda: _attention(64, 256, 512, 4, 16),
+    "attn-grad-d16": lambda: _attention(64, 256, 512, 4, 16, grad=True),
+    "attn-fwd-d128": lambda: _attention(64, 256, 512, 4, 128),
+    "attn-grad-d128": lambda: _attention(64, 256, 512, 4, 128, grad=True),
+    "attn-causal-prefill-d128": lambda: _attention(
+        4, 256, 512, 4, 128, causal_offset=256),
+    "attn-q1-decode-d128": lambda: _attention(
+        4, 1, 512, 4, 128, causal_offset=511),
+    "ce-fwd-c64": lambda: _flash_ce(10240, 64, 10003),
+    "ce-grad-c64": lambda: _flash_ce(10240, 64, 10003, grad=True),
+    "ce-fwd-c512": lambda: _flash_ce(10240, 512, 10003),
+    "ce-grad-c512": lambda: _flash_ce(10240, 512, 10003, grad=True),
+    "qmm-int8-mlp": lambda: _qmm(*_MLP, bits=8),
+    "qmm-int8-head": lambda: _qmm(*_HEAD, bits=8),
+    "qmm-int4-mlp": lambda: _qmm(*_MLP, bits=4),
+    "qmm-int4-head": lambda: _qmm(*_HEAD, bits=4),
+    "qmm-int4-grouped-mlp": lambda: _qmm(*_MLP, bits=4, group_size=128),
+    "qmm-int4-grouped-head": lambda: _qmm(*_HEAD, bits=4, group_size=128),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(name, one_chip):
+    fn, shapes = CASES[name]()
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()

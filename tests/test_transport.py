@@ -1,6 +1,6 @@
 """Zero-copy replica transport (r22): the raw array codec, the shmem slot
 state machine, and ONE parametrized fabric-contract suite that runs the r12
-wire contract — taxonomy round-trip, session pins, trace propagation, phase
+wire contract — classification round-trip, session pins, trace propagation, phase
 attribution, drain, piggybacked health, at-most-once — identically over all
 three transports (http / uds / shmem).
 
@@ -281,7 +281,7 @@ def fabric(request):
 def test_transport_contract_roundtrip(fabric, x):
     """The r12 wire contract over every transport: arrays round-trip,
     sessions stay resident (and AffinityLost mirrors for unknown pins),
-    admin verbs work, drain rejects with the draining taxonomy, and phases
+    admin verbs work, drain rejects with the draining classification, and phases
     ride the response metadata."""
     from perceiver_io_tpu.inference.engine import PHASES
 
@@ -356,7 +356,7 @@ def test_transport_pipelined_concurrency(fabric, x):
 
 def test_transport_dead_replica_is_reroutable_connection_error(x):
     """A dead replica raises ConnectionError on every transport — the
-    failover taxonomy's reroute class (vs DeadlineExceeded, which FAILs:
+    failover classification's reroute class (vs DeadlineExceeded, which FAILs:
     at-most-once means never re-route work that may have executed)."""
     policy = FailoverPolicy()
     for transport in TRANSPORTS:
@@ -502,7 +502,7 @@ def test_chaos_drill_kill9_transport_fleet_zero_lost(transport):
     the victim, and (shmem) no request ever lands on the stale slab."""
     proc = subprocess.run(
         [sys.executable, os.path.join(ROOT, "tools", "load_bench.py"),
-         "--cpu", "--replicas", "2", "--replica_mode", "process",
+         "--cpu", "--preset", "tiny", "--replicas", "2", "--replica_mode", "process",
          "--transport", transport,
          "--kill_replica_at", "0.5", "--kill_point", "0",
          "--duration_s", "2", "--rate_factors", "0.8",

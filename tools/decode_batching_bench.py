@@ -127,6 +127,9 @@ def run(args) -> int:
 
     if args.cpu:
         ensure_cpu_only()
+    from perceiver_io_tpu.aot import configure_compile_cache
+
+    configure_compile_cache()
     import jax
     import numpy as np
 

@@ -252,6 +252,9 @@ def main(argv=None) -> int:
         from perceiver_io_tpu.utils.platform import ensure_cpu_only
 
         ensure_cpu_only()  # the drill is a scheduler test, never a TPU job
+        from perceiver_io_tpu.aot import configure_compile_cache
+
+        configure_compile_cache()
         import tempfile
 
         path = args.drill_events

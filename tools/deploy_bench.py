@@ -115,9 +115,9 @@ def main() -> None:
         from perceiver_io_tpu.utils.platform import ensure_cpu_only
 
         ensure_cpu_only()
-    from perceiver_io_tpu.aot import maybe_enable_cache_from_env
+    from perceiver_io_tpu.aot import configure_compile_cache
 
-    maybe_enable_cache_from_env()  # PIT_COMPILE_CACHE opt-in (stderr only)
+    configure_compile_cache()
     import jax
 
     import perceiver_io_tpu.deploy as deploy

@@ -288,6 +288,10 @@ def run(name: str) -> None:
 
 
 def main():
+    from perceiver_io_tpu.aot import configure_compile_cache
+
+    configure_compile_cache()
+
     names = sys.argv[1:] or list(CONFIGS)
     unknown = [n for n in names if n not in CONFIGS]
     if unknown:

@@ -159,6 +159,10 @@ def fwd_only_variant():
 
 
 def main():
+    from perceiver_io_tpu.aot import configure_compile_cache
+
+    configure_compile_cache()
+
     print(f"device: {probe_backend().device_kind}, batch {BATCH}, {STEPS} steps", file=sys.stderr)
     rows = [
         ("full (bench default)", standard(build())),

@@ -65,7 +65,10 @@ def run(attn_impl: str, batch_size=64, steps=20, gather=None):
 
 
 if __name__ == "__main__":
+    from perceiver_io_tpu.aot import configure_compile_cache
     from perceiver_io_tpu.utils import profiling
+
+    configure_compile_cache()
 
     peak = profiling.device_peak_flops()
     peak_str = f", peak {peak/1e12:.0f} TF/s" if peak else " (no known peak: MFU off)"

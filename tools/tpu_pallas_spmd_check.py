@@ -26,6 +26,10 @@ import numpy as np
 
 
 def main() -> None:
+    from perceiver_io_tpu.aot import configure_compile_cache
+
+    configure_compile_cache()
+
     parser = argparse.ArgumentParser()
     parser.add_argument("--seq", type=int, default=8192)
     parser.add_argument("--batch", type=int, default=4)
