@@ -25,8 +25,9 @@ nothing is carried past it):
             kernel.
 5. warm     the server again, same process: zero backend compiles,
             bit-identical answers.
-6. generate ``ContinuousBatcher`` at ``flagship_ar`` widths, 4 concurrent
-            streams x 16 tokens, bit-identical to a plain ``ARGenerator``.
+6. generate ``ContinuousBatcher`` at ``flagship_ar`` widths (f32, true-f32
+            matmuls: the parity path), 4 concurrent streams x 16 tokens,
+            bit-identical to a plain ``ARGenerator``.
 
 The LAST line of stdout is the device line the builder's contract fixes:
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": 1}}``.
@@ -480,16 +481,21 @@ def phase_warm_start(ckpt: str, first: Dict[str, Any],
 def phase_generate(build_model: Optional[Callable] = None,
                    max_seq_len: int = 512, vocab: int = HEAD_WIDTH,
                    streams: int = 4, new_tokens: int = 16) -> Dict[str, Any]:
+    """Stream identity is a property of the arena's LOGIC (slots, admission,
+    cache rings) only under exact arithmetic: in bf16 on the MXU a batch-4
+    and a batch-1 matmul round differently, and with random weights (near-
+    tied logits) a greedy stream flipped at token 13 of 16 on the v5e (PR 22,
+    PERF.md). So the check runs the repo's parity path — f32 compute with
+    true-f32 matmuls — at every width of ``flagship_ar``."""
     t0 = time.monotonic()
     import jax
+    import jax.numpy as jnp
 
     from perceiver_io_tpu.inference.batching import ContinuousBatcher
     from perceiver_io_tpu.inference.generate import ARGenerator, SamplingConfig
     from perceiver_io_tpu.models.presets import flagship_ar
 
-    model = (build_model or flagship_ar)()
-    ids = np.zeros((1, max_seq_len), np.int32)
-    params = model.init({"params": jax.random.key(0)}, ids, ids == 0)["params"]
+    model = (build_model or (lambda: flagship_ar(dtype=jnp.float32)))()
     rng = np.random.default_rng(0)
     cases = []
     for i in range(streams):
@@ -499,15 +505,23 @@ def phase_generate(build_model: Optional[Callable] = None,
                                   top_k=16, seed=i)
         cases.append((prefix, new_tokens, sampling))
 
-    oracle = ARGenerator(model, params, max_seq_len=max_seq_len, chunk=8,
-                         name="smoke-oracle")
-    want = [oracle.generate(list(p), n, s)[0] for p, n, s in cases]
     got: List[Any] = [None] * streams
     errors: List[BaseException] = []
-    arena = ContinuousBatcher(model, params, max_seq_len=max_seq_len, chunk=8,
-                              slots=streams, max_slots=streams,
-                              name="smoke-arena")
+    # process-wide, not the context manager: the arena traces its programs on
+    # its own dispatcher thread, and jax's config contexts are thread-local
+    precision = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", "highest")
+    arena = None
     try:
+        ids = np.zeros((1, max_seq_len), np.int32)
+        params = model.init(
+            {"params": jax.random.key(0)}, ids, ids == 0)["params"]
+        oracle = ARGenerator(model, params, max_seq_len=max_seq_len, chunk=8,
+                             name="smoke-oracle")
+        want = [oracle.generate(list(p), n, s)[0] for p, n, s in cases]
+        arena = ContinuousBatcher(
+            model, params, max_seq_len=max_seq_len, chunk=8, slots=streams,
+            max_slots=streams, name="smoke-arena")
 
         def one(i: int) -> None:
             try:
@@ -528,13 +542,16 @@ def phase_generate(build_model: Optional[Callable] = None,
             raise AssertionError("a generation stream did not finish")
         stats = arena.stats()
     finally:
-        arena.close()
+        if arena is not None:
+            arena.close()
+        jax.config.update("jax_default_matmul_precision", precision)
     for i, (g, w) in enumerate(zip(got, want)):
         if g != w:
             raise AssertionError(
                 f"stream {i} diverged from the per-session generator: "
                 f"{g} vs {w}")
     return emit("generate", t0, streams=streams, new_tokens=new_tokens,
+                dtype="float32", matmul_precision="highest",
                 tokens_match=True, dispatches=stats["dispatches"],
                 admitted=stats["admitted"])
 
