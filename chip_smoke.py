@@ -608,15 +608,27 @@ def phase_multichip(base_flags: Optional[Sequence[str]] = None,
             "--num_encoder_layers", "2",
             "--num_self_attention_layers_per_block", "2",
             "--max_steps", "3", "--eval_every_n_steps", "1000"]
-    one = _mesh_run("one_device", None, base_flags)
-    print(json.dumps({"phase": "multichip_run", **one}), flush=True)
-    runs = {}
-    for name, flags in meshes.items():
-        run = _mesh_run(name, flags, base_flags)
-        run["max_loss_diff"] = float(np.max(np.abs(
-            np.asarray(run["losses"]) - np.asarray(one["losses"]))))
-        print(json.dumps({"phase": "multichip_run", **run}), flush=True)
-        runs[name] = run
+    # true-f32 matmuls for the whole comparison, as in phase 6: at the TPU's
+    # default f32 precision (one bf16 pass) the zero3 losses sat 9.2e-5 from
+    # the one-device ones on four v5e chips (PR 22) — rounding that depends
+    # on the per-device shapes, not a sharding fault, and outside an f32
+    # tolerance. Process-wide: trainers trace on their own threads too.
+    import jax
+
+    precision = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", "highest")
+    try:
+        one = _mesh_run("one_device", None, base_flags)
+        print(json.dumps({"phase": "multichip_run", **one}), flush=True)
+        runs = {}
+        for name, flags in meshes.items():
+            run = _mesh_run(name, flags, base_flags)
+            run["max_loss_diff"] = float(np.max(np.abs(
+                np.asarray(run["losses"]) - np.asarray(one["losses"]))))
+            print(json.dumps({"phase": "multichip_run", **run}), flush=True)
+            runs[name] = run
+    finally:
+        jax.config.update("jax_default_matmul_precision", precision)
     for name, run in runs.items():
         if len(run["losses"]) != len(one["losses"]) or not (
                 run["max_loss_diff"] <= MESH_LOSS_ATOL):
@@ -633,6 +645,7 @@ def phase_multichip(base_flags: Optional[Sequence[str]] = None,
                 f"{one['argument_bytes_per_device']}")
     return emit(
         "multichip", t0, loss_atol=MESH_LOSS_ATOL, flags=" ".join(base_flags),
+        matmul_precision="highest",
         one_device_argument_bytes=one["argument_bytes_per_device"],
         **{name: {k: run[k] for k in ("max_loss_diff", "collectives",
                                       "argument_bytes_per_device")}

@@ -343,10 +343,18 @@ def sp_gradient_canary(mesh: Mesh, axis: str = AXIS_SEQ) -> None:
     k = jax.random.normal(keys[1], (b, s, h, d), jnp.float32)
     v = jax.random.normal(keys[2], (b, s, h, d), jnp.float32)
 
+    # the kernel's f32 path is multi-pass; XLA's DEFAULT f32 matmul on a TPU
+    # is one bf16 pass, 3e-3 away — enough to trip this probe on real chips
+    # (median ratio 1.003 on a 2x2 v5e, PR 22) though the convention it
+    # guards shows up as a factor of 2 or more. Same precision both sides.
+    exact = jax.lax.Precision.HIGHEST
+
     def ref_loss(q, k, v):
-        logits = jnp.einsum("bthd,bshd->bhts", q * (d ** -0.5), k)
+        logits = jnp.einsum("bthd,bshd->bhts", q * (d ** -0.5), k,
+                            precision=exact)
         probs = jax.nn.softmax(logits, axis=-1)
-        return jnp.sum(jnp.einsum("bhts,bshd->bthd", probs, v) ** 2)
+        return jnp.sum(
+            jnp.einsum("bhts,bshd->bthd", probs, v, precision=exact) ** 2)
 
     def sp_loss(q, k, v):
         out = seq_parallel_fused_attention(q, k, v, mesh=mesh, axis=axis)
