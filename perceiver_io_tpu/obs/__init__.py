@@ -6,8 +6,14 @@ One subsystem, four pieces, every layer wired through it:
   gauges, bounded histograms with p50/p95/p99); the single source of truth
   the serving engine, the Trainer/``MetricsLogger``, and the watchdog all
   publish to.
-- :mod:`tracing` — span/event tracing to JSONL (compiles, warmups, stalls),
-  every record dual-stamped (wall + monotonic) and pid-labeled.
+- :mod:`tracing` — timed spans and discrete events. ``span`` is always on:
+  every finished span (start, end, parent, thread, fields) is kept in memory,
+  bounded per span name, read back with ``spans()``, and mirrored while open
+  as a profiler annotation ``pio.<name>`` once jax is imported; ``add_span``
+  enters one measured elsewhere (the compile path's ``jax.trace`` /
+  ``jax.lower`` / ``jax.backend_compile``, the Trainer's ``train.step``).
+  ``event`` and the JSONL sink (compiles, warmups, stalls; every record
+  dual-stamped wall + monotonic and pid-labeled) stay opt-in.
 - :mod:`reqtrace` — distributed request tracing: ``TraceContext``
   propagation across router → RPC → replica → engine, span records, and
   cross-process trace assembly with clock alignment and tail sampling.
@@ -87,10 +93,12 @@ from perceiver_io_tpu.obs.timeseries import (
 )
 from perceiver_io_tpu.obs.tracing import (
     EventLog,
+    add_span,
     configure_event_log,
     event,
     get_event_log,
     span,
+    spans,
 )
 from perceiver_io_tpu.obs.watchdog import SelfProfiler, install_compile_counter
 
@@ -114,6 +122,7 @@ __all__ = [
     "SeriesStore",
     "TraceBuffer",
     "TraceContext",
+    "add_span",
     "assemble_traces",
     "configure_event_log",
     "event",
@@ -134,6 +143,7 @@ __all__ = [
     "register_health_source",
     "sanitize_metric_name",
     "span",
+    "spans",
     "tail_sample",
     "thread_stacks",
     "unregister_health_source",

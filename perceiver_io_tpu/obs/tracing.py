@@ -1,10 +1,29 @@
-"""Lightweight span/event tracing to JSONL.
+"""Spans and events: timed spans kept in memory, and a JSONL sink for both.
 
-The narrative channel next to the registry's numeric one: discrete runtime
-happenings (a bucket program compiled, a warmup finished, a heartbeat
-stalled) append one JSON object per line to a configured file. Unconfigured,
-``event``/``span`` are near-free no-ops — library code calls them
-unconditionally and only entry points opt into a sink.
+The narrative channel next to the registry's numeric one. Two kinds of
+record:
+
+- ``span(name, **fields)`` times a stretch of the program. Every finished
+  span is kept IN MEMORY, always: ``name``, ``id``, ``parent`` (the innermost
+  span open on the same thread when it started), ``thread``, ``start_ns`` /
+  ``end_ns`` on ``time.monotonic_ns()``, ``ok`` and the fields. The buffers
+  are bounded PER SPAN NAME (``SPAN_DEPTH`` records each, oldest dropped
+  first, drops counted per name), so a week of ``train.step`` records can
+  never evict the dozen set-up spans. ``spans()`` returns a snapshot;
+  ``add_span`` enters a span whose ends were measured elsewhere (the
+  ``jax.monitoring`` listener in ``obs.watchdog``, the Trainer's loop). While
+  open, a span is also a ``jax.profiler.TraceAnnotation("pio." + name)`` if
+  ``jax`` is already imported in the process (this module never imports it):
+  under an active profiler the program's spans lie in the capture's host
+  plane on the trace's own clock, and cost nothing extra when none runs.
+  About 3 µs a span on the sandbox's CPU (PERF.md, PR 29).
+- ``event(name, **fields)`` is one discrete happening (a bucket program
+  compiled, a warmup finished, a heartbeat stalled): a no-op until a JSONL
+  sink is configured.
+
+The JSONL sink is the opt-in it always was: only entry points configure one,
+and then every ``event`` and every ``span`` (as one record carrying
+``dur_s``, ``ok``, ``error``) appends one JSON object per line to it.
 
 Every record carries DUAL clock stamps plus the writer's pid: ``t``
 (wall-clock epoch seconds — external log correlation and cross-process
@@ -37,15 +56,18 @@ emitting one span per request — can never grow the log unboundedly. Pass
 from __future__ import annotations
 
 import contextlib
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 import weakref
 from collections import deque
-from typing import Any, Dict, Iterator, Optional
+from typing import Any, Dict, List, Optional
 
-__all__ = ["EventLog", "configure_event_log", "event", "get_event_log", "span"]
+__all__ = ["EventLog", "SPAN_DEPTH", "add_span", "configure_event_log",
+           "event", "get_event_log", "span", "spans"]
 
 # rotation defaults: ~64 MB live segment + 3 rotated = a ~256 MB hard ceiling
 # per process, weeks of serving events at typical rates
@@ -344,18 +366,153 @@ def event(name: str, **fields: Any) -> None:
         log.write({"event": name, **fields})
 
 
-@contextlib.contextmanager
-def span(name: str, **fields: Any) -> Iterator[None]:
-    """Record a timed span as one event carrying ``dur_s`` (and ``ok=False``
-    plus the error type when the body raises)."""
-    if _LOG is None:  # stay free when unconfigured
-        yield
-        return
-    t0 = time.perf_counter()
-    try:
-        yield
-    except BaseException as e:
-        event(name, dur_s=round(time.perf_counter() - t0, 6), ok=False,
-              error=type(e).__name__, **fields)
-        raise
-    event(name, dur_s=round(time.perf_counter() - t0, 6), ok=True, **fields)
+# records kept per span name; a constant, not an option: what a reader needs
+# of one name (the set-up spans, the last window's steps) fits many times over
+SPAN_DEPTH = 4096
+
+
+class SpanSnapshot(list):
+    """What ``spans()`` returns: the records, oldest first, and ``dropped``,
+    the number of records each name's full buffer has let go so far."""
+
+    dropped: Dict[str, int]
+
+
+class SpanRecorder:
+    """The process's finished spans, in one bounded buffer per span name."""
+
+    # pitlint PIT-LOCK: spans finish on any thread (the fit loop, jax's
+    # compile path, engine workers) and readers snapshot from another
+    _guarded_by = {"_buffers": "_lock", "_dropped": "_lock"}
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._buffers: Dict[str, deque] = {}
+        self._dropped: Dict[str, int] = {}
+        self._ids = itertools.count(1)  # next() on it is atomic under the GIL
+        self._local = threading.local()  # .open: ids of this thread's open spans
+
+    def _open_ids(self) -> List[int]:
+        try:
+            return self._local.open
+        except AttributeError:
+            self._local.open = []
+            return self._local.open
+
+    def open(self) -> int:
+        """A new span begins on this thread: its id, now the innermost open."""
+        span_id = next(self._ids)
+        self._open_ids().append(span_id)
+        return span_id
+
+    def close(self, span_id: int) -> None:
+        try:
+            self._open_ids().remove(span_id)
+        except ValueError:
+            pass  # closed on another thread than the one that opened it
+
+    def add(self, name: str, start_ns: int, end_ns: int, ok: bool,
+            fields: Dict[str, Any], span_id: Optional[int] = None) -> None:
+        """Keep one finished span. Its parent is the innermost span open on
+        this thread now: spans close innermost first, so for a span that has
+        just closed that is the one that was open when it started."""
+        open_ids = self._open_ids()
+        record = {  # a new dict; its own keys last, so no field shadows them
+            **fields,
+            "name": name,
+            "id": next(self._ids) if span_id is None else span_id,
+            "parent": open_ids[-1] if open_ids else None,
+            "thread": threading.get_ident(),
+            "start_ns": start_ns,
+            "end_ns": end_ns,
+            "ok": ok,
+        }
+        with self._lock:
+            buf = self._buffers.get(name)
+            if buf is None:
+                buf = self._buffers[name] = deque(maxlen=SPAN_DEPTH)
+            elif len(buf) == SPAN_DEPTH:
+                self._dropped[name] = self._dropped.get(name, 0) + 1
+            buf.append(record)
+
+    def snapshot(self, name: Optional[str] = None) -> SpanSnapshot:
+        with self._lock:
+            if name is None:
+                records = [r for buf in self._buffers.values() for r in buf]
+            else:
+                records = list(self._buffers.get(name, ()))
+            dropped = dict(self._dropped)
+        out = SpanSnapshot(sorted(records, key=lambda r: (r["start_ns"], r["id"])))
+        out.dropped = dropped
+        return out
+
+
+_RECORDER = SpanRecorder()
+_TRACE_ANNOTATION = None  # jax.profiler.TraceAnnotation, once jax is imported
+
+
+def _trace_annotation():
+    """The profiler's annotation class if ``jax`` is already imported in the
+    process, else None. Never imports jax: entry points pick their platform
+    before the first import, and obs must not pre-empt that."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        _TRACE_ANNOTATION = getattr(profiler, "TraceAnnotation", None)
+    return _TRACE_ANNOTATION
+
+
+class span(contextlib.ContextDecorator):
+    """Time a stretch of the program as one span: ``with span("warmup",
+    engine="e1"):`` or ``@span("trainer.init")`` on a function (each call is
+    then its own span). The finished span is kept in memory (module
+    docstring), mirrored while open as the profiler annotation
+    ``pio.<name>``, and, with a JSONL sink configured, written as one event
+    carrying ``dur_s`` (and ``ok=False`` plus the error type when the body
+    raises)."""
+
+    def __init__(self, name: str, **fields: Any):
+        self.name = name
+        self.fields = fields
+
+    def _recreate_cm(self):  # decorator use: a fresh span per call
+        return span(self.name, **self.fields)
+
+    def __enter__(self) -> None:
+        self._id = _RECORDER.open()
+        cls = _trace_annotation()
+        self._annotation = None if cls is None else cls("pio." + self.name)
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self._start_ns = time.monotonic_ns()
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        end_ns = time.monotonic_ns()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        _RECORDER.close(self._id)
+        ok = exc_type is None
+        if _LOG is not None:
+            dur_s = round((end_ns - self._start_ns) / 1e9, 6)
+            if ok:
+                event(self.name, dur_s=dur_s, ok=True, **self.fields)
+            else:
+                event(self.name, dur_s=dur_s, ok=False,
+                      error=exc_type.__name__, **self.fields)
+        _RECORDER.add(self.name, self._start_ns, end_ns, ok, self.fields,
+                      self._id)
+
+
+def add_span(name: str, start_ns: int, end_ns: int, **fields: Any) -> None:
+    """Enter a finished span whose ends (``time.monotonic_ns()``) were taken
+    elsewhere: one append, kept in memory only (never written to the JSONL
+    sink, no profiler annotation). Its parent is the innermost span open on
+    the calling thread."""
+    _RECORDER.add(name, start_ns, end_ns, True, fields)
+
+
+def spans(name: Optional[str] = None) -> SpanSnapshot:
+    """A snapshot of the finished spans kept in memory (of one name, or of
+    all), oldest first; ``.dropped`` counts, per name, the records a full
+    buffer has let go."""
+    return _RECORDER.snapshot(name)

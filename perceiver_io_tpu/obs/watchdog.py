@@ -46,6 +46,34 @@ _COMPILE_COUNTERS: list = []  # list of weakref.ref[Counter]
 _COMPILE_LISTENER_INSTALLED = False
 _COMPILE_LOCK = threading.Lock()
 
+# jax.monitoring duration events of the compile path -> the span the listener
+# enters for each (obs.tracing.add_span; end = when the event fired, start =
+# end - duration). Trace events NEST (an inner jit traced inside the step's
+# trace fires its own): readers take the union of intervals, never the sum.
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.backend_compile",
+}
+# Tracing one flax step fires thousands of inner TRACE events of microseconds
+# each (8,711 / 11,558 under a millisecond in the set-up of the benchmark's two
+# cells, 0.32 / 0.62 s summed, beside 497 / 1,188 longer ones; PERF.md, PR
+# 29): entered one by one they would push the step's own trace out of the
+# bounded buffer. A trace event shorter than this is not entered;
+# most lie inside a longer trace span that is. Every lower and backend-compile
+# event is entered whatever its length (dozens a process).
+_MIN_TRACE_SPAN_NS = 1_000_000
+
+
+def _enter_compile_span(span_name: str, duration: float, **fields) -> None:
+    """One duration event of the compile path, fired at its end on the
+    thread that did the work."""
+    length_ns = int(duration * 1e9)
+    if span_name == "jax.trace" and length_ns < _MIN_TRACE_SPAN_NS:
+        return
+    end_ns = time.monotonic_ns()
+    tracing.add_span(span_name, end_ns - length_ns, end_ns, **fields)
+
 
 def install_compile_counter(registry=None):
     """Count every XLA backend compilation into the
@@ -61,6 +89,12 @@ def install_compile_counter(registry=None):
     which makes the counter a live recompile detector with the persistent
     cache on. One process-wide listener fans out to every registry that
     asked (tests use private registries; production uses the default one).
+
+    The same listener enters the compile path's spans (``_COMPILE_SPANS``):
+    ``jax.trace``, ``jax.lower`` and ``jax.backend_compile``, the last with
+    the field ``cache_hit`` from that pairing (on a hit its length is the
+    persistent cache's read + deserialise, on a miss XLA's compile). Trace
+    events under a millisecond are left out (``_MIN_TRACE_SPAN_NS``).
     """
     global _COMPILE_LISTENER_INSTALLED
     registry = registry or _registry_mod.get_registry()
@@ -84,9 +118,15 @@ def install_compile_counter(registry=None):
                         answered.hit = True
 
                 def _listener(name: str, duration: float, **kwargs) -> None:
-                    if not name.endswith("backend_compile_duration"):
+                    span_name = _COMPILE_SPANS.get(name)
+                    if span_name is None:
                         return
-                    if getattr(answered, "hit", False):
+                    if span_name != "jax.backend_compile":
+                        _enter_compile_span(span_name, duration)
+                        return
+                    hit = getattr(answered, "hit", False)
+                    _enter_compile_span(span_name, duration, cache_hit=hit)
+                    if hit:
                         answered.hit = False  # the persistent cache's, not XLA's
                         return
                     dead = False

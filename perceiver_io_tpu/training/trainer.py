@@ -56,6 +56,8 @@ Metrics = Dict[str, Any]
 # asks the fleet to checkpoint-and-exit at the next agreed step boundary
 _PREEMPT_BIT = 1
 
+_END = object()  # the train loader is exhausted
+
 
 @dataclasses.dataclass(frozen=True)
 class TrainerConfig:
@@ -228,6 +230,7 @@ class Trainer:
       tokens_per_example: when set, throughput is also logged as tokens/sec.
     """
 
+    @obs.span("trainer.init")
     def __init__(
         self,
         train_step: Callable,
@@ -418,6 +421,10 @@ class Trainer:
         self._coord_dispatch = 0
         self._last_val_metrics: Dict[str, float] = {}
         self._last_train_loss = float("nan")
+        # the open iteration of fit's dispatch loop, on time.monotonic_ns():
+        # its start, and the host's time so far in next() on the loader and
+        # in _dispatch (``_iterations``)
+        self._iter_start_ns = self._loader_ns = self._dispatch_ns = 0
 
         self._selfprof = None
         if config.selfprofile_every_n_steps > 0:
@@ -514,6 +521,41 @@ class Trainer:
                 "of device time.", stacklevel=2,
             )
 
+    def _iterations(self, loader):
+        """``_dispatch_batches`` with the iteration clock. An iteration of
+        ``fit``'s dispatch loop runs from one resumption of this generator to
+        the next, so each unit handed out becomes one ``train.step`` span
+        (``obs.spans("train.step")``, child of the call's ``train.fit``) when
+        the loop comes back for more; ``fit`` enters the one it breaks out
+        of. Fields, in nanoseconds: ``loader_ns``, the Trainer's own wait in
+        ``next()`` on the loader, and ``dispatch_ns``, its time inside
+        ``_dispatch`` (the host-to-device put plus the call of the jitted
+        step, which the runtime holds back once enough programs are in
+        flight). What is left of the iteration is the loop's own Python and,
+        where it has them, the log boundary's syncs, eval and checkpoints.
+        The wait that finds the loader exhausted belongs to no iteration."""
+        self._iter_start_ns = time.monotonic_ns()
+        self._loader_ns = self._dispatch_ns = 0
+        for unit in self._dispatch_batches(self._timed(loader)):
+            yield unit
+            self._end_iteration()
+
+    def _timed(self, loader):
+        src = iter(loader)
+        while True:
+            t0 = time.monotonic_ns()
+            batch = next(src, _END)
+            self._loader_ns += time.monotonic_ns() - t0
+            if batch is _END:
+                return
+            yield batch
+
+    def _end_iteration(self) -> None:
+        end_ns = time.monotonic_ns()
+        obs.add_span("train.step", self._iter_start_ns, end_ns,
+                     loader_ns=self._loader_ns, dispatch_ns=self._dispatch_ns)
+        self._iter_start_ns, self._loader_ns, self._dispatch_ns = end_ns, 0, 0
+
     def _dispatch_batches(self, loader):
         """Yield ``(batch, n_steps)`` dispatch units: single loader batches
         (K=1), or up to K of them stacked on a new leading scan axis. A
@@ -584,14 +626,19 @@ class Trainer:
     def _dispatch(self, batch):
         """One train dispatch; feeds the coordination flags when the
         multi-host agreement channel is active."""
-        # chaos hook over the HOST-LOCAL batch: nan = one host's shard
-        # corrupted (its NaN rides the global loss reduction to every peer —
-        # the agreement drill), hang/slow = a wedged/throttled host
-        batch = faults.fire("trainer.collective", batch)
-        gb = self._to_global(batch)
-        if self._coord:
-            return self._train_step(self.state, gb, self._local_flags_array())
-        return self._train_step(self.state, gb)
+        t0 = time.monotonic_ns()
+        try:
+            # chaos hook over the HOST-LOCAL batch: nan = one host's shard
+            # corrupted (its NaN rides the global loss reduction to every peer
+            # — the agreement drill), hang/slow = a wedged/throttled host
+            batch = faults.fire("trainer.collective", batch)
+            gb = self._to_global(batch)
+            if self._coord:
+                return self._train_step(
+                    self.state, gb, self._local_flags_array())
+            return self._train_step(self.state, gb)
+        finally:
+            self._dispatch_ns += time.monotonic_ns() - t0
 
     def _note_coord(self, metrics: Metrics, step_i: int) -> None:
         """Consume the agreed-flags output of THIS dispatch, and read the
@@ -857,11 +904,14 @@ class Trainer:
 
     # -- the loop ------------------------------------------------------------
 
+    @obs.span("train.fit")
     def fit(self, train_loader, val_loader=None):
         """Run the training loop; returns the final state.
 
         ``train_loader`` is re-iterated per epoch (fresh shuffle each time);
-        ``val_loader`` per validation pass.
+        ``val_loader`` per validation pass. Each call is one ``train.fit``
+        span (``obs.spans``), from entry to return, with one ``train.step``
+        span per iteration of the dispatch loop under it (``_iterations``).
         """
         cfg = self.config
         step_i = int(jax.device_get(self.state.step))
@@ -952,7 +1002,7 @@ class Trainer:
                     break
                 steps_this_epoch = 0
                 batches_this_epoch = 0
-                for batch, ksteps in self._dispatch_batches(train_loader):
+                for batch, ksteps in self._iterations(train_loader):
                     batches_this_epoch += 1
                     # single-process: act on the local flag directly;
                     # coordinated: only on the fleet-AGREED flag, which every
@@ -1105,6 +1155,7 @@ class Trainer:
                         self._publish(step_i)
 
                     if cfg.max_steps is not None and step_i >= cfg.max_steps:
+                        self._end_iteration()
                         done = True
                         break
                 if (self._agreed_preempt

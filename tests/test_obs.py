@@ -142,6 +142,201 @@ def test_event_log_span_and_event(tmp_path):
     assert rows[2]["ok"] is False and rows[2]["error"] == "RuntimeError"
 
 
+def _new_spans(since_id):
+    return [r for r in obs.spans() if r["id"] > since_id]
+
+
+def _last_span_id():
+    return max((r["id"] for r in obs.spans()), default=0)
+
+
+def test_span_records_start_end_parent_and_thread():
+    """Every finished span is kept in memory with its ends on the monotonic
+    clock, the span that was open around it on the same thread, and its
+    thread; a span on another thread has no parent here."""
+    since = _last_span_id()
+    lo = time.monotonic_ns()
+    with obs.span("t_outer", engine="e1"):
+        with obs.span("t_inner"):
+            pass
+
+        def on_thread():
+            with obs.span("t_thread"):
+                pass
+
+        worker = threading.Thread(target=on_thread)
+        worker.start()
+        worker.join(timeout=10)
+        assert not worker.is_alive()
+        obs.add_span("t_added", lo, lo + 5, n=1)
+    hi = time.monotonic_ns()
+    got = {r["name"]: r for r in _new_spans(since)}
+    outer, inner = got["t_outer"], got["t_inner"]
+    assert lo <= outer["start_ns"] <= inner["start_ns"] <= inner["end_ns"] \
+        <= outer["end_ns"] <= hi
+    assert outer["parent"] is None and inner["parent"] == outer["id"]
+    assert outer["engine"] == "e1" and outer["ok"]
+    assert outer["thread"] == threading.get_ident()
+    assert got["t_thread"]["parent"] is None
+    assert got["t_thread"]["thread"] != outer["thread"]
+    # a span measured elsewhere: its own ends, the open span as its parent
+    added = got["t_added"]
+    assert (added["start_ns"], added["end_ns"]) == (lo, lo + 5)
+    assert added["parent"] == outer["id"] and added["n"] == 1
+    # oldest first, and by name
+    assert [r["id"] for r in obs.spans("t_inner")][-1] == inner["id"]
+    starts = [r["start_ns"] for r in obs.spans()]
+    assert starts == sorted(starts)
+
+
+def test_span_exception_path_and_decorator():
+    since = _last_span_id()
+    with pytest.raises(RuntimeError):
+        with obs.span("t_boom"):
+            with obs.span("t_boom_child"):
+                raise RuntimeError("x")
+    with obs.span("t_after"):
+        pass
+
+    @obs.span("t_decorated", kind="call")
+    def work(x):
+        return x + 1
+
+    assert work(1) == 2 and work(2) == 3
+    got = _new_spans(since)
+    by_name = {r["name"]: r for r in got}
+    assert by_name["t_boom"]["ok"] is False
+    assert by_name["t_boom_child"]["ok"] is False
+    assert by_name["t_boom_child"]["parent"] == by_name["t_boom"]["id"]
+    # the failed spans were closed: the next one is nobody's child
+    assert by_name["t_after"]["parent"] is None and by_name["t_after"]["ok"]
+    calls = [r for r in got if r["name"] == "t_decorated"]
+    assert len(calls) == 2 and calls[0]["id"] != calls[1]["id"]
+    assert all(r["kind"] == "call" and r["ok"] for r in calls)
+    # a span object used twice gives two records, neither carrying the
+    # other's keys, and its own fields stay as they were given
+    twice = obs.span("t_twice", kind="with")
+    with twice:
+        pass
+    with twice:
+        pass
+    first, second = obs.spans("t_twice")[-2:]
+    assert first["id"] != second["id"] and first is not second
+    assert twice.fields == {"kind": "with"}
+
+
+def test_span_buffer_is_bounded_per_name():
+    """A flood of one name drops its own oldest records (counted) and can
+    never evict another name's."""
+    from perceiver_io_tpu.obs import tracing
+
+    with obs.span("t_rare"):
+        pass
+    rare = obs.spans("t_rare")[-1]
+    dropped_before = obs.spans().dropped.get("t_flood", 0)
+    held_before = len(obs.spans("t_flood"))
+    extra = 10
+    for i in range(tracing.SPAN_DEPTH - held_before + extra):
+        obs.add_span("t_flood", i, i + 1, i=i)
+    flood = obs.spans("t_flood")
+    assert len(flood) == tracing.SPAN_DEPTH
+    assert flood.dropped["t_flood"] == dropped_before + extra
+    assert flood[-1]["i"] == tracing.SPAN_DEPTH - held_before + extra - 1
+    assert obs.spans("t_rare")[-1] == rare
+    assert "t_rare" not in obs.spans().dropped
+
+
+def test_span_jsonl_record_is_unchanged(tmp_path):
+    """With a sink the span still writes ONE event with dur_s / ok / error
+    and the fields, and none of the in-memory record's keys."""
+    path = str(tmp_path / "events.jsonl")
+    obs.configure_event_log(path)
+    try:
+        with obs.span("t_jsonl", engine="e1"):
+            pass
+        obs.add_span("t_jsonl_added", 1, 2)  # memory only
+    finally:
+        obs.configure_event_log(None)
+    rows = [json.loads(l) for l in open(path)]
+    assert len(rows) == 1
+    assert set(rows[0]) == {"t", "mono", "pid", "event", "dur_s", "ok", "engine"}
+    assert obs.spans("t_jsonl")[-1]["engine"] == "e1"
+
+
+def test_spans_survive_concurrent_writers():
+    """More writers than cores under a short switch interval: no record is
+    lost (kept + dropped == written, per name) and ids stay unique."""
+    import sys
+
+    from perceiver_io_tpu.obs import tracing
+
+    writers, each = 16, 600
+    base_kept = len(obs.spans("t_shared"))
+    base_dropped = obs.spans().dropped.get("t_shared", 0)
+    since = _last_span_id()
+
+    def write(k):
+        for i in range(each):
+            with obs.span("t_shared"):
+                obs.add_span(f"t_own_{k}", i, i + 1)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=write, args=(k,)) for k in range(writers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    snap = obs.spans()
+    shared = [r for r in snap if r["name"] == "t_shared"]
+    assert (len(shared) - base_kept) + (snap.dropped.get("t_shared", 0)
+                                        - base_dropped) == writers * each
+    assert len(shared) <= tracing.SPAN_DEPTH
+    for k in range(writers):
+        own = [r for r in snap if r["name"] == f"t_own_{k}"]
+        assert len(own) == each
+        # each was entered inside ITS thread's open t_shared span
+        assert len({r["thread"] for r in own}) == 1
+    ids = [r["id"] for r in snap if r["id"] > since]
+    assert len(ids) == len(set(ids))
+
+
+def test_compile_listener_enters_trace_lower_compile_spans():
+    """The one jax.monitoring listener also enters the compile path's spans;
+    the count of real compilations keeps its meaning."""
+    counter = obs.install_compile_counter(obs.get_registry())
+    since, compiles = _last_span_id(), counter.value
+    lo = time.monotonic_ns()
+
+    def long_to_trace(x):  # trace events under a millisecond are not entered
+        for i in range(300):
+            x = jnp.tanh(x) * (1.0 + i) + 0.25
+        return x
+
+    jax.jit(long_to_trace)(jnp.ones((5, 3))).block_until_ready()
+    hi = time.monotonic_ns()
+    got = _new_spans(since)
+    for name in ("jax.trace", "jax.lower", "jax.backend_compile"):
+        mine = [r for r in got if r["name"] == name]
+        assert mine, (name, [r["name"] for r in got])
+        assert all(lo <= r["end_ns"] <= hi and r["start_ns"] <= r["end_ns"]
+                   for r in mine)
+    built = [r for r in got if r["name"] == "jax.backend_compile"]
+    assert all(r["cache_hit"] is False for r in built)  # conftest: cache off
+    assert counter.value - compiles == len(built)
+    # the short inner trace events (jnp.tanh's own jit, ...) are left out;
+    # every lower and backend compile is in, one of each per program
+    from perceiver_io_tpu.obs import watchdog
+
+    assert all(r["end_ns"] - r["start_ns"] >= watchdog._MIN_TRACE_SPAN_NS
+               for r in got if r["name"] == "jax.trace")
+    assert len([r for r in got if r["name"] == "jax.lower"]) == len(built)
+
+
 def test_event_log_size_capped_rotation(tmp_path):
     """A long load run cannot grow events.jsonl unboundedly: the sink
     rotates at max_bytes keeping N numbered segments, every surviving line

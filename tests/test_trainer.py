@@ -514,3 +514,85 @@ def test_empty_profile_trace_warns(tmp_path, monkeypatch):
     with trainer:
         with pytest.warns(UserWarning, match="EMPTY xplane"):
             trainer.fit(loaders[0], loaders[1])
+
+
+# -- spans inside the program (obs.tracing) ----------------------------------
+
+
+def _spans_since(since_id):
+    from perceiver_io_tpu import obs
+
+    return [r for r in obs.spans() if r["id"] > since_id]
+
+
+def _last_span_id():
+    from perceiver_io_tpu import obs
+
+    return max((r["id"] for r in obs.spans()), default=0)
+
+
+@pytest.mark.parametrize("variant", ["plain", "recovery", "stacked"])
+def test_fit_leaves_one_fit_span_and_a_record_per_iteration(tmp_path, variant):
+    """A fit of N steps is one ``train.fit`` span holding N ``train.step``
+    records (N / K under K-step dispatch) whose parts never exceed the
+    iteration: on the plain path, on the recovery path, and stacked."""
+    since = _last_span_id()
+    base, (train_loader, val_loader) = _make_parts(tmp_path)
+    base.close()
+    extra = {"recovery": {"skip_nonfinite_steps": True},
+             "stacked": {"steps_per_dispatch": 2}}.get(variant, {})
+    cfg = dataclasses.replace(base.config, max_epochs=None, max_steps=6,
+                              eval_every_n_steps=4, experiment=variant, **extra)
+    trainer = Trainer(base._raw_train_step, None, base.state, cfg,
+                      example_batch=base._example_batch)
+    assert trainer.config.recovery_active == (variant == "recovery")
+    with trainer:
+        state = trainer.fit(train_loader, ())
+    assert int(jax.device_get(state.step)) == 6
+    got = _spans_since(since)
+    fits = [r for r in got if r["name"] == "train.fit"]
+    assert len(fits) == 1
+    fit = fits[0]
+    k = 2 if variant == "stacked" else 1
+    assert fit["ok"]
+    steps = [r for r in got if r["name"] == "train.step"]
+    assert len(steps) == 6 // k
+    prev_end = fit["start_ns"]
+    for r in steps:
+        assert r["parent"] == fit["id"]
+        assert prev_end <= r["start_ns"] <= r["end_ns"] <= fit["end_ns"]
+        prev_end = r["end_ns"]
+        assert r["loader_ns"] > 0 and r["dispatch_ns"] > 0
+        assert r["loader_ns"] + r["dispatch_ns"] <= r["end_ns"] - r["start_ns"]
+    # the Trainer's own construction
+    inits = [r for r in got if r["name"] == "trainer.init"]
+    assert len(inits) == 2  # _make_parts' and this test's
+    assert all(r["ok"] and r["end_ns"] <= fit["start_ns"] for r in inits)
+
+
+def test_program_spans_lie_in_the_profilers_host_plane(tmp_path):
+    """Under an active profiler the program's spans are annotations on the
+    trace's own clock: the capture's host plane holds ``pio.train.fit``."""
+    trainer, (train_loader, _) = _make_parts(tmp_path)
+    trainer.config = dataclasses.replace(trainer.config, max_epochs=None, max_steps=4)
+    logdir = str(tmp_path / "capture")
+    with trainer:
+        trainer.fit(train_loader, ())  # compiled before the capture
+        trainer.config = dataclasses.replace(trainer.config, max_steps=8)
+        jax.profiler.start_trace(logdir)
+        try:
+            trainer.fit(train_loader, ())
+        finally:
+            jax.profiler.stop_trace()
+    import glob
+
+    (path,) = glob.glob(os.path.join(logdir, "plugins", "profile", "*", "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    names = {}
+    for plane in data.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for event in line.events:
+                    if event.name.startswith("pio."):
+                        names[event.name] = names.get(event.name, 0) + 1
+    assert names == {"pio.train.fit": 1}, names
