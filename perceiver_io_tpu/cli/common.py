@@ -234,13 +234,20 @@ def add_compute_args(parser: argparse.ArgumentParser) -> None:
                         "path with that sp routing; packed = experimental "
                         "small-latent kernel (PERF.md)")
     g.add_argument("--remat", action="store_true",
-                   help="rematerialize encoder layers (HBM for FLOPs)")
+                   help="rematerialize encoder layers (HBM for FLOPs). "
+                        "Selective where it pays: a cross-attention that "
+                        "materializes its logits over a long input (XLA "
+                        "path, >= 4096 positions) keeps logits, weighted sum "
+                        "and K/V while their bytes over all layers fit 40%% "
+                        "of the device's memory; otherwise, and for every "
+                        "other tensor, whole layers are recomputed")
     g.add_argument("--no_reuse_kv", action="store_true",
                    help="recompute the shared layer_n cross-attention K/V "
                         "projections per recurrent application instead of "
                         "caching them (the cache is exact and measured "
                         "faster — PERF.md r5; this is the off switch for "
-                        "A/Bs and minimal-live-memory remat runs)")
+                        "A/Bs and minimal-live-memory runs under a --remat "
+                        "that recomputes whole layers)")
     g.add_argument("--pad_vocab_multiple", type=int, default=None,
                    help="round the vocab/class projection width up to this "
                         "multiple (padded logits pinned to -1e30) so it "

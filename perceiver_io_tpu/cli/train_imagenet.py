@@ -53,7 +53,8 @@ def build_parser() -> argparse.ArgumentParser:
     t = parser.add_argument_group("task (ImageNet classification)")
     t.add_argument("--num_frequency_bands", type=int, default=64)
     t.add_argument("--no_remat", action="store_true",
-                   help="disable the remat-by-default applied at image_size ≥ 64")
+                   help="disable the remat-by-default applied at image_size ≥ 64 "
+                        "(see --remat for what it keeps and what it recomputes)")
     # Perceiver-paper ImageNet defaults (BASELINE.md tracked config)
     parser.set_defaults(experiment="imagenet", num_latents=512,
                         num_latent_channels=1024, num_encoder_layers=6,

@@ -21,7 +21,14 @@ Key structural semantics preserved:
 TPU-first choices: modules take a ``dtype`` (bfloat16 compute, f32 params),
 an ``attn_impl`` switch ('xla' einsum vs. fused Pallas kernel), and an
 optional ``remat`` flag that rematerializes each perceiver layer to trade
-FLOPs for HBM when the recurrent stack is deep.
+FLOPs for HBM when the recurrent stack is deep. Rematerialization is
+selective where it pays: a layer whose cross-attention materializes its
+logits over a long input (the XLA path, S >= ``AUTO_PALLAS_MIN_KV``) keeps
+those logits, the weighted sum's output and the K/V projections for its
+backward pass, as long as their bytes fit a share of the device's memory, and
+recomputes the rest (norms, q projection, softmax, MLP, the whole
+self-attention block); everywhere else the whole layer is recomputed
+(``PerceiverEncoder._remat_policy``).
 """
 
 from __future__ import annotations
@@ -32,10 +39,35 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from perceiver_io_tpu.ops.attention import CrossAttentionLayer, SelfAttentionBlock
+from perceiver_io_tpu import obs
+from perceiver_io_tpu.ops.attention import (
+    AUTO_PALLAS_MIN_KV,
+    REMAT_CROSS_CONTEXT,
+    REMAT_CROSS_KV,
+    REMAT_CROSS_LOGITS,
+    CrossAttentionLayer,
+    SelfAttentionBlock,
+    auto_attention_impl,
+)
 from perceiver_io_tpu.ops.masking import IGNORE_LABEL, TextMasking
+from perceiver_io_tpu.parallel.mesh import AXIS_DATA, active_step_mesh
 
 Array = jax.Array
+
+# The share of one device's memory (``bytes_limit``) that the residuals kept
+# under ``remat=True`` may take, all encoder layers together. Placed from chip
+# runs of the ImageNet configuration on a 16.9 GB v5e (PERF.md §6, PR 30): its
+# set is 34% of the chip at batch 8 (engaged, the peak is 10.4 GB) and 69% at
+# batch 16, where the engaged step does not fit beside the rest; at 40% the
+# switch lies where the engaged step is estimated at two thirds of the chip.
+REMAT_KEEP_FRACTION = 0.4
+
+
+def _device_bytes_limit() -> Optional[int]:
+    """``bytes_limit`` of one local device, or None where the backend reports
+    no memory statistics (the CPU)."""
+    stats = jax.local_devices()[0].memory_stats()
+    return stats.get("bytes_limit") if stats else None
 
 
 def latent_init(std: float = 0.02, clamp: float = 2.0):
@@ -119,11 +151,55 @@ class PerceiverEncoder(nn.Module):
     # reused, not re-derived); the win is mostly the BACKWARD projection pass
     # autodiff would otherwise emit per application — measured 2.3 ms/step on
     # the 131k-token MLM config (PERF.md r5). Off: recompute per application
-    # (marginally less live memory under remat).
+    # (marginally less live memory under a remat that recomputes whole
+    # layers; where remat keeps the cross-attention's residuals, every
+    # application then keeps a K/V set of its own).
     reuse_kv: bool = True
 
-    def _make_layer(self, name: str) -> nn.Module:
-        cls = nn.remat(PerceiverLayer) if self.remat else PerceiverLayer
+    def _remat_policy(self, b: int, s: int):
+        """The ``jax.checkpoint`` policy of the layers under ``remat=True``
+        for a (B, S, C) input; None recomputes whole layers (the bare
+        ``nn.remat``). Decided once per trace, from shapes and the device,
+        and reported as one ``remat.policy`` event.
+
+        The ``REMAT_CROSS_*`` residuals are kept when (1) the cross-attention
+        takes the XLA path over a long stream, S >= ``AUTO_PALLAS_MIN_KV``
+        (recomputing its logits costs 2d operations per byte kept away; the
+        Pallas kernels carry residuals of their own; a short stream's logits
+        are not worth keeping), and (2) their bytes over all layers, per
+        device where a mesh shares the batch, fit ``REMAT_KEEP_FRACTION`` of
+        the device's memory. The shared layer's K/V is an output of its first
+        application either way: naming it only saves its recomputation.
+        """
+        t, e = self.latent_shape
+        h = self.num_cross_attention_heads
+        impl = self.attn_impl
+        if impl == "auto":
+            impl = auto_attention_impl(b, t, s, h, e // h)
+        saved = 0
+        if impl == "xla" and s >= AUTO_PALLAS_MIN_KV:
+            shared = self.num_layers - 1
+            kv_sets = 1 + (min(shared, 1) if self.reuse_kv else shared)
+            saved = (self.num_layers * (b * h * t * s + b * t * e)
+                     + kv_sets * 2 * b * s * e) * jnp.dtype(self.dtype).itemsize
+            mesh = active_step_mesh()
+            shards = mesh.shape.get(AXIS_DATA, 1) if mesh is not None else 1
+            if b % shards == 0:
+                saved //= shards
+        limit = _device_bytes_limit()
+        budget = None if limit is None else int(limit * REMAT_KEEP_FRACTION)
+        engaged = budget is not None and 0 < saved <= budget
+        obs.event("remat.policy", engaged=engaged, layers=self.num_layers,
+                  saved_bytes=saved, budget_bytes=budget)
+        if not engaged:
+            return None
+        return jax.checkpoint_policies.save_only_these_names(
+            REMAT_CROSS_LOGITS, REMAT_CROSS_CONTEXT, REMAT_CROSS_KV)
+
+    def _make_layer(self, name: str, remat_policy=None) -> nn.Module:
+        cls = PerceiverLayer
+        if self.remat:
+            cls = nn.remat(PerceiverLayer, policy=remat_policy)
         return cls(
             num_latent_channels=self.latent_shape[1],
             num_input_channels=self.input_adapter.num_input_channels,
@@ -146,7 +222,8 @@ class PerceiverEncoder(nn.Module):
         latent = self.param("latent", latent_init(), self.latent_shape)
         x_latent = jnp.broadcast_to(latent.astype(self.dtype), (b, *self.latent_shape))
 
-        x_latent, _ = self._make_layer("layer_1")(
+        policy = self._remat_policy(b, x.shape[1]) if self.remat else None
+        x_latent, _ = self._make_layer("layer_1", policy)(
             x_latent, x, pad_mask=pad_mask, deterministic=deterministic
         )
         if self.num_layers > 1:
@@ -154,7 +231,7 @@ class PerceiverEncoder(nn.Module):
             # (reference model.py:162-166,185-187). Its K/V projection of the
             # (unchanging) input is identical across applications — cache and
             # reuse it (reuse_kv above).
-            layer_n = self._make_layer("layer_n")
+            layer_n = self._make_layer("layer_n", policy)
             kv = None
             for _ in range(self.num_layers - 1):
                 x_latent, kv_out = layer_n(

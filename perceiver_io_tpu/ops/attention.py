@@ -52,6 +52,7 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+from jax.ad_checkpoint import checkpoint_name
 
 # Every projection in this module applies through ``linear_apply``: plain
 # tensors take the exact flax-Dense math (promote_dtype + x @ w + b), while
@@ -91,6 +92,19 @@ AUTO_PALLAS_MIN_KV = 4096
 AUTO_PALLAS_MAX_HEAD_DIM = 512
 AUTO_PALLAS_MIN_LOGITS = 32 * 1024 * 1024  # B·H·T·S elements
 AUTO_PALLAS_AREA_MIN_HEAD_DIM = 32
+
+# ``jax.checkpoint`` names of what the XLA path of a CROSS-attention leaves
+# for its backward pass: the materialized (B, H, T, S) logits, the weighted
+# sum's (B, T, E) output (``out_proj``'s weight gradient reads it), and the
+# (k, v) projected here. A rematerialization policy that names them keeps
+# them (``PerceiverEncoder``, ``remat=True``); recomputing the logits and the
+# weighted sum costs two S-long matmuls, 2d operations per logit byte. Under a
+# bare ``jax.checkpoint`` and outside one the names lower to nothing.
+# Self-attention logits are not named: a latent stack's residuals are many
+# and small, and stay recomputed.
+REMAT_CROSS_LOGITS = "cross_attention_logits"
+REMAT_CROSS_CONTEXT = "cross_attention_context"
+REMAT_CROSS_KV = "cross_attention_kv"
 
 
 def auto_attention_impl(
@@ -138,12 +152,15 @@ def _dot_product_attention(
     dropout_rate: float,
     dropout_rng: Optional[Array],
     deterministic: bool,
+    name_residuals: bool = False,
 ) -> Array:
     """Scaled dot-product attention over (B, T, H, D) tensors.
 
     pad_mask: (B, S) bool, True = position is padding (masked OUT) — the
     ``key_padding_mask`` convention of the reference's torch MHA.
     attn_mask: (T, S) or (B, T, S) additive-style bool, True = masked OUT.
+    name_residuals: give the logits and the output their ``REMAT_CROSS_*``
+    checkpoint names (cross-attention callers).
     """
     d = q.shape[-1]
     scale = d**-0.5
@@ -173,6 +190,8 @@ def _dot_product_attention(
         if attn_mask.ndim == 2:
             attn_mask = attn_mask[None]
         logits = jnp.where(attn_mask[:, None, :, :], neg, logits)
+    if name_residuals:
+        logits = checkpoint_name(logits, REMAT_CROSS_LOGITS)
 
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
 
@@ -181,7 +200,10 @@ def _dot_product_attention(
         probs = jnp.where(keep, probs / (1.0 - dropout_rate), 0.0)
 
     probs = probs.astype(v.dtype)
-    return jnp.einsum("bhts,bshd->bthd", probs, v, precision=precision)
+    out = jnp.einsum("bhts,bshd->bthd", probs, v, precision=precision)
+    if name_residuals:
+        out = checkpoint_name(out, REMAT_CROSS_CONTEXT)
+    return out
 
 
 class _LinearParams(nn.Module):
@@ -322,6 +344,12 @@ class MultiHeadAttention(nn.Module):
             q = linear_apply(x_q, wq, bq, self.dtype)
             k = linear_apply(x_kv, wk, bk, self.dtype)
             v = linear_apply(x_kv, wv, bv, self.dtype)
+            if return_kv:
+                # projected HERE and handed on (the encoder's shared-layer
+                # cache): a caller's cached ``kv`` above is an input of the
+                # layer and needs no name
+                k = checkpoint_name(k, REMAT_CROSS_KV)
+                v = checkpoint_name(v, REMAT_CROSS_KV)
 
         b, t = q.shape[:2]
         s = k.shape[1]
@@ -427,6 +455,7 @@ class MultiHeadAttention(nn.Module):
                 q.reshape(b, t, h, d), k.reshape(b, s, h, d),
                 v.reshape(b, s, h, d), pad_mask, attn_mask,
                 self.dropout, dropout_rng, deterministic,
+                name_residuals=x_q is not x_kv,
             ).reshape(b, t, e)
         wo, bo = _LinearParams(e, e, kernel_init=torch_linear_kernel_init,
                                name="out_proj")()

@@ -93,6 +93,30 @@ def active_sequence_parallel() -> Optional[SequenceParallelContext]:
     return _ACTIVE_SP.get()
 
 
+_STEP_MESH: contextvars.ContextVar[Optional[Mesh]] = (
+    contextvars.ContextVar("perceiver_io_tpu_step_mesh", default=None)
+)
+
+
+@contextlib.contextmanager
+def step_mesh_context(mesh: Mesh):
+    """Name the mesh a step is traced for. ``jax.jit(in_shardings=...)`` shows
+    the traced code global shapes only; ``make_sharded_train_step`` wraps the
+    step with this so that model code which reckons bytes per device at trace
+    time (the encoder's rematerialization policy) can ask how many devices
+    share the batch."""
+    token = _STEP_MESH.set(mesh)
+    try:
+        yield
+    finally:
+        _STEP_MESH.reset(token)
+
+
+def active_step_mesh() -> Optional[Mesh]:
+    """The mesh of :func:`step_mesh_context`, or None (plain ``jax.jit``)."""
+    return _STEP_MESH.get()
+
+
 def initialize_distributed(
     coordinator_address: Optional[str] = None,
     num_processes: Optional[int] = None,

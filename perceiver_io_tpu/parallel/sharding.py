@@ -24,6 +24,7 @@ Tensor-parallel layout (Megatron-style pairing, per attention/MLP block):
 
 from __future__ import annotations
 
+import contextlib
 import re
 from typing import Any, Dict, Sequence, Tuple
 
@@ -37,6 +38,7 @@ from perceiver_io_tpu.parallel.mesh import (
     AXIS_MODEL,
     AXIS_SEQ,
     sequence_parallel_context,
+    step_mesh_context,
 )
 
 
@@ -419,20 +421,24 @@ def make_sharded_train_step(
     sharded_state, state_shardings = shard_train_state(state, mesh, rules, zero_opt=zero_opt)
     b_shardings = batch_shardings(example_batch, mesh, shard_seq, stacked)
 
-    if shard_seq and mesh.shape[AXIS_SEQ] > 1:
+    sp = shard_seq and mesh.shape[AXIS_SEQ] > 1
+    if sp:
         # Runtime canary (VERDICT r3 item 6): fail loudly AT SETUP if a JAX
         # upgrade changed the shard_map transpose convention _sp_bwd encodes,
         # instead of training with silently rescaled gradients.
         sp_gradient_canary(mesh)
-        # Activate sequence-parallel kernel routing for every (re)trace: the
-        # encoder cross-attention (seq_shard_kv) then runs its Pallas path
-        # under shard_map with S/n KV per device instead of letting GSPMD
-        # all-gather the stream around the pallas_call.
-        inner_step = train_step
+    inner_step = train_step
 
-        def train_step(state, batch):  # noqa: F811 — deliberate rebind
-            with sequence_parallel_context(mesh):
-                return inner_step(state, batch)
+    def train_step(state, batch):  # noqa: F811 — deliberate rebind
+        # Every (re)trace sees the mesh it is traced for (per-device byte
+        # reckoning in the model) and, under shard_seq, sequence-parallel
+        # kernel routing: the encoder cross-attention (seq_shard_kv) then
+        # runs its Pallas path under shard_map with S/n KV per device instead
+        # of letting GSPMD all-gather the stream around the pallas_call.
+        with step_mesh_context(mesh), (
+                sequence_parallel_context(mesh) if sp
+                else contextlib.nullcontext()):
+            return inner_step(state, batch)
 
     if coord_flags:
         flags_sharding = coord_flags_sharding(mesh)

@@ -1,5 +1,9 @@
 """Structural tests for the Perceiver core: weight sharing, shapes, masking flow."""
 
+import contextlib
+import json
+import re
+
 import numpy as np
 import pytest
 import jax
@@ -377,6 +381,168 @@ class TestSharedLayerKVReuse:
         # element's own size (observed 9e-5 on a 0.05-scale element).
         for a, b in zip(jax.tree_util.tree_leaves(g), jax.tree_util.tree_leaves(gr)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5, atol=2e-4)
+
+
+class TestSelectiveRemat:
+    """``remat=True`` keeps the long cross-attention's residuals (logits,
+    weighted sum, K/V) where their bytes fit a share of the device's memory,
+    and recomputes whole layers everywhere else (models/perceiver.py
+    ``_remat_policy``). The device is the CPU here, which reports no memory
+    limit: the tests put one in through ``_device_bytes_limit``, the one
+    function that reads the device."""
+
+    B, T, HEADS = 2, 8, 4
+
+    @staticmethod
+    def _text_encoder(remat):
+        return TestSharedLayerKVReuse()._encoder(True, remat=remat)
+
+    def _tokens(self):
+        return jnp.asarray(
+            np.random.default_rng(4).integers(0, VOCAB, (self.B, MAX_LEN)), jnp.int32)
+
+    @staticmethod
+    def _image_encoder():
+        # 64 x 64 pixels = 4096 positions: AUTO_PALLAS_MIN_KV, a long stream
+        return PerceiverEncoder(
+            input_adapter=ImageInputAdapter(image_shape=(64, 64, 3),
+                                            num_frequency_bands=4),
+            latent_shape=(8, 32), num_layers=3, num_cross_attention_heads=1,
+            num_self_attention_heads=4, num_self_attention_layers_per_block=1,
+            dtype=jnp.bfloat16, remat=True)
+
+    @pytest.fixture
+    def short_streams_count(self, monkeypatch):
+        """MAX_LEN tokens pass for a long stream, so that the parity cases
+        run at the shapes whose tolerance ``test_remat_composes_with_reuse``
+        set."""
+        from perceiver_io_tpu.models import perceiver
+
+        monkeypatch.setattr(perceiver, "AUTO_PALLAS_MIN_KV", MAX_LEN)
+        return perceiver
+
+    @pytest.mark.parametrize("case", ["no_remat", "bare", "engaged", "fallen_back"])
+    def test_output_and_gradients_match_unrematerialised(
+            self, case, short_streams_count, monkeypatch, capsys):
+        limit = {"no_remat": None, "bare": None, "engaged": 16e9,
+                 "fallen_back": 1000}[case]
+        monkeypatch.setattr(short_streams_count, "_device_bytes_limit", lambda: limit)
+        x = self._tokens()
+        enc, enc_r = self._text_encoder(False), self._text_encoder(case != "no_remat")
+        v = enc.init({"params": jax.random.key(0)}, x)
+        assert bool((enc_r.apply(v, x) == enc.apply(v, x)).all())
+
+        def loss(params, e):
+            return jnp.sum(e.apply({"params": params}, x) ** 2)
+
+        g, gr = jax.grad(loss)(v["params"], enc), jax.grad(loss)(v["params"], enc_r)
+        # test_remat_composes_with_reuse's tolerance, for its reasons
+        for a, b in zip(jax.tree_util.tree_leaves(g), jax.tree_util.tree_leaves(gr)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5, atol=2e-4)
+
+        # what the backward pass keeps, by shape: cross logits (B, H, T, S),
+        # weighted sum (B, T, H, D), K/V (B, S, E); self logits (B, H, T, T)
+        capsys.readouterr()
+        jax.ad_checkpoint.print_saved_residuals(
+            lambda params: loss(params, enc_r), v["params"])
+        kept = [line.split()[0] for line in capsys.readouterr().out.splitlines()
+                if "from the argument" not in line]
+        b, t, h = self.B, self.T, self.HEADS
+        cross_logits = f"f32[{b},{h},{t},{MAX_LEN}]"
+        context = f"f32[{b},{t},{h},{C // h}]"
+        kv = f"f32[{b},{MAX_LEN},{C}]"  # the adapted input's shape too: one more
+        self_logits = f"f32[{b},{h},{t},{t}]"
+        if case == "engaged":
+            # 3 layers; layer_1's K/V and the shared layer's one set
+            assert kept.count(cross_logits) == 3 and kept.count(context) == 3
+            assert kept.count(kv) == 1 + 4
+            assert self_logits not in kept
+        elif case != "no_remat":
+            assert not {cross_logits, context, self_logits} & set(kept)
+            # the shared layer's K/V: an output of its first application
+            assert kept.count(kv) == 1 + 2
+
+    def test_engaged_policy_recomputes_less_than_bare_more_than_none(
+            self, short_streams_count, monkeypatch):
+        x = self._tokens()
+        enc = self._text_encoder(False)
+        params = enc.init({"params": jax.random.key(0)}, x)["params"]
+
+        def flops(remat, limit):
+            monkeypatch.setattr(short_streams_count, "_device_bytes_limit", lambda: limit)
+            e = self._text_encoder(remat)
+            grad = jax.grad(lambda p: jnp.sum(e.apply({"params": p}, x) ** 2))
+            return jax.jit(grad).lower(params).compile().cost_analysis()["flops"]
+
+        none, bare, engaged = flops(False, None), flops(True, None), flops(True, 16e9)
+        assert none < engaged < bare
+
+    def test_names_lower_to_nothing_without_remat(self, monkeypatch):
+        from perceiver_io_tpu.ops import attention
+
+        x = self._tokens()
+        enc = self._text_encoder(False)
+        params = enc.init({"params": jax.random.key(0)}, x)["params"]
+
+        def lowered():
+            grad = jax.grad(lambda p: jnp.sum(enc.apply({"params": p}, x) ** 2))
+            text = jax.jit(grad).lower(params).as_text()
+            # the names shift the numbers MLIR's symbol table gives private
+            # functions (@_where_257 -> @_where_261), and nothing else
+            return re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
+
+        named = lowered()
+        assert "stablehlo.dot_general" in named
+        monkeypatch.setattr(attention, "checkpoint_name", lambda x, name: x)
+        assert lowered() == named
+
+    @pytest.fixture
+    def events(self, tmp_path):
+        from perceiver_io_tpu import obs
+
+        path = tmp_path / "events.jsonl"
+        obs.configure_event_log(str(path))
+
+        def read():
+            obs.configure_event_log(None)  # drains, then closes
+            with open(path) as f:
+                records = [json.loads(line) for line in f]
+            return [r for r in records if r.get("event") == "remat.policy"]
+
+        yield read
+        obs.configure_event_log(None)
+
+    @pytest.mark.parametrize("case", ["engaged", "over_budget", "no_limit", "dp_mesh"])
+    def test_remat_policy_event(self, case, events, monkeypatch):
+        from perceiver_io_tpu.models import perceiver
+        from perceiver_io_tpu.parallel import make_mesh
+        from perceiver_io_tpu.parallel.mesh import step_mesh_context
+
+        b, s, t, e, layers = 8, 64 * 64, 8, 32, 3
+        # bf16: logits + weighted sum per layer, layer_1's and the shared K/V
+        reckoned = (layers * (b * t * s + b * t * e) + 2 * 2 * b * s * e) * 2
+        limit = {"engaged": 16e9, "dp_mesh": 16e9, "no_limit": None,
+                 "over_budget": reckoned / perceiver.REMAT_KEEP_FRACTION - 8}[case]
+        monkeypatch.setattr(perceiver, "_device_bytes_limit", lambda: limit)
+        enc = self._image_encoder()
+        image = jax.ShapeDtypeStruct((b, 64, 64, 3), jnp.float32)
+        params = jax.eval_shape(
+            lambda x: enc.init({"params": jax.random.key(0)}, x), image)["params"]
+        wrapped = []
+        monkeypatch.setattr(
+            perceiver.nn, "remat",
+            lambda cls, policy=None: wrapped.append(policy) or cls)
+        with (step_mesh_context(make_mesh(dp=8)) if case == "dp_mesh"
+              else contextlib.nullcontext()):
+            jax.eval_shape(lambda p, x: enc.apply({"params": p}, x), params, image)
+        record = events()[-1]
+        engaged = case in ("engaged", "dp_mesh")
+        assert record["engaged"] is engaged and record["layers"] == layers
+        assert record["saved_bytes"] == (reckoned // 8 if case == "dp_mesh" else reckoned)
+        assert record["budget_bytes"] == (
+            None if limit is None else int(limit * perceiver.REMAT_KEEP_FRACTION))
+        # layer_1 and layer_n of the traced apply: a policy each, or the bare remat
+        assert len(wrapped) == 2 and all((p is not None) == engaged for p in wrapped)
 
 
 def test_scaled_embed_matches_post_scale_bitwise():
