@@ -57,6 +57,11 @@ MASK_VALUE = -1e30
 PAD_BIAS = 2.0 * MASK_VALUE
 
 _LANES = 128
+# ``name=`` of the three pallas_calls: what a device trace calls them, and a
+# scope of their own in it (``.../fused_attention_fwd/pallas_call``)
+KERNEL_FWD = "fused_attention_fwd"
+KERNEL_DQ = "fused_attention_dq"
+KERNEL_DKV = "fused_attention_dkv"
 DEFAULT_KV_BLOCK = 512
 DEFAULT_Q_BLOCK = 512
 # Test hook (tests/test_pallas_attention.py fuzz): force the COMPILED lane
@@ -187,15 +192,42 @@ def _causal_bias(t_blk: int, s_blk: int, t_idx, s_idx, offset: int):
     return jnp.where(cols > rows + offset, MASK_VALUE, 0.0)
 
 
+def _tile_visible(t_idx, s_idx, t_blk: int, s_blk: int, offset: int):
+    """False where the causal rule masks the whole (T_blk, S_blk) tile: its
+    first key lies past the last query row's ``row + offset``."""
+    return s_idx * s_blk <= t_idx * t_blk + t_blk - 1 + offset
+
+
+def _last_visible_kv(t_idx, t_blk: int, s_blk: int, offset: int):
+    """Index of the last KV block that query block ``t_idx`` can see."""
+    return (t_idx * t_blk + t_blk - 1 + offset) // s_blk
+
+
+def _kv_block_index(t_idx, s_idx, *, t_blk: int, s_blk: int, offset: Optional[int],
+                    skip_masked: bool):
+    """The KV block a grid step fetches: its own, or with ``skip_masked`` the
+    last one its query block can see (an unchanged index is not copied)."""
+    if not skip_masked:
+        return s_idx
+    return jnp.minimum(s_idx, _last_visible_kv(t_idx, t_blk, s_blk, offset))
+
+
+def _first_visible_q(s_idx, t_blk: int, s_blk: int, offset: int):
+    """Index of the first query block that can see KV block ``s_idx``."""
+    return jnp.maximum(s_idx * s_blk - offset, 0) // t_blk
+
+
 def _attention_kernel(bias_ref, q_ref, k_ref, v_ref, out_ref, *rest,
                       scale: float, with_lse: bool,
-                      causal_offset: Optional[int]):
+                      causal_offset: Optional[int], skip_masked: bool):
     if with_lse:
         m_out, l_out, m_ref, l_ref, acc_ref = rest
         lse_ref = (m_out, l_out)
     else:
         lse_ref, (m_ref, l_ref, acc_ref) = None, rest
-    s_idx = pl.program_id(3)
+    # read outside the conditional tile body (interpret mode cannot lower a
+    # program_id inside a cond)
+    t_idx, s_idx = pl.program_id(2), pl.program_id(3)
 
     @pl.when(s_idx == 0)
     def _init():
@@ -203,26 +235,33 @@ def _attention_kernel(bias_ref, q_ref, k_ref, v_ref, out_ref, *rest,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0]  # (T_blk, D)
-    k = k_ref[0, 0]  # (S_blk, D)
-    logits = _dot(q, k, (1, 1)) * scale  # (T_blk, S_blk)
-    logits += bias_ref[0]  # (1, S_blk) broadcasts over T_blk
-    if causal_offset is not None:
-        logits += _causal_bias(q.shape[0], k.shape[0], pl.program_id(2),
-                               s_idx, causal_offset)
+    def _tile():
+        q = q_ref[0, 0]  # (T_blk, D)
+        k = k_ref[0, 0]  # (S_blk, D)
+        logits = _dot(q, k, (1, 1)) * scale  # (T_blk, S_blk)
+        logits += bias_ref[0]  # (1, S_blk) broadcasts over T_blk
+        if causal_offset is not None:
+            logits += _causal_bias(q.shape[0], k.shape[0], t_idx, s_idx,
+                                   causal_offset)
 
-    m_prev = m_ref[:, :1]  # (T_blk, 1)
-    l_prev = l_ref[:, :1]
-    m_cur = jnp.max(logits, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(logits - m_new)  # (T_blk, S_blk)
+        m_prev = m_ref[:, :1]  # (T_blk, 1)
+        l_prev = l_ref[:, :1]
+        m_cur = jnp.max(logits, axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(logits - m_new)  # (T_blk, S_blk)
 
-    l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-    pv = _dot(p.astype(v_ref.dtype), v_ref[0, 0], (1, 0))  # (T_blk, D)
-    acc_ref[:] = acc_ref[:] * alpha + pv
-    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        pv = _dot(p.astype(v_ref.dtype), v_ref[0, 0], (1, 0))  # (T_blk, Dv)
+        acc_ref[:] = acc_ref[:] * alpha + pv
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    if skip_masked:
+        pl.when(_tile_visible(t_idx, s_idx, q_ref.shape[2],
+                              k_ref.shape[2], causal_offset))(_tile)
+    else:
+        _tile()
 
     @pl.when(s_idx == pl.num_programs(3) - 1)
     def _finish():
@@ -236,27 +275,34 @@ def _attention_kernel(bias_ref, q_ref, k_ref, v_ref, out_ref, *rest,
 @functools.partial(
     jax.jit,
     static_argnames=("t_blk", "s_blk", "interpret", "with_lse",
-                     "causal_offset"),
+                     "causal_offset", "skip_masked"),
 )
 def _fused_attention_fwd_impl(
     q: Array, k: Array, v: Array, bias: Array,
     t_blk: int, s_blk: int, interpret: bool, with_lse: bool = False,
-    causal_offset: Optional[int] = None,
+    causal_offset: Optional[int] = None, skip_masked: bool = False,
 ):
-    """(B, H, T, D) q against (B, H, S, D) k/v with (B, S) additive bias.
-    ``t_blk``/``s_blk`` must divide T/S (the wrapper guarantees it).
+    """(B, H, T, D) q against (B, H, S, D) k and (B, H, S, Dv) v with (B, S)
+    additive bias; the output is (B, H, T, Dv). ``t_blk``/``s_blk`` must
+    divide T/S (the wrapper guarantees it).
     With ``with_lse`` also returns the softmax running max ``m`` and
     denominator ``l``, each lane-broadcast to (B, H, T, LANES) f32, for the
     fused backward. They are saved separately — not as ``m + log l`` — so a
     fully padded row (m pinned at MASK_VALUE, which absorbs log l in f32)
-    still recomputes exactly as exp(logits − m)/l."""
+    still recomputes exactly as exp(logits − m)/l.
+    ``skip_masked``: tiles that the causal rule masks whole are neither
+    computed nor fetched (the KV index stays at the last visible block, and
+    a block whose index does not change is not copied again)."""
     b, h, t, d = q.shape
-    s = k.shape[2]
+    s, dv = k.shape[2], v.shape[3]
     scale = d**-0.5
     grid = (b, h, t // t_blk, s // s_blk)
 
-    out_shape = jax.ShapeDtypeStruct((b, h, t, d), q.dtype)
-    out_specs = pl.BlockSpec((1, 1, t_blk, d), lambda bi, hi, ti, si: (bi, hi, ti, 0))
+    kv_block = functools.partial(_kv_block_index, t_blk=t_blk, s_blk=s_blk,
+                                 offset=causal_offset, skip_masked=skip_masked)
+
+    out_shape = jax.ShapeDtypeStruct((b, h, t, dv), q.dtype)
+    out_specs = pl.BlockSpec((1, 1, t_blk, dv), lambda bi, hi, ti, si: (bi, hi, ti, 0))
     if with_lse:
         lm_shape = jax.ShapeDtypeStruct((b, h, t, _LANES), jnp.float32)
         lm_spec = pl.BlockSpec((1, 1, t_blk, _LANES),
@@ -267,21 +313,24 @@ def _fused_attention_fwd_impl(
     bias = bias[:, None, :]  # (B, 1, S)
     kernel = pl.pallas_call(
         functools.partial(_attention_kernel, scale=scale, with_lse=with_lse,
-                          causal_offset=causal_offset),
+                          causal_offset=causal_offset, skip_masked=skip_masked),
         grid=grid,
         in_specs=[
             # (B, 1, S) so the block's trailing dims satisfy TPU tiling
-            pl.BlockSpec((1, 1, s_blk), lambda bi, hi, ti, si: (bi, 0, si)),
+            pl.BlockSpec((1, 1, s_blk),
+                         lambda bi, hi, ti, si: (bi, 0, kv_block(ti, si))),
             pl.BlockSpec((1, 1, t_blk, d), lambda bi, hi, ti, si: (bi, hi, ti, 0)),
-            pl.BlockSpec((1, 1, s_blk, d), lambda bi, hi, ti, si: (bi, hi, si, 0)),
-            pl.BlockSpec((1, 1, s_blk, d), lambda bi, hi, ti, si: (bi, hi, si, 0)),
+            pl.BlockSpec((1, 1, s_blk, d),
+                         lambda bi, hi, ti, si: (bi, hi, kv_block(ti, si), 0)),
+            pl.BlockSpec((1, 1, s_blk, dv),
+                         lambda bi, hi, ti, si: (bi, hi, kv_block(ti, si), 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((t_blk, _LANES), jnp.float32),  # running max
             pltpu.VMEM((t_blk, _LANES), jnp.float32),  # running denominator
-            pltpu.VMEM((t_blk, d), jnp.float32),  # PV accumulator
+            pltpu.VMEM((t_blk, dv), jnp.float32),  # PV accumulator
         ],
         compiler_params=pltpu.CompilerParams(
             # batch/head/query-block grid steps are independent; only the KV
@@ -289,6 +338,7 @@ def _fused_attention_fwd_impl(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name=KERNEL_FWD,
     )
     return kernel(bias, q, k, v)
 
@@ -309,7 +359,7 @@ def _recompute_probs_and_ds(bias_ref, q_ref, k_ref, v_ref, g_ref,
     passes whichever program_id carries each axis)."""
     q = q_ref[0, 0]  # (T_blk, D)
     k = k_ref[0, 0]  # (S_blk, D)
-    g = g_ref[0, 0]  # (T_blk, D)
+    g = g_ref[0, 0]  # (T_blk, Dv)
     logits = _dot(q, k, (1, 1)) * scale  # (T_blk, S_blk)
     logits += bias_ref[0]  # (1, S_blk) broadcasts over T_blk
     if causal_offset is not None:
@@ -326,19 +376,26 @@ def _recompute_probs_and_ds(bias_ref, q_ref, k_ref, v_ref, g_ref,
 
 def _bwd_dq_kernel(bias_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, di_ref,
                    dq_ref, acc_ref, *, scale: float,
-                   causal_offset: Optional[int]):
-    s_idx = pl.program_id(3)
+                   causal_offset: Optional[int], skip_masked: bool):
+    t_idx, s_idx = pl.program_id(2), pl.program_id(3)
 
     @pl.when(s_idx == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    _, ds, _, k, _ = _recompute_probs_and_ds(
-        bias_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, di_ref,
-        scale=scale, causal_offset=causal_offset,
-        t_idx=pl.program_id(2), s_idx=s_idx,
-    )
-    acc_ref[:] += _dot(ds.astype(k.dtype), k, (1, 0))  # (T_blk, D)
+    def _tile():
+        _, ds, _, k, _ = _recompute_probs_and_ds(
+            bias_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, di_ref,
+            scale=scale, causal_offset=causal_offset,
+            t_idx=t_idx, s_idx=s_idx,
+        )
+        acc_ref[:] += _dot(ds.astype(k.dtype), k, (1, 0))  # (T_blk, D)
+
+    if skip_masked:
+        pl.when(_tile_visible(t_idx, s_idx, q_ref.shape[2],
+                              k_ref.shape[2], causal_offset))(_tile)
+    else:
+        _tile()
 
     @pl.when(s_idx == pl.num_programs(3) - 1)
     def _finish():
@@ -347,22 +404,29 @@ def _bwd_dq_kernel(bias_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, di_ref,
 
 def _bwd_dkv_kernel(bias_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, di_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
-                    causal_offset: Optional[int]):
-    t_idx = pl.program_id(3)
+                    causal_offset: Optional[int], skip_masked: bool):
+    s_idx, t_idx = pl.program_id(2), pl.program_id(3)
 
     @pl.when(t_idx == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    p, ds, q, _, g = _recompute_probs_and_ds(
-        bias_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, di_ref,
-        scale=scale, causal_offset=causal_offset,
-        t_idx=t_idx, s_idx=pl.program_id(2),
-    )
-    # contract the query axis: (T_blk, S_blk)ᵀ·(T_blk, D) → (S_blk, D)
-    dv_acc[:] += _dot(p.astype(g.dtype), g, (0, 0))
-    dk_acc[:] += _dot(ds.astype(q.dtype), q, (0, 0))
+    def _tile():
+        p, ds, q, _, g = _recompute_probs_and_ds(
+            bias_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, di_ref,
+            scale=scale, causal_offset=causal_offset,
+            t_idx=t_idx, s_idx=s_idx,
+        )
+        # contract the query axis: (T_blk, S_blk)ᵀ·(T_blk, D) → (S_blk, D)
+        dv_acc[:] += _dot(p.astype(g.dtype), g, (0, 0))
+        dk_acc[:] += _dot(ds.astype(q.dtype), q, (0, 0))
+
+    if skip_masked:
+        pl.when(_tile_visible(t_idx, s_idx, q_ref.shape[2],
+                              k_ref.shape[2], causal_offset))(_tile)
+    else:
+        _tile()
 
     @pl.when(t_idx == pl.num_programs(3) - 1)
     def _finish():
@@ -372,89 +436,115 @@ def _bwd_dkv_kernel(bias_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, di_ref,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("t_blk", "s_blk", "interpret", "causal_offset"),
+    static_argnames=("t_blk", "s_blk", "interpret", "causal_offset",
+                     "skip_masked"),
 )
 def _fused_attention_bwd_impl(
     q: Array, k: Array, v: Array, bias: Array, out: Array,
     m: Array, l: Array,
     g: Array, t_blk: int, s_blk: int, interpret: bool,
-    causal_offset: Optional[int] = None,
+    causal_offset: Optional[int] = None, skip_masked: bool = False,
 ):
     b, h, t, d = q.shape
-    s = k.shape[2]
+    s, dv = k.shape[2], v.shape[3]
     scale = d**-0.5
 
     # delta = Σ_d g·out per query row, lane-broadcast like lse
     di = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     di = jnp.broadcast_to(di[..., None], (b, h, t, _LANES))
 
+    kv_block = functools.partial(_kv_block_index, t_blk=t_blk, s_blk=s_blk,
+                                 offset=causal_offset, skip_masked=skip_masked)
+
     bias = bias[:, None, :]  # (B, 1, S)
-    qo_spec = pl.BlockSpec((1, 1, t_blk, d), lambda bi, hi, ti, si: (bi, hi, ti, 0))
-    kv_spec = pl.BlockSpec((1, 1, s_blk, d), lambda bi, hi, ti, si: (bi, hi, si, 0))
+    q_spec = pl.BlockSpec((1, 1, t_blk, d), lambda bi, hi, ti, si: (bi, hi, ti, 0))
+    g_spec = pl.BlockSpec((1, 1, t_blk, dv), lambda bi, hi, ti, si: (bi, hi, ti, 0))
+    k_spec = pl.BlockSpec((1, 1, s_blk, d),
+                          lambda bi, hi, ti, si: (bi, hi, kv_block(ti, si), 0))
+    v_spec = pl.BlockSpec((1, 1, s_blk, dv),
+                          lambda bi, hi, ti, si: (bi, hi, kv_block(ti, si), 0))
     lm_spec = pl.BlockSpec((1, 1, t_blk, _LANES),
                            lambda bi, hi, ti, si: (bi, hi, ti, 0))
-    bias_spec = pl.BlockSpec((1, 1, s_blk), lambda bi, hi, ti, si: (bi, 0, si))
+    bias_spec = pl.BlockSpec((1, 1, s_blk),
+                             lambda bi, hi, ti, si: (bi, 0, kv_block(ti, si)))
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale,
-                          causal_offset=causal_offset),
+                          causal_offset=causal_offset, skip_masked=skip_masked),
         grid=(b, h, t // t_blk, s // s_blk),  # KV axis sequential
-        in_specs=[bias_spec, qo_spec, kv_spec, kv_spec, qo_spec,
+        in_specs=[bias_spec, q_spec, k_spec, v_spec, g_spec,
                   lm_spec, lm_spec, lm_spec],
-        out_specs=qo_spec,
+        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((t_blk, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name=KERNEL_DQ,
     )(bias, q, k, v, g, m, l, di)
 
     # dkv grid puts the query axis innermost (sequential): same index maps
     # apply, with ti/si read from swapped grid positions
-    qo_spec2 = pl.BlockSpec((1, 1, t_blk, d), lambda bi, hi, si, ti: (bi, hi, ti, 0))
-    kv_spec2 = pl.BlockSpec((1, 1, s_blk, d), lambda bi, hi, si, ti: (bi, hi, si, 0))
+    def q_block(si, ti):
+        if not skip_masked:
+            return ti
+        # a KV block past every row's reach has no visible query block: its
+        # tiles are all skipped, but what it fetches must still exist
+        first = jnp.minimum(_first_visible_q(si, t_blk, s_blk, causal_offset),
+                            t // t_blk - 1)
+        return jnp.maximum(ti, first)
+
+    q_spec2 = pl.BlockSpec((1, 1, t_blk, d),
+                           lambda bi, hi, si, ti: (bi, hi, q_block(si, ti), 0))
+    g_spec2 = pl.BlockSpec((1, 1, t_blk, dv),
+                           lambda bi, hi, si, ti: (bi, hi, q_block(si, ti), 0))
+    k_spec2 = pl.BlockSpec((1, 1, s_blk, d), lambda bi, hi, si, ti: (bi, hi, si, 0))
+    v_spec2 = pl.BlockSpec((1, 1, s_blk, dv), lambda bi, hi, si, ti: (bi, hi, si, 0))
     lm_spec2 = pl.BlockSpec((1, 1, t_blk, _LANES),
-                            lambda bi, hi, si, ti: (bi, hi, ti, 0))
+                            lambda bi, hi, si, ti: (bi, hi, q_block(si, ti), 0))
     bias_spec2 = pl.BlockSpec((1, 1, s_blk), lambda bi, hi, si, ti: (bi, 0, si))
-    dk, dv = pl.pallas_call(
+    dk, dv_out = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale,
-                          causal_offset=causal_offset),
+                          causal_offset=causal_offset, skip_masked=skip_masked),
         grid=(b, h, s // s_blk, t // t_blk),  # query axis sequential
-        in_specs=[bias_spec2, qo_spec2, kv_spec2, kv_spec2, qo_spec2,
+        in_specs=[bias_spec2, q_spec2, k_spec2, v_spec2, g_spec2,
                   lm_spec2, lm_spec2, lm_spec2],
-        out_specs=(kv_spec2, kv_spec2),
+        out_specs=(k_spec2, v_spec2),
         out_shape=(jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)),
         scratch_shapes=[pltpu.VMEM((s_blk, d), jnp.float32),
-                        pltpu.VMEM((s_blk, d), jnp.float32)],
+                        pltpu.VMEM((s_blk, dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name=KERNEL_DKV,
     )(bias, q, k, v, g, m, l, di)
-    return dq, dk, dv
+    return dq, dk, dv_out
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _fused_attention(q, k, v, bias, t_blk, s_blk, interpret, causal_offset):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _fused_attention(q, k, v, bias, t_blk, s_blk, interpret, causal_offset,
+                     skip_masked=False):
     return _fused_attention_fwd_impl(q, k, v, bias, t_blk, s_blk, interpret,
-                                     causal_offset=causal_offset)
+                                     causal_offset=causal_offset,
+                                     skip_masked=skip_masked)
 
 
-def _fwd(q, k, v, bias, t_blk, s_blk, interpret, causal_offset):
+def _fwd(q, k, v, bias, t_blk, s_blk, interpret, causal_offset, skip_masked):
     out, m, l = _fused_attention_fwd_impl(
         q, k, v, bias, t_blk, s_blk, interpret, with_lse=True,
-        causal_offset=causal_offset,
+        causal_offset=causal_offset, skip_masked=skip_masked,
     )
     return out, (q, k, v, bias, out, m, l)
 
 
-def _bwd(t_blk, s_blk, interpret, causal_offset, residuals, g):
+def _bwd(t_blk, s_blk, interpret, causal_offset, skip_masked, residuals, g):
     q, k, v, bias, out, m, l = residuals
     dq, dk, dv = _fused_attention_bwd_impl(
         q, k, v, bias, out, m, l, g, t_blk, s_blk, interpret,
-        causal_offset=causal_offset,
+        causal_offset=causal_offset, skip_masked=skip_masked,
     )
     return dq, dk, dv, jnp.zeros_like(bias)
 
@@ -532,7 +622,10 @@ def fused_attention(
     interpret: Optional[bool] = None,
     causal_offset: Optional[int] = None,
 ) -> Array:
-    """Fused multi-head attention over (B, T, H, D) q and (B, S, H, D) k/v.
+    """Fused multi-head attention over (B, T, H, D) q, (B, S, H, D) k and
+    (B, S, H, Dv) v; the output is (B, T, H, Dv). Dv may differ from D (a
+    latent-attention head: 192-deep scores, 128-deep values); the scores are
+    scaled by ``D ** -0.5``.
 
     ``pad_mask``: optional (B, S) bool, True = key position masked out (the
     torch ``key_padding_mask`` convention). ``causal_offset``: static int —
@@ -546,6 +639,14 @@ def fused_attention(
     ``q_block_size=None`` (default) resolves per shape after KV-block sizing
     (see LONG_KV_Q_BLOCK). Off-TPU backends run the kernel in interpreter
     mode (slow — for tests), overridable via ``interpret``.
+
+    With a ``causal_offset >= 0`` and no ``pad_mask`` the tiles that lie
+    wholly above the diagonal are neither fetched nor computed, forward and
+    backward: about half of a square causal attention. Exact there, because
+    every row sees a key in its first tile, so a masked tile adds
+    exp(-1e30 - m) = 0. With a pad mask a row may see padding only, and then
+    owes its uniform softmax to the masked tiles too; under a negative offset
+    the first rows see no key at all: both keep every tile.
     """
     if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
         raise ValueError(f"expected (B, T/S, H, D) tensors, got {q.shape=} {k.shape=}")
@@ -559,12 +660,14 @@ def fused_attention(
     else:
         bias = jnp.where(pad_mask, MASK_VALUE, 0.0).astype(jnp.float32)
 
+    skip_masked = causal_offset is not None and causal_offset >= 0 and pad_mask is None
     q, k, v, bias, t_blk, s_blk, t_pad = _prepare_blocks(
         q, k, v, bias, kv_block_size, q_block_size, interpret
     )
     out = _fused_attention(
         q, k, v, bias, t_blk, s_blk, interpret,
         None if causal_offset is None else int(causal_offset),
+        skip_masked,
     )
     if t_pad:
         out = out[:, :, :t]

@@ -290,6 +290,37 @@ def make_ar_steps(model, schedule: Optional[Schedule] = None,
     return train_step, eval_step, predict_fn
 
 
+def make_lm_steps(model, schedule: Optional[Schedule] = None):
+    """(train_step, eval_step, predict_fn) for a token-level causal decoder
+    (``models.decoder_lm.DecoderLM``): batches ``{'token_ids': (B, T) int,
+    'pad_mask': (B, T) bool}``. The loss is the model's own (``model.loss``:
+    next-token CE plus the weighted multi-token-prediction term); the step's
+    metrics carry its parts (``loss_main``, ``loss_mtp``) and the expert
+    layers' routing statistics (``moe_load_max_over_mean``,
+    ``moe_local_assignment_pct``, ``moe_dropped_assignments``), which the
+    Trainer's log boundary turns into registry gauges. Nothing is sampled:
+    no rng stream is drawn."""
+
+    def loss_fn(params, batch):
+        return model.apply({"params": params}, batch["token_ids"], batch["pad_mask"],
+                           method=model.loss)
+
+    def train_step(state: TrainState, batch) -> Tuple[TrainState, Metrics]:
+        (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(state.params, batch)
+        metrics = {"loss": loss, **aux, **_lr_metric(schedule, state.step)}
+        return state.apply_gradients(grads), metrics
+
+    def eval_step(state: TrainState, batch, key: Optional[Array] = None) -> Metrics:
+        # the key is the Trainer's stochastic-eval slot; nothing here is sampled
+        loss, aux = loss_fn(state.params, batch)
+        return {"loss": loss, **aux}
+
+    def predict_fn(params, token_ids):
+        return model.apply({"params": params}, token_ids)
+
+    return train_step, eval_step, predict_fn
+
+
 def make_classifier_steps(
     model,
     schedule: Optional[Schedule] = None,

@@ -208,6 +208,12 @@ class TrainerConfig:
         return self.skip_nonfinite_steps or self.dispatch_error_retries > 0
 
 
+def _logged_name(metric: str) -> str:
+    """A step metric's name in metrics.jsonl and the registry: the task's
+    ``loss`` / ``acc`` take the ``train_`` prefix, a model's own keep theirs."""
+    return f"train_{metric}" if metric in ("loss", "acc") else metric
+
+
 class Trainer:
     """Drives jitted steps over data loaders; owns logging and checkpoints.
 
@@ -1094,7 +1100,7 @@ class Trainer:
                         self._maybe_compute_flops(batch)
                         # the float() conversions are the only host syncs in the loop
                         host_metrics = {
-                            f"train_{k}" if k in ("loss", "acc") else k: float(v)
+                            _logged_name(k): float(v)
                             for k, v in metrics.items()
                         }
                         self._last_train_loss = host_metrics.get(
@@ -1215,9 +1221,21 @@ class Trainer:
             if not np.isfinite(self._last_train_loss) and "loss" in metrics:
                 self._last_train_loss = float(metrics["loss"])
             self._validate_and_checkpoint(step_i, val_loader)
+        self._publish_last_step(metrics)
         self.checkpoints.wait()
         self.logger.flush()
         return self.state
+
+    def _publish_last_step(self, metrics: Metrics) -> None:
+        """The last dispatched step's metrics as registry gauges, under the
+        log boundary's names (``train_loss``, ``lr``, a model's own counters):
+        a fit that ends between two boundaries, or is shorter than one
+        interval, still leaves them readable. One wait on the last step's
+        outputs, which the end-of-epoch bookkeeping above has usually paid
+        already (``_last_train_loss``); no row is written to metrics.jsonl."""
+        reg = obs.get_registry()
+        for k, v in metrics.items():
+            reg.gauge(_logged_name(k)).set(float(v))
 
     def set_flops_per_step(self, flops: Optional[float]) -> None:
         """Install the per-step FLOP count used for the MFU metric (compute it

@@ -58,14 +58,36 @@ def _sum_sq(fn, grad):
     return loss
 
 
-def _attention(b, t, s, h, d, grad=False, causal_offset=None):
+def _attention(b, t, s, h, d, grad=False, causal_offset=None, dv=None,
+               blocks=(None, None)):
     from perceiver_io_tpu.ops.pallas_attention import fused_attention
 
     fn = functools.partial(
-        fused_attention, causal_offset=causal_offset, interpret=False)
+        fused_attention, causal_offset=causal_offset, interpret=False,
+        q_block_size=blocks[0], kv_block_size=blocks[1])
     qkv = [((b, t, h, d), jnp.bfloat16), ((b, s, h, d), jnp.bfloat16),
-           ((b, s, h, d), jnp.bfloat16)]
+           ((b, s, h, dv or d), jnp.bfloat16)]
     return _sum_sq(fn, grad), qkv
+
+
+def _mla_blocks():
+    from perceiver_io_tpu.ops import latent_attention
+
+    return latent_attention.PALLAS_QUERY_BLOCK, latent_attention.PALLAS_KV_BLOCK
+
+
+def _grouped_matmul(tokens, top_k, held, k, n, grad=False, dtype=jnp.bfloat16):
+    from perceiver_io_tpu.ops.moe import TILE_ROWS
+    from perceiver_io_tpu.ops.pallas_grouped_matmul import grouped_matmul
+
+    tiles = tokens * top_k // TILE_ROWS + held
+    fn = lambda lhs, rhs, tile_group: grouped_matmul(  # noqa: E731
+        lhs, rhs, tile_group, TILE_ROWS, interpret=False)
+    if grad:
+        fn = jax.grad(lambda lhs, rhs, tile_group, inner=fn: jnp.sum(
+            inner(lhs, rhs, tile_group).astype(jnp.float32) ** 2), argnums=(0, 1))
+    return fn, [((tiles * TILE_ROWS, k), dtype), ((held, k, n), dtype),
+                ((tiles,), jnp.int32)]
 
 
 def _flash_ce(rows, c, vocab, grad=False):
@@ -105,6 +127,19 @@ CASES = {
         4, 256, 512, 4, 128, causal_offset=256),
     "attn-q1-decode-d128": lambda: _attention(
         4, 1, 512, 4, 128, causal_offset=511),
+    # the causal decoder's latent attention (4 rows x 4096, 32 heads, scores
+    # 192 deep, values 128 deep, tiles above the diagonal skipped) and its held
+    # experts' products (16,384 tokens x top-8, 8 experts, 2048 <-> 768)
+    "attn-mla-causal-fwd": lambda: _attention(
+        4, 4096, 4096, 32, 192, dv=128, causal_offset=0, blocks=_mla_blocks()),
+    "attn-mla-causal-grad": lambda: _attention(
+        4, 4096, 4096, 32, 192, dv=128, causal_offset=0, blocks=_mla_blocks(), grad=True),
+    "gmm-experts-up-grad": lambda: _grouped_matmul(16384, 8, 8, 2048, 768, grad=True),
+    "gmm-experts-down-grad": lambda: _grouped_matmul(16384, 8, 8, 768, 2048, grad=True),
+    # the float32 (parity) path: blocks of the bfloat16 size ran out of VMEM
+    # on the chip (PR 32)
+    "gmm-experts-up-grad-f32": lambda: _grouped_matmul(
+        16384, 8, 8, 2048, 768, grad=True, dtype=jnp.float32),
     "ce-fwd-c64": lambda: _flash_ce(10240, 64, 10003),
     "ce-grad-c64": lambda: _flash_ce(10240, 64, 10003, grad=True),
     "ce-fwd-c512": lambda: _flash_ce(10240, 512, 10003),
