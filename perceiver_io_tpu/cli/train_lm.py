@@ -30,6 +30,7 @@ from perceiver_io_tpu.aot import configure_compile_cache
 from perceiver_io_tpu.cli import common
 from perceiver_io_tpu.data.imdb import IMDBDataModule
 from perceiver_io_tpu.models.decoder_lm import DecoderLM, DecoderLMConfig
+from perceiver_io_tpu.ops import moe
 from perceiver_io_tpu.training import TrainState, make_lm_steps
 from perceiver_io_tpu.training.trainer import Trainer
 
@@ -82,8 +83,13 @@ def model_config(args, vocab_size: int) -> DecoderLMConfig:
 def build_model(args, vocab_size: int) -> DecoderLM:
     config = model_config(args, vocab_size)
     held = config.n_routed_experts if config.experts_held is None else config.experts_held
+    tokens = args.batch_size * args.max_seq_len
     obs.event("moe.share", held=held, total=config.n_routed_experts,
-              offset=config.expert_offset)
+              offset=config.expert_offset,
+              capacity_rows=moe.TILE_ROWS * moe.capacity_tiles(
+                  tokens, config.num_experts_per_tok, held, config.n_routed_experts, moe.TILE_ROWS),
+              worst_case_rows=moe.TILE_ROWS * moe.worst_case_tiles(
+                  tokens * config.num_experts_per_tok, held, moe.TILE_ROWS))
     # the model rematerialises every block (``--remat`` is the Perceiver
     # encoders' switch) and the experts' path follows the backend
     return DecoderLM(config, attn_impl=args.attn_impl, dtype=common.DTYPES[args.dtype])

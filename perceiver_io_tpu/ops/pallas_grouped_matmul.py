@@ -7,8 +7,10 @@ is a plain tiled matmul whose weight block is chosen by a prefetched scalar.
 ``tile_group[t]`` names tile ``t``'s group; the value ``num_groups`` marks a
 tile past the last group. Such a tile is not computed and not fetched (its
 block indices stay where the last real tile left them; a block whose index
-does not change is not copied again) and its output is zeros. The buffer is
-sized for the worst routing, so most tiles are of that kind.
+does not change is not copied again) and its output is zeros. The buffer holds
+a multiple of the expected load (``ops/moe.py`` ``capacity_tiles``; the worst
+routing only where a step overflows that), so such tiles are the larger part
+of it, not nearly all.
 
 Backward: the gradient of ``lhs`` is the same product against the transposed
 weights; the gradient of ``rhs`` (``grouped_matmul_transposed``) sums

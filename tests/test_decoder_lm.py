@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from flax import linen as nn
 
 from benchmarks import run as run_mod, traffic
 from benchmarks.reference import common as ref_common, decoder_lm as ref
@@ -148,6 +149,81 @@ def test_no_assignment_is_dropped_when_every_token_picks_the_same_experts(impl):
     assert float(stats["dropped_assignments"]) == 0
     assert float(stats["local_assignment_pct"]) == 50.0  # experts 5 and 6 of the four
     assert float(stats["load_max_over_mean"]) == 2.0     # 48 each, the other two held idle
+
+
+# A layer whose capacity is smaller than its worst case: 2 of 16 experts (5
+# and 6) held, top 2, 48 tokens in tiles of 8. A router that favours no expert
+# sends 12 rows here, so the bounded buffer is ceil(4 * 12 / 8) + 2 = 8 tiles
+# of the worst case's 96 / 8 + 2 = 14; two experts with 48 rows each need 12.
+BOUNDED = dict(num_experts=PUBLISHED_EXPERTS, top_k=2, experts_held=2, expert_offset=5,
+               tile_rows=8)
+
+
+def _bias_that_needs(case, scores):
+    """A selection bias under which the 48 tokens' routing to experts 5 and
+    6 needs fewer tiles than the capacity, exactly its 8, or the 12 over it."""
+    bias = jnp.zeros(PUBLISHED_EXPERTS)
+    if case == "under":
+        return bias
+    bias = bias.at[5].set(10.0)  # every token's first choice: 6 tiles
+    if case == "over":
+        return bias.at[6].set(10.0)
+    # 'exact': 12 tokens choose expert 6 second, 2 tiles more: its bias lies
+    # between the 12th and the 13th smallest lead of the best other expert
+    lead = jnp.sort(jnp.max(scores.at[:, 5:7].set(0.0), axis=-1) - scores[:, 6])
+    return bias.at[6].set((lead[11] + lead[12]) / 2)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("case, bounded_pct, local_pct", [
+    ("under", 100.0, None), ("exact", 100.0, 62.5), ("over", 0.0, 100.0), ("all_held", 100.0, 100.0)])
+def test_row_buffer_follows_the_load(case, bounded_pct, local_pct, impl):
+    """Output and the gradients of the input, the router and the three expert
+    kernels, through ``nn.remat`` and ``value_and_grad`` as the step takes
+    them, against the plain reference: on the bounded buffer, at its last
+    tile, over it (the worst-case buffer), and with every expert held, where
+    the layer builds one path and no ``cond``."""
+    cfg, mix, builder = tiny_cell()
+    params, _, _ = seeded(cfg, mix, builder)
+    p = dict(params["layer_1"]["moe"])
+    x = jax.random.normal(jax.random.key(9), (2, 24, cfg["hidden_size"]))
+    weight = jax.random.normal(jax.random.key(10), x.shape)
+    kw = dict(BOUNDED, width=cfg["moe_intermediate_size"], expert_impl=impl,
+              routed_scaling_factor=cfg["routed_scaling_factor"])
+    if case == "all_held":
+        kw.update(experts_held=None, expert_offset=0)
+    else:
+        scores = jax.nn.sigmoid(jnp.dot(x.reshape(-1, x.shape[-1]), p["router"]["kernel"],
+                                        precision=jax.lax.Precision.HIGHEST))
+        p["e_score_correction_bias"] = _bias_that_needs(case, scores)
+        p.update({k: {"kernel": p[k]["kernel"][5:7]}
+                  for k in ("experts_gate", "experts_up", "experts_down")})
+        assert moe.capacity_tiles(48, 2, 2, PUBLISHED_EXPERTS, 8) == 8
+        assert moe.worst_case_tiles(96, 2, 8) == 14
+    layer = nn.remat(moe.MoELayer)(**kw)
+    held = kw["experts_held"] or PUBLISHED_EXPERTS
+    sz = dict(builder.sizes(cfg), top_k=2, experts_held=held, expert_offset=kw["expert_offset"])
+
+    def program(p, x):
+        y, stats = layer.apply({"params": p}, x)
+        return jnp.sum(y * weight), (y, stats)
+
+    def reference(p, x):
+        y = ref.expert_layer(ref_common.F32, p, x, sz)
+        return jnp.sum(y * weight), y
+
+    (_, (y, stats)), got = jax.value_and_grad(program, argnums=(0, 1), has_aux=True)(p, x)
+    (_, want_y), want = jax.value_and_grad(reference, argnums=(0, 1), has_aux=True)(p, x)
+    assert worst(y, want_y) < LOGIT_TOL
+    assert worst(got[1], want[1]) < GRAD_TOL
+    for leaf in ("router", "experts_gate", "experts_up", "experts_down"):
+        assert worst(got[0][leaf]["kernel"], want[0][leaf]["kernel"]) < GRAD_TOL, leaf
+    assert float(stats["dropped_assignments"]) == 0
+    assert float(stats["bounded_path_pct"]) == bounded_pct
+    if local_pct is not None:  # 48 + 12 and 48 + 48 of the 96 assignments
+        assert float(stats["local_assignment_pct"]) == local_pct
+    if impl == "xla":  # the interpreted kernel's ``pl.when``s are conds too
+        assert str(jax.make_jaxpr(program)(p, x)).count("cond[") == (case != "all_held")
 
 
 def test_grouped_matmul_kernel_matches_masked_matmuls():
@@ -297,3 +373,4 @@ def test_train_lm_cli_three_synthetic_steps(tmp_path):
     assert np.isclose(gauges["train_loss"], gauges["loss_main"] + 0.3 * gauges["loss_mtp"],
                       rtol=1e-5)
     assert {"moe_load_max_over_mean", "moe_local_assignment_pct"} <= set(gauges)
+    assert gauges["moe_bounded_path_pct"] == 100.0  # half the experts held: one path

@@ -76,11 +76,14 @@ def _mla_blocks():
     return latent_attention.PALLAS_QUERY_BLOCK, latent_attention.PALLAS_KV_BLOCK
 
 
-def _grouped_matmul(tokens, top_k, held, k, n, grad=False, dtype=jnp.bfloat16):
-    from perceiver_io_tpu.ops.moe import TILE_ROWS
+def _grouped_matmul(tokens, top_k, held, k, n, grad=False, dtype=jnp.bfloat16, of_experts=None):
+    """Over the worst-case buffer, or over the bounded one of a layer that
+    holds ``held`` ``of_experts``."""
+    from perceiver_io_tpu.ops.moe import TILE_ROWS, capacity_tiles, worst_case_tiles
     from perceiver_io_tpu.ops.pallas_grouped_matmul import grouped_matmul
 
-    tiles = tokens * top_k // TILE_ROWS + held
+    tiles = (worst_case_tiles(tokens * top_k, held, TILE_ROWS) if of_experts is None else
+             capacity_tiles(tokens, top_k, held, of_experts, TILE_ROWS))
     fn = lambda lhs, rhs, tile_group: grouped_matmul(  # noqa: E731
         lhs, rhs, tile_group, TILE_ROWS, interpret=False)
     if grad:
@@ -136,6 +139,11 @@ CASES = {
         4, 4096, 4096, 32, 192, dv=128, causal_offset=0, blocks=_mla_blocks(), grad=True),
     "gmm-experts-up-grad": lambda: _grouped_matmul(16384, 8, 8, 2048, 768, grad=True),
     "gmm-experts-down-grad": lambda: _grouped_matmul(16384, 8, 8, 768, 2048, grad=True),
+    # the same over the bounded buffer of 8 of 256 experts (72 tiles of the 520)
+    "gmm-experts-up-grad-bounded": lambda: _grouped_matmul(
+        16384, 8, 8, 2048, 768, grad=True, of_experts=256),
+    "gmm-experts-down-grad-bounded": lambda: _grouped_matmul(
+        16384, 8, 8, 768, 2048, grad=True, of_experts=256),
     # the float32 (parity) path: blocks of the bfloat16 size ran out of VMEM
     # on the chip (PR 32)
     "gmm-experts-up-grad-f32": lambda: _grouped_matmul(
