@@ -17,13 +17,13 @@ it never stands in for the device number — a trace that cannot be captured
 or parsed fails the run.
 
 Env knobs: PIT_BENCH_STEPS / PIT_BENCH_BATCH override defaults;
-PIT_BENCH_ATTN selects the attention impl ('xla' | 'pallas' | 'packed');
+PIT_BENCH_ATTN selects the attention impl ('xla' | 'pallas');
 PIT_BENCH_GATHER sets the masked-decode capacity (-1 auto, 0 = reference-
 shaped full decode); PIT_BENCH_HEAD selects the vocab head ('pallas' = the
-fused flash-CE kernel, the default; 'none' = unfused; 'xla' = chunked-scan
-variant). The compile cache lives where ``aot.configure_compile_cache``
-puts it (``JAX_COMPILATION_CACHE_DIR`` or ``<checkout>/.cache/jax``);
-compile time never enters the measured window.
+fused flash-CE kernel, the default; 'none' = unfused). The compile cache
+lives where ``aot.configure_compile_cache`` puts it
+(``JAX_COMPILATION_CACHE_DIR`` or ``<checkout>/.cache/jax``); compile time
+never enters the measured window.
 """
 
 from __future__ import annotations
@@ -63,17 +63,17 @@ def main() -> None:
     steps = int(os.environ.get("PIT_BENCH_STEPS", "20"))
     compute_dtype = jnp.bfloat16
     attn_impl = os.environ.get("PIT_BENCH_ATTN", "xla")
-    if attn_impl not in ("xla", "pallas", "packed"):
+    if attn_impl not in ("xla", "pallas"):
         raise SystemExit(
-            f"PIT_BENCH_ATTN must be 'xla', 'pallas' or 'packed', got {attn_impl!r}")
+            f"PIT_BENCH_ATTN must be 'xla' or 'pallas', got {attn_impl!r}")
     gather = int(os.environ.get("PIT_BENCH_GATHER", "-1"))
     if gather < 0:
         gather = mlm_gather_capacity(seq_len)
     head = os.environ.get("PIT_BENCH_HEAD", "pallas")
-    fused_head = {"pallas": "pallas", "xla": True, "none": False}.get(head)
+    fused_head = {"pallas": "pallas", "none": False}.get(head)
     if fused_head is None:
         raise SystemExit(
-            f"PIT_BENCH_HEAD must be 'pallas', 'xla' or 'none', got {head!r}")
+            f"PIT_BENCH_HEAD must be 'pallas' or 'none', got {head!r}")
 
     from perceiver_io_tpu.models.presets import flagship_mlm
 

@@ -224,15 +224,14 @@ def add_compute_args(parser: argparse.ArgumentParser) -> None:
     g = parser.add_argument_group("compute")
     g.add_argument("--dtype", choices=sorted(DTYPES), default="bfloat16")
     g.add_argument("--attn_impl",
-                   choices=("auto", "xla", "pallas", "pallas_sp", "packed"),
+                   choices=("auto", "xla", "pallas", "pallas_sp"),
                    default="auto",
                    help="attention inner-product impl; auto picks the fused "
                         "Pallas kernel for long KV streams, XLA otherwise "
                         "(and routes the encoder cross-attention through the "
                         "sequence-parallel kernel when --sp > 1 and "
                         "--shard_seq are active); pallas_sp forces the kernel "
-                        "path with that sp routing; packed = experimental "
-                        "small-latent kernel (PERF.md)")
+                        "path with that sp routing")
     g.add_argument("--remat", action="store_true",
                    help="rematerialize encoder layers (HBM for FLOPs). "
                         "Selective where it pays: a cross-attention that "

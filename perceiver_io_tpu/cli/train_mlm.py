@@ -81,15 +81,14 @@ def build_parser() -> argparse.ArgumentParser:
                    help="decode only the masked positions, up to this many per "
                         "row (gradient-equivalent, skips most vocab-projection "
                         "FLOPs). -1 = auto (2·mask_p·seq_len), 0 = full decode")
-    g.add_argument("--fused_head", choices=["auto", "pallas", "xla", "off"],
+    g.add_argument("--fused_head", choices=["auto", "pallas", "off"],
                    default="auto",
                    help="fuse the vocab projection into the CE so the "
                         "(B, K, V) logits never materialize: 'pallas' = the "
-                        "flash-CE kernel (the measured winner on TPU, "
-                        "PERF.md r3), 'xla' = chunked-scan variant, 'off' = "
-                        "unfused. auto = pallas only on a single-device TPU "
-                        "mesh (off under ANY multi-chip sharding — dp/sp/tp "
-                        "— and on other backends)")
+                        "flash-CE kernel, 'off' = unfused. auto = pallas "
+                        "only on a single-device TPU mesh at 128 latent "
+                        "channels or fewer (off under ANY multi-chip "
+                        "sharding — dp/sp/tp — and on other backends)")
     # reference per-task defaults (train_mlm.py:93-106); the preset-affected
     # args default to the None sentinel apply_preset resolves
     parser.set_defaults(experiment="mlm", batch_size=64, num_latents=None,
@@ -196,9 +195,8 @@ def build_trainer(args: argparse.Namespace, mesh=None):
         # so sharded meshes keep the unfused head whose collectives GSPMD
         # manages. Explicit 'pallas' overrides for dp/sp (correct, possibly
         # slower); tp is rejected below (vocab sharding conflicts). The
-        # width gate is measured: at C=64 the kernel is +6.1% (PERF.md r3),
-        # at C=512 it's -2% (the K=512-deep head matmuls are MXU-efficient,
-        # so skipping the logits traffic no longer pays — r4 roofline A/B).
+        # width gate (C <= 128) was set on an earlier device, not measured
+        # on the v5e (ROADMAP S6).
         fused = ("pallas" if jax.default_backend() == "tpu"
                  and mesh.size == 1
                  and args.num_latent_channels <= 128 else "off")
@@ -209,7 +207,7 @@ def build_trainer(args: argparse.Namespace, mesh=None):
         )
     train_step, eval_step, predict_fn = make_mlm_steps(
         model, schedule, loss_gather_capacity=capacity or None,
-        fused_head={"pallas": "pallas", "xla": True, "off": False}[fused],
+        fused_head={"pallas": "pallas", "off": False}[fused],
     )
 
     trainer = Trainer(

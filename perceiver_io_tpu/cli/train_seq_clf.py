@@ -223,7 +223,7 @@ def main(argv: Optional[Sequence[str]] = None):
 
     trainer = Trainer(
         train_step,
-        lambda s, b, k: eval_step(s, b),
+        eval_step,
         state,
         common.trainer_config(args),
         example_batch={k: example[k] for k in ("token_ids", "pad_mask", "label")},

@@ -9,7 +9,7 @@ from perceiver_io_tpu.data.tokenizer import (
     save_tokenizer,
     load_tokenizer,
 )
-from perceiver_io_tpu.data.pipeline import DataLoader, prefetch_to_device
+from perceiver_io_tpu.data.pipeline import DataLoader
 from perceiver_io_tpu.data.imdb import (
     Collator,
     IMDBDataModule,
@@ -47,7 +47,6 @@ __all__ = [
     "save_tokenizer",
     "load_tokenizer",
     "DataLoader",
-    "prefetch_to_device",
     "Collator",
     "IMDBDataModule",
     "IMDBDataset",

@@ -332,25 +332,3 @@ def _prefetch_thread(it: Iterator, size: int) -> Iterator:
         # consumer broke early: release the (possibly blocked) worker
         stop.set()
 
-
-def prefetch_to_device(
-    it: Iterator[Batch], sharding=None, size: int = 2
-) -> Iterator[Batch]:
-    """Move batches onto device(s) ahead of consumption.
-
-    With a ``jax.sharding.Sharding``, arrays land pre-sharded (the device-side
-    half of the input pipeline); otherwise default placement.
-    """
-    import jax
-
-    def put(batch: Batch):
-        if sharding is None:
-            return jax.device_put(batch)
-        return jax.device_put(batch, sharding)
-
-    buffer = []
-    for batch in it:
-        buffer.append(put(batch))
-        if len(buffer) > size:
-            yield buffer.pop(0)
-    yield from buffer

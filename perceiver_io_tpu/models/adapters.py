@@ -225,7 +225,7 @@ class ClassificationOutputAdapter(OutputAdapter):
         """(kernel, bias) of the linear head with padded classes masked out
         of the bias — the single source of truth for the ``pad_classes_to``
         scheme when a caller fuses the head into the loss
-        (``fused_linear_cross_entropy_with_ignore``) instead of applying this
+        (``pallas_linear_cross_entropy_with_ignore``) instead of applying this
         adapter. Mirrors the -inf-stand-in masking ``__call__`` applies to
         its logits: padded columns get a large-negative bias, so they vanish
         from any downstream softmax/logsumexp and receive zero gradient."""

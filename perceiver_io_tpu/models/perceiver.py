@@ -272,7 +272,7 @@ class PerceiverDecoder(nn.Module):
 
         ``return_features=True`` skips the output adapter and returns the
         (B, K, C) decoder stream — for callers that fuse the head into the
-        loss (``fused_linear_cross_entropy_with_ignore``).
+        loss (``pallas_linear_cross_entropy_with_ignore``).
         """
         b, *d = x.shape
         if tuple(d) != tuple(self.latent_shape):

@@ -17,8 +17,7 @@ from perceiver_io_tpu.ops.masking import IGNORE_LABEL, TextMasking, apply_text_m
 # Pallas kernels resolve lazily (PEP 562) so `import perceiver_io_tpu.ops`
 # stays light — jax.experimental.pallas only loads when a kernel is touched,
 # matching the deferred imports on MultiHeadAttention's dispatch path.
-_LAZY = {"fused_attention", "packed_latent_attention",
-         "seq_parallel_fused_attention"}
+_LAZY = {"fused_attention", "seq_parallel_fused_attention"}
 
 
 def __getattr__(name):
@@ -43,6 +42,5 @@ __all__ = [
     "TextMasking",
     "apply_text_masking",
     "fused_attention",
-    "packed_latent_attention",
     "seq_parallel_fused_attention",
 ]

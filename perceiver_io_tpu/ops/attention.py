@@ -26,11 +26,9 @@ normalized output by ~0.1%.
 The attention inner product is pluggable: ``attn_impl='xla'`` uses pure
 jnp/einsum (XLA fuses this well on the MXU); ``attn_impl='pallas'`` dispatches
 to the streaming fused Pallas kernel in ``perceiver_io_tpu.ops.pallas_attention``;
-``attn_impl='packed'`` is the experimental small-latent packed-heads kernel
-(opt-in — see PERF.md's negative-results note); ``'auto'`` (default) picks per
-call site: the fused kernel for long KV streams (image/flow inputs) and for
-big-logits self-attention stacks, XLA for small/shallow shapes (text) — see
-``auto_attention_impl``.
+``'auto'`` (default) picks per call site: the fused kernel for long KV streams
+(image/flow inputs) and for big-logits self-attention stacks, XLA for
+small/shallow shapes (text) — see ``auto_attention_impl``.
 
 Sequence parallelism: under an active regime
 (``parallel.mesh.sequence_parallel_context`` — entered by
@@ -239,7 +237,7 @@ class MultiHeadAttention(nn.Module):
     num_heads: int
     dropout: float = 0.0
     dtype: jnp.dtype = jnp.float32
-    attn_impl: str = "auto"  # 'auto' | 'xla' | 'pallas' | 'pallas_sp' | 'packed'
+    attn_impl: str = "auto"  # 'auto' | 'xla' | 'pallas' | 'pallas_sp'
     # Structural marker set by the ENCODER on its cross-attention: this call's
     # KV stream is the adapted input whose sequence axis shards over the mesh's
     # seq axis under shard_seq=True. Only such calls may route to the
@@ -289,12 +287,12 @@ class MultiHeadAttention(nn.Module):
         h = self.num_heads
         if e % h != 0:
             raise ValueError(f"num_q_channels {e} not divisible by num_heads {h}")
-        if self.attn_impl not in ("auto", "xla", "pallas", "pallas_sp", "packed"):
+        if self.attn_impl not in ("auto", "xla", "pallas", "pallas_sp"):
             # a typo'd impl must not silently fall through to the XLA branch
             # and get benchmarked under the wrong label (PERF.md discipline)
             raise ValueError(
                 f"unknown attn_impl {self.attn_impl!r}; expected one of "
-                "'auto', 'xla', 'pallas', 'pallas_sp', 'packed'"
+                "'auto', 'xla', 'pallas', 'pallas_sp'"
             )
         d = e // h
 
@@ -363,9 +361,7 @@ class MultiHeadAttention(nn.Module):
         #
         # 'auto' (the default) picks per call site — long KV stream with
         # shallow heads → streaming fused kernel; everything else → XLA
-        # einsum. 'packed' is the small-latent kernel reading the un-split
-        # (B, T, E) layout (head separation in-VMEM by channel masking) —
-        # opt-in while its end-to-end wins are shape-dependent.
+        # einsum.
         impl = self.attn_impl
         # Sequence-parallel routing: active regime (make_sharded_train_step
         # shard_seq=True over a mesh with seq > 1) + this call marked as the
@@ -403,11 +399,6 @@ class MultiHeadAttention(nn.Module):
             # kernel's in-kernel causal flag.
             impl = ("xla" if causal_offset is not None
                     else auto_attention_impl(b, t, s, h, d))
-        if impl == "packed" and causal_offset is not None:
-            raise ValueError(
-                "attn_impl='packed' does not implement causal_offset — use "
-                "'auto'/'xla' (masked einsum) or 'pallas' (in-kernel flag)"
-            )
         fusable = attn_mask is None and not dropout_active
         if impl == "pallas" and fusable and sp is not None:
             from perceiver_io_tpu.ops.pallas_attention import (
@@ -423,19 +414,6 @@ class MultiHeadAttention(nn.Module):
                 mesh=sp.mesh, axis=sp.axis, batch_axis=sp.batch_axis,
                 head_axis=head_axis,
             ).reshape(b, t, e)
-        elif impl == "packed" and fusable:
-            from perceiver_io_tpu.ops.pallas_attention import (
-                packed_fits_vmem,
-                packed_latent_attention,
-            )
-
-            if not packed_fits_vmem(t, s, e, jnp.dtype(q.dtype).itemsize):
-                raise ValueError(
-                    f"attn_impl='packed' shapes T={t} S={s} E={e} exceed the "
-                    "kernel's per-example VMEM budget (see "
-                    "pallas_attention.packed_vmem_bytes)"
-                )
-            out = packed_latent_attention(q, k, v, h, pad_mask=pad_mask)
         elif impl == "pallas" and fusable:
             from perceiver_io_tpu.ops.pallas_attention import fused_attention
 

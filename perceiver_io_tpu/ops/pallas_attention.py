@@ -853,209 +853,3 @@ def seq_parallel_fused_attention(
         **{check_kw: False},
     )(q, k, v, bias)
 
-
-# -- packed-heads latent kernel ----------------------------------------------
-#
-# The streaming kernel above pays for its generality at the Perceiver's OWN
-# shapes: with E = 64 channels over H = 4 heads, per-head (T, 16) operands
-# waste 7/8 of every (8, 128) memory tile and feed the MXU 16-wide
-# contractions. This kernel instead reads the PACKED (B, T, E) tensors —
-# never materializing a head-split layout in HBM — and computes each head's
-# logits as an E-wide contraction against a channel-masked K:
-#
-#     logits_h = Q @ (K ⊙ mask_h)^T      (mask_h selects head h's channels)
-#     out     += softmax(logits_h) @ (V ⊙ mask_h)
-#
-# The masked operands add H× MXU work, but at these shapes the step is
-# HBM-bound, not FLOP-bound (PERF.md): trading 4× cheap MXU passes for an 8×
-# reduction in bytes wins. Softmax and the (T, S) probabilities live only in
-# VMEM; the backward recomputes them (flash style) so neither direction puts
-# logits in HBM. Grid is (B,) — everything for one example fits in VMEM at
-# latent shapes, which the dispatcher enforces (PACKED_MAX_* below).
-
-
-def _head_masked(x, h: int, d: int):
-    col = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
-    return jnp.where((col >= h * d) & (col < (h + 1) * d), x, 0)
-
-
-def _packed_fwd_kernel(bias_ref, q_ref, k_ref, v_ref, out_ref, *,
-                       num_heads: int, scale: float):
-    q = q_ref[0]  # (T, E)
-    k = k_ref[0]  # (S, E)
-    v = v_ref[0]
-    bias = bias_ref[0]  # (1, S), broadcasts over T
-    d = q.shape[-1] // num_heads
-    acc = jnp.zeros(q.shape, jnp.float32)
-    for h in range(num_heads):
-        kh = _head_masked(k, h, d)
-        vh = _head_masked(v, h, d)
-        logits = _dot(q, kh, (1, 1)) * scale + bias  # (T, S) f32, VMEM only
-        m = jnp.max(logits, axis=-1, keepdims=True)
-        p = jnp.exp(logits - m)
-        p = p / jnp.sum(p, axis=-1, keepdims=True)
-        # vh is zero outside head h's channels, so each head's PV lands in
-        # its own output columns; summing concatenates the heads for free
-        acc += _dot(p.astype(v.dtype), vh, (1, 0))
-    out_ref[0] = acc.astype(out_ref.dtype)
-
-
-def _packed_bwd_kernel(bias_ref, q_ref, k_ref, v_ref, g_ref,
-                       dq_ref, dk_ref, dv_ref, *, num_heads: int, scale: float):
-    q = q_ref[0]
-    k = k_ref[0]
-    v = v_ref[0]
-    g = g_ref[0]  # (T, E) output cotangent
-    bias = bias_ref[0]
-    d = q.shape[-1] // num_heads
-    dq = jnp.zeros(q.shape, jnp.float32)
-    dk = jnp.zeros(k.shape, jnp.float32)
-    dv = jnp.zeros(v.shape, jnp.float32)
-    for h in range(num_heads):
-        kh = _head_masked(k, h, d)
-        vh = _head_masked(v, h, d)
-        gh = _head_masked(g, h, d)
-        logits = _dot(q, kh, (1, 1)) * scale + bias
-        m = jnp.max(logits, axis=-1, keepdims=True)
-        p = jnp.exp(logits - m)
-        p = p / jnp.sum(p, axis=-1, keepdims=True)  # (T, S) f32
-        dp = _dot(gh.astype(v.dtype), vh, (1, 1))  # (T, S): gh @ vh^T
-        delta = jnp.sum(p * dp, axis=-1, keepdims=True)
-        ds = p * (dp - delta) * scale
-        # fully-masked rows (m pinned at the MASK_VALUE bias): probabilities
-        # are uniform — dv keeps that contribution, but dq/dk must be exactly
-        # zero to match the XLA path's where-style masking (same rule as the
-        # streaming kernel's backward above)
-        ds = jnp.where(m <= 0.5 * MASK_VALUE, 0.0, ds).astype(q.dtype)
-        pb = p.astype(q.dtype)
-        qh = _head_masked(q, h, d)
-        # masked operands confine every contribution to head h's channels
-        dv += _dot(pb, gh, (0, 0))        # (S, E): p^T @ gh
-        dq += _dot(ds, kh, (1, 0))        # (T, E): ds @ kh
-        dk += _dot(ds, qh, (0, 0))        # (S, E): ds^T @ qh
-    dq_ref[0] = dq.astype(dq_ref.dtype)
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("num_heads", "interpret"))
-def _packed_fwd_impl(q, k, v, bias, num_heads, interpret):
-    b, t, e = q.shape
-    s = k.shape[1]
-    d = e // num_heads
-    kernel = functools.partial(
-        _packed_fwd_kernel, num_heads=num_heads, scale=d**-0.5
-    )
-    return pl.pallas_call(
-        kernel,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, 1, s), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, t, e), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, s, e), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, s, e), lambda i: (i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, t, e), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, t, e), q.dtype),
-        interpret=interpret,
-    )(bias, q, k, v)
-
-
-@functools.partial(jax.jit, static_argnames=("num_heads", "interpret"))
-def _packed_bwd_impl(q, k, v, bias, g, num_heads, interpret):
-    b, t, e = q.shape
-    s = k.shape[1]
-    d = e // num_heads
-    kernel = functools.partial(
-        _packed_bwd_kernel, num_heads=num_heads, scale=d**-0.5
-    )
-    return pl.pallas_call(
-        kernel,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, 1, s), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, t, e), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, s, e), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, s, e), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, t, e), lambda i: (i, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, t, e), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, s, e), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, s, e), lambda i: (i, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, t, e), q.dtype),
-            jax.ShapeDtypeStruct((b, s, e), k.dtype),
-            jax.ShapeDtypeStruct((b, s, e), v.dtype),
-        ],
-        interpret=interpret,
-    )(bias, q, k, v, g)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _packed_attention(q, k, v, bias, num_heads, interpret):
-    return _packed_fwd_impl(q, k, v, bias, num_heads, interpret)
-
-
-def _packed_fwd(q, k, v, bias, num_heads, interpret):
-    out = _packed_fwd_impl(q, k, v, bias, num_heads, interpret)
-    return out, (q, k, v, bias)
-
-
-def _packed_bwd(num_heads, interpret, residuals, g):
-    q, k, v, bias = residuals
-    dq, dk, dv = _packed_bwd_impl(q, k, v, bias, g, num_heads, interpret)
-    return dq, dk, dv, jnp.zeros_like(bias)
-
-
-_packed_attention.defvjp(_packed_fwd, _packed_bwd)
-
-# VMEM guardrail for the (B,)-grid packed kernel: one backward grid step
-# holds three f32 (T, S) tiles (logits/p, dp, ds), three f32 (rows, E)
-# accumulators, and the packed operands — all live at once (Mosaic does not
-# spill). Budget them jointly against a conservative slice of the ~16 MB
-# scoped VMEM; independent per-dim caps would admit shapes whose combination
-# cannot compile.
-PACKED_VMEM_BUDGET = 8 * 1024 * 1024
-
-
-def packed_vmem_bytes(t: int, s: int, e: int, itemsize: int = 2) -> int:
-    """Estimated live VMEM of one backward grid step (the larger direction)."""
-    tiles = 3 * t * s * 4                      # logits/p, dp, ds (f32)
-    accs = (t + 2 * s) * e * 4                 # dq, dk, dv accumulators (f32)
-    operands = (2 * t + 2 * s) * e * itemsize  # q, g, k, v blocks
-    return tiles + accs + operands
-
-
-def packed_fits_vmem(t: int, s: int, e: int, itemsize: int = 2) -> bool:
-    return packed_vmem_bytes(t, s, e, itemsize) <= PACKED_VMEM_BUDGET
-
-
-def packed_latent_attention(
-    q: Array,
-    k: Array,
-    v: Array,
-    num_heads: int,
-    pad_mask: Optional[Array] = None,
-    interpret: Optional[bool] = None,
-) -> Array:
-    """Fused multi-head attention over PACKED (B, T, E) q and (B, S, E) k/v.
-
-    The head-split (B, T, H, D) layout never exists: heads are separated
-    in-kernel by channel masking. Returns (B, T, E) — heads already merged.
-    ``pad_mask``: optional (B, S) bool, True = masked out.
-    """
-    if q.ndim != 3 or k.ndim != 3 or v.ndim != 3:
-        raise ValueError(f"expected packed (B, T/S, E) tensors, got {q.shape=}")
-    if q.shape[-1] % num_heads != 0:
-        raise ValueError(f"E {q.shape[-1]} not divisible by num_heads {num_heads}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    b, _, _ = q.shape
-    s = k.shape[1]
-    if pad_mask is None:
-        bias = jnp.zeros((b, 1, s), jnp.float32)
-    else:
-        bias = jnp.where(pad_mask, MASK_VALUE, 0.0).astype(jnp.float32)[:, None, :]
-    return _packed_attention(q, k, v, bias, num_heads, interpret)

@@ -6,12 +6,10 @@ Motivation (device-trace measurement, PERF.md round 3): on the flagship MLM
 config the unfused head complex — vocab matmul, CE reductions, softmax-grad
 matmuls — costs ~1.4 ms of a 10.4 ms step, nearly all of it streaming the
 206 MB (64, 160, 10003) bf16 logits tensor at HBM peak (~5 passes ≈ 1 GB of
-traffic per step). The XLA chunked variant (``losses.fused_linear_ce_integer``)
-already avoids the materialization but serializes 10-20 skinny matmul
-dispatches (measured slower, PERF.md negative result #7). This kernel runs
-the same online-logsumexp recurrence INSIDE one ``pallas_call`` — the vocab
-axis is the innermost sequential grid dimension, per-block logits live only
-in VMEM, and the MXU stays on one stream of (rows × vocab-block) matmuls.
+traffic per step). This kernel runs an online-logsumexp recurrence over
+vocab blocks INSIDE one ``pallas_call`` — the vocab axis is the innermost
+sequential grid dimension, per-block logits live only in VMEM, and the MXU
+stays on one stream of (rows × vocab-block) matmuls.
 
 Layout notes:
 

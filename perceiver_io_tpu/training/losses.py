@@ -57,149 +57,6 @@ def _ce_bwd(res, g):
 softmax_ce_integer.defvjp(_ce_fwd, _ce_bwd)
 
 
-import functools
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def fused_linear_ce_integer(
-    features: Array, kernel: Array, bias: Array, labels: Array, chunk: int
-) -> Array:
-    """Per-position CE of ``features @ kernel + bias`` vs integer ``labels``,
-    WITHOUT materializing the (..., V) logits.
-
-    The vocab axis is processed in ``chunk``-wide slices with an online
-    logsumexp (flash-attention's trick applied to the classifier head), and
-    the backward recomputes each chunk's logits instead of saving them. The
-    (B, K, V) logits tensor of the unfused path is produced once and re-read
-    ~4x (CE forward, softmax backward, and both matmul transposes) — at the
-    flagship MLM decode shape (64, 160, 10003) that is ~1 GB of HBM traffic
-    per step, ~25% of the step's total (measured from a device profile; see
-    PERF.md). Here per-chunk logits live on-chip only.
-
-    Numerics match the unfused path: the matmul and bias-add run in the
-    features dtype (bf16 accumulates in f32 on the MXU), statistics
-    accumulate in f32.
-    """
-    per_pos, _ = _fused_ce_fwd_impl(features, kernel, bias, labels, chunk)
-    return per_pos
-
-
-def _pad_vocab(kernel: Array, bias: Array, chunk: int):
-    v = kernel.shape[-1]
-    pad = -v % chunk
-    if pad:
-        kernel = jnp.pad(kernel, ((0, 0), (0, pad)))
-        # large-negative (not -inf: no inf arithmetic in any dtype) so padded
-        # columns contribute exp(..) == 0 to the softmax statistics
-        bias = jnp.pad(bias, (0, pad), constant_values=-1e9)
-    return kernel, bias, (v + pad) // chunk
-
-
-def _chunk_logits(features, kernel, bias, i, chunk):
-    w = jax.lax.dynamic_slice_in_dim(kernel, i * chunk, chunk, axis=1)
-    b = jax.lax.dynamic_slice_in_dim(bias, i * chunk, chunk)
-    logits = jnp.einsum(
-        "...kc,cv->...kv", features, w.astype(features.dtype)
-    ) + b.astype(features.dtype)
-    return logits.astype(jnp.float32), w
-
-def _fused_ce_fwd_impl(features, kernel, bias, labels, chunk):
-    kern_p, bias_p, n = _pad_vocab(kernel, bias, chunk)
-    shape = labels.shape
-
-    def body(carry, i):
-        m, s, ll = carry
-        logits, _ = _chunk_logits(features, kern_p, bias_p, i, chunk)
-        m_c = logits.max(axis=-1)
-        m2 = jnp.maximum(m, m_c)
-        s = s * jnp.exp(m - m2) + jnp.exp(
-            logits - m2[..., None]
-        ).sum(axis=-1)
-        in_chunk = (labels >= i * chunk) & (labels < (i + 1) * chunk)
-        idx = jnp.clip(labels - i * chunk, 0, chunk - 1)
-        picked = jnp.take_along_axis(logits, idx[..., None], axis=-1)[..., 0]
-        ll = ll + jnp.where(in_chunk, picked, 0.0)
-        return (m2, s, ll), None
-
-    init = (
-        jnp.full(shape, -jnp.inf, jnp.float32),
-        jnp.zeros(shape, jnp.float32),
-        jnp.zeros(shape, jnp.float32),
-    )
-    (m, s, ll), _ = jax.lax.scan(body, init, jnp.arange(n))
-    lse = m + jnp.log(s)
-    return lse - ll, lse
-
-
-def _fused_ce_fwd(features, kernel, bias, labels, chunk):
-    per_pos, lse = _fused_ce_fwd_impl(features, kernel, bias, labels, chunk)
-    return per_pos, (features, kernel, bias, labels, lse)
-
-
-def _fused_ce_bwd(chunk, res, g):
-    features, kernel, bias, labels, lse = res
-    kern_p, bias_p, n = _pad_vocab(kernel, bias, chunk)
-
-    def body(carry, i):
-        dx, dw, db = carry
-        logits, w = _chunk_logits(features, kern_p, bias_p, i, chunk)
-        p = jnp.exp(logits - lse[..., None])
-        in_chunk = (labels >= i * chunk) & (labels < (i + 1) * chunk)
-        idx = jnp.where(in_chunk, labels - i * chunk, chunk)  # chunk = none
-        onehot = (
-            jax.lax.broadcasted_iota(idx.dtype, logits.shape, logits.ndim - 1)
-            == idx[..., None]
-        )
-        d = ((p - onehot) * g[..., None]).astype(features.dtype)
-        dx = dx + jnp.einsum(
-            "...kv,cv->...kc", d, w.astype(features.dtype),
-            preferred_element_type=jnp.float32,
-        )
-        dw_c = jnp.einsum(
-            "...kc,...kv->cv", features, d, preferred_element_type=jnp.float32
-        )
-        dw = jax.lax.dynamic_update_slice_in_dim(dw, dw_c, i * chunk, axis=1)
-        db_c = d.astype(jnp.float32).sum(axis=tuple(range(d.ndim - 1)))
-        db = jax.lax.dynamic_update_slice_in_dim(db, db_c, i * chunk, axis=0)
-        return (dx, dw, db), None
-
-    init = (
-        jnp.zeros(features.shape, jnp.float32),
-        jnp.zeros(kern_p.shape, jnp.float32),
-        jnp.zeros(bias_p.shape, jnp.float32),
-    )
-    (dx, dw, db), _ = jax.lax.scan(body, init, jnp.arange(n))
-    v = kernel.shape[-1]
-    return (
-        dx.astype(features.dtype),
-        dw[:, :v].astype(kernel.dtype),
-        db[:v].astype(bias.dtype),
-        np.zeros(labels.shape, jax.dtypes.float0),
-    )
-
-
-fused_linear_ce_integer.defvjp(_fused_ce_fwd, _fused_ce_bwd)
-
-
-def fused_linear_cross_entropy_with_ignore(
-    features: Array,
-    kernel: Array,
-    bias: Array,
-    labels: Array,
-    ignore_label: int = IGNORE_LABEL,
-    chunk: int = 512,
-) -> Array:
-    """Mean CE of a linear head applied to ``features``, ignoring
-    ``ignore_label`` positions — :func:`cross_entropy_with_ignore` semantics
-    with the head matmul fused into the chunked loss (the (..., V) logits
-    never materialize, forward or backward)."""
-    valid = labels != ignore_label
-    safe_labels = jnp.where(valid, labels, 0)
-    per_pos = fused_linear_ce_integer(features, kernel, bias, safe_labels, chunk)
-    denom = jnp.maximum(valid.sum(), 1)
-    return jnp.where(valid, per_pos, 0.0).sum() / denom
-
-
 def pallas_linear_cross_entropy_with_ignore(
     features: Array,
     kernel: Array,
@@ -207,12 +64,12 @@ def pallas_linear_cross_entropy_with_ignore(
     labels: Array,
     ignore_label: int = IGNORE_LABEL,
 ) -> Array:
-    """:func:`fused_linear_cross_entropy_with_ignore` semantics on the fused
-    Pallas flash-CE kernel (``ops.pallas_ce``): head matmul + online-logsumexp
-    CE in one kernel, logits never in HBM, forward or backward. The measured
-    winner at the flagship MLM head shapes (PERF.md round 3) — unlike the XLA
-    chunked variant, the vocab loop is a sequential grid inside ONE kernel
-    rather than a scan of dispatches."""
+    """Mean CE of a linear head applied to ``features``, ignoring
+    ``ignore_label`` positions — :func:`cross_entropy_with_ignore` semantics
+    with the head matmul fused into the loss on the Pallas flash-CE kernel
+    (``ops.pallas_ce``): head matmul + online-logsumexp CE in one kernel, the
+    vocab loop a sequential grid inside it; the (..., V) logits never
+    materialize, forward or backward."""
     from perceiver_io_tpu.ops.pallas_ce import pallas_linear_ce_integer
 
     valid = labels != ignore_label

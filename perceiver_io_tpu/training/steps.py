@@ -27,7 +27,6 @@ import optax
 from perceiver_io_tpu.training.losses import (
     classification_loss_and_accuracy,
     cross_entropy_with_ignore,
-    fused_linear_cross_entropy_with_ignore,
     pallas_linear_cross_entropy_with_ignore,
 )
 from perceiver_io_tpu.training.train_state import TrainState
@@ -152,6 +151,39 @@ def mlm_gather_capacity(seq_len: int, mask_p: float = 0.15) -> int:
     return min(seq_len, max(cap, 32))
 
 
+def _make_steps(loss_fn, rng_streams: Sequence[str], schedule: Optional[Schedule]):
+    """The one train step and the one eval step of this module, around
+    ``loss_fn(params, batch, rngs, deterministic) -> (loss, aux)`` (``aux`` a
+    dict of further metrics, possibly empty).
+
+    - ``train_step(state, batch) -> (state, metrics)`` draws the step's
+      ``rng_streams`` from the state (none when the tuple is empty) and
+      publishes ``loss``, ``aux`` and, with a schedule, ``lr``.
+    - ``eval_step(state, batch, key=None) -> metrics`` runs the loss
+      deterministically. ``key`` is the Trainer's stochastic-eval slot: it
+      feeds the ``masking`` stream where the family draws one (the val loss
+      is then measured on corrupted inputs; without a key the stream is the
+      state's own for its step) and is ignored elsewhere.
+    """
+
+    def train_step(state: TrainState, batch) -> Tuple[TrainState, Metrics]:
+        rngs = state.step_rngs(*rng_streams) if rng_streams else {}
+        (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            state.params, batch, rngs, False
+        )
+        metrics = {"loss": loss, **aux, **_lr_metric(schedule, state.step)}
+        return state.apply_gradients(grads), metrics
+
+    def eval_step(state: TrainState, batch, key: Optional[Array] = None) -> Metrics:
+        rngs = {}
+        if "masking" in rng_streams:
+            rngs["masking"] = state.step_rngs("masking")["masking"] if key is None else key
+        loss, aux = loss_fn(state.params, batch, rngs, True)
+        return {"loss": loss, **aux}
+
+    return train_step, eval_step
+
+
 def make_mlm_steps(
     model,
     schedule: Optional[Schedule] = None,
@@ -173,26 +205,16 @@ def make_mlm_steps(
     predict path decodes every position unless the caller passes explicit
     ``positions`` (see ``predict_fn``).
 
-    ``fused_head``: fuse the vocab projection into the CE so the (B, K, V)
-    logits never materialize in train/eval.
-
-    - ``'pallas'``: the fused flash-CE kernel (``ops.pallas_ce``) — matmul +
-      online-logsumexp + label pick inside ONE ``pallas_call``, gradients by
-      blockwise recomputation. The measured WINNER at the flagship MLM head
-      shapes (PERF.md round 3: the unfused head complex streams the 206 MB
-      logits tensor ~5x at HBM peak, ~1.4 ms of a 10.4 ms step).
-    - ``True``: the XLA chunked variant
-      (``fused_linear_cross_entropy_with_ignore``) — a MEMORY lever only; on
-      the flagship config it measured slower at every chunk size (PERF.md
-      negative result #7: the chunk scan serializes 10-20 skinny dispatches).
-      Kept for environments where the Pallas path is unavailable.
-
-    Both are gradient-equivalent to the unfused path (tested); predict is
-    unaffected.
+    ``fused_head``: ``False`` builds the (B, K, V) logits and takes their CE;
+    ``'pallas'`` fuses the vocab projection into the CE on the flash-CE kernel
+    (``ops.pallas_ce``: matmul + online-logsumexp + label pick inside ONE
+    ``pallas_call``, gradients by blockwise recomputation), so the logits
+    never materialize in train/eval. Gradient-equivalent to the unfused path
+    (tested); predict is unaffected.
     """
-    if fused_head not in (False, True, "pallas"):
+    if fused_head not in (False, "pallas"):
         raise ValueError(
-            f"fused_head must be False, True or 'pallas', got {fused_head!r}"
+            f"fused_head must be False or 'pallas', got {fused_head!r}"
         )
 
     def loss_fn(params, batch, rngs, deterministic):
@@ -210,25 +232,8 @@ def make_mlm_steps(
             kernel, bias = model.decoder.output_adapter.masked_head(
                 params["decoder"]["output_adapter"]
             )
-            fused_ce = (
-                pallas_linear_cross_entropy_with_ignore
-                if fused_head == "pallas"
-                else fused_linear_cross_entropy_with_ignore
-            )
-            return fused_ce(out, kernel, bias, labels)
-        return cross_entropy_with_ignore(out, labels)
-
-    def train_step(state: TrainState, batch) -> Tuple[TrainState, Metrics]:
-        rngs = state.step_rngs("masking", "dropout")
-        loss, grads = jax.value_and_grad(loss_fn)(
-            state.params, batch, rngs, False
-        )
-        metrics = {"loss": loss, **_lr_metric(schedule, state.step)}
-        return state.apply_gradients(grads), metrics
-
-    def eval_step(state: TrainState, batch, key: Array) -> Metrics:
-        loss = loss_fn(state.params, batch, {"masking": key}, True)
-        return {"loss": loss}
+            return pallas_linear_cross_entropy_with_ignore(out, kernel, bias, labels), {}
+        return cross_entropy_with_ignore(out, labels), {}
 
     def predict_fn(params, token_ids, pad_mask, positions=None):
         # positions (B, K): decode only those rows of the output-query array
@@ -241,7 +246,7 @@ def make_mlm_steps(
         )
         return logits
 
-    return train_step, eval_step, predict_fn
+    return (*_make_steps(loss_fn, ("masking", "dropout"), schedule), predict_fn)
 
 
 def make_ar_steps(model, schedule: Optional[Schedule] = None,
@@ -266,28 +271,13 @@ def make_ar_steps(model, schedule: Optional[Schedule] = None,
         o = (ids.shape[1] - logits.shape[1] if latent_offset is None
              else latent_offset)
         labels = shift_ar_labels(ids, pad, o)
-        return cross_entropy_with_ignore(logits, labels)
-
-    def train_step(state: TrainState, batch) -> Tuple[TrainState, Metrics]:
-        rngs = state.step_rngs("dropout")
-        loss, grads = jax.value_and_grad(loss_fn)(
-            state.params, batch, rngs, False
-        )
-        metrics = {"loss": loss, **_lr_metric(schedule, state.step)}
-        return state.apply_gradients(grads), metrics
-
-    def eval_step(state: TrainState, batch, key: Optional[Array] = None
-                  ) -> Metrics:
-        # the key parameter is the Trainer's stochastic-eval slot (MLM
-        # masking); AR eval is deterministic, so it is accepted and unused
-        loss = loss_fn(state.params, batch, {}, True)
-        return {"loss": loss}
+        return cross_entropy_with_ignore(logits, labels), {}
 
     def predict_fn(params, token_ids, pad_mask):
         return model.apply({"params": params}, token_ids, pad_mask,
                            latent_offset=latent_offset)
 
-    return train_step, eval_step, predict_fn
+    return (*_make_steps(loss_fn, ("dropout",), schedule), predict_fn)
 
 
 def make_lm_steps(model, schedule: Optional[Schedule] = None):
@@ -301,24 +291,14 @@ def make_lm_steps(model, schedule: Optional[Schedule] = None):
     Trainer's log boundary turns into registry gauges. Nothing is sampled:
     no rng stream is drawn."""
 
-    def loss_fn(params, batch):
+    def loss_fn(params, batch, rngs, deterministic):
         return model.apply({"params": params}, batch["token_ids"], batch["pad_mask"],
                            method=model.loss)
-
-    def train_step(state: TrainState, batch) -> Tuple[TrainState, Metrics]:
-        (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(state.params, batch)
-        metrics = {"loss": loss, **aux, **_lr_metric(schedule, state.step)}
-        return state.apply_gradients(grads), metrics
-
-    def eval_step(state: TrainState, batch, key: Optional[Array] = None) -> Metrics:
-        # the key is the Trainer's stochastic-eval slot; nothing here is sampled
-        loss, aux = loss_fn(state.params, batch)
-        return {"loss": loss, **aux}
 
     def predict_fn(params, token_ids):
         return model.apply({"params": params}, token_ids)
 
-    return train_step, eval_step, predict_fn
+    return (*_make_steps(loss_fn, (), schedule), predict_fn)
 
 
 def make_classifier_steps(
@@ -355,21 +335,9 @@ def make_classifier_steps(
     def loss_fn(params, batch, rngs, deterministic):
         logits = forward(params, batch, rngs, deterministic)
         loss, acc = classification_loss_and_accuracy(logits, batch["label"])
-        return loss, acc
+        return loss, {"acc": acc}
 
-    def train_step(state: TrainState, batch) -> Tuple[TrainState, Metrics]:
-        rngs = state.step_rngs("dropout")
-        (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            state.params, batch, rngs, False
-        )
-        metrics = {"loss": loss, "acc": acc, **_lr_metric(schedule, state.step)}
-        return state.apply_gradients(grads), metrics
-
-    def eval_step(state: TrainState, batch) -> Metrics:
-        loss, acc = loss_fn(state.params, batch, {}, True)
-        return {"loss": loss, "acc": acc}
-
-    return train_step, eval_step
+    return _make_steps(loss_fn, ("dropout",), schedule)
 
 
 def make_multimodal_steps(
@@ -409,19 +377,7 @@ def make_multimodal_steps(
             video_patch_info=video_patch_info,
         )
 
-    def train_step(state: TrainState, batch) -> Tuple[TrainState, Metrics]:
-        rngs = state.step_rngs("dropout")
-        (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            state.params, batch, rngs, False
-        )
-        metrics = {"loss": loss, **aux, **_lr_metric(schedule, state.step)}
-        return state.apply_gradients(grads), metrics
-
-    def eval_step(state: TrainState, batch) -> Metrics:
-        loss, aux = loss_fn(state.params, batch, {}, True)
-        return {"loss": loss, **aux}
-
-    return train_step, eval_step
+    return _make_steps(loss_fn, ("dropout",), schedule)
 
 
 def make_flow_steps(model, schedule: Optional[Schedule] = None):
@@ -435,15 +391,6 @@ def make_flow_steps(model, schedule: Optional[Schedule] = None):
             {"params": params}, batch["frames"], rngs=rngs,
             deterministic=deterministic,
         )
-        return end_point_error(pred, batch["flow"])
+        return end_point_error(pred, batch["flow"]), {}
 
-    def train_step(state: TrainState, batch) -> Tuple[TrainState, Metrics]:
-        rngs = state.step_rngs("dropout")
-        loss, grads = jax.value_and_grad(loss_fn)(state.params, batch, rngs, False)
-        metrics = {"loss": loss, **_lr_metric(schedule, state.step)}
-        return state.apply_gradients(grads), metrics
-
-    def eval_step(state: TrainState, batch) -> Metrics:
-        return {"loss": loss_fn(state.params, batch, {}, True)}
-
-    return train_step, eval_step
+    return _make_steps(loss_fn, ("dropout",), schedule)

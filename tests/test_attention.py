@@ -243,3 +243,15 @@ def test_auto_attention_impl_rule():
     assert impl(512, 256, 256, 4, 16, backend="tpu") == "xla"
     # area below threshold: ImageNet self-attn at batch 8 stays on XLA
     assert impl(8, 512, 512, 8, 128, backend="tpu") == "xla"
+
+
+@pytest.mark.parametrize("impl", ["packed", "flash"])
+def test_unknown_attn_impl_is_refused(impl):
+    """A string the dispatch does not know must not fall through to the XLA
+    branch under the wrong label: refused, with the accepted list."""
+    x = jnp.zeros((1, T, E))
+    mha = MultiHeadAttention(
+        num_q_channels=E, num_kv_channels=E, num_heads=H, attn_impl=impl)
+    with pytest.raises(ValueError, match="'auto', 'xla', 'pallas', 'pallas_sp'") as err:
+        mha.init(jax.random.key(0), x, x)
+    assert repr(impl) in str(err.value)

@@ -64,7 +64,7 @@ def test_train_mlm_hybrid_dcn_mesh(tmp_path):
 
 
 @pytest.mark.slow  # tier-1 budget (r10): fused-head numerics stay tier-1 in
-# tests/test_train_steps.py::test_mlm_step_fused_head_matches_unfused; flag
+# tests/test_pallas_ce.py::TestMLMFusedHeadPallas::test_train_step_matches_unfused; flag
 # parsing in test_all_parsers_build_and_render_help
 def test_train_mlm_fused_head_flag(tmp_path):
     """--fused_head pallas trains end to end (interpret mode off-TPU) and
@@ -972,6 +972,17 @@ def test_all_parsers_build_and_render_help():
                  "--breaker_cooldown_s", "--slo_p99_ms",
                  "--slo_availability", "--slo_burn_alert", "--span_every"):
         assert flag in help_text, f"serve missing {flag}"
+
+
+@pytest.mark.parametrize("flag, value", [("--attn_impl", "packed"), ("--fused_head", "xla")])
+def test_train_mlm_parser_refuses_a_removed_choice(flag, value, capsys):
+    """A path that was deleted is not a choice: argparse exits with the
+    accepted list instead of the value reaching the model."""
+    from perceiver_io_tpu.cli import train_mlm
+
+    with pytest.raises(SystemExit):
+        train_mlm.build_parser().parse_args(["--synthetic", flag, value])
+    assert f"invalid choice: '{value}'" in capsys.readouterr().err
 
 
 def test_mlm_preset_flagship_tpu_defaults():

@@ -358,8 +358,6 @@ class TestSharedLayerKVReuse:
                 rtol=2e-5, atol=max(1e-5, 1e-4 * float(jnp.abs(b).max())),
             )
 
-    @pytest.mark.slow  # tier-1 budget (r10): reuse parity and remat are each
-    # pinned tier-1 on their own in this class; this is their composition
     def test_remat_composes_with_reuse(self):
         """The kv cache crosses the nn.remat boundary as a pytree argument
         (no static bool — PerceiverLayer always returns (latent, kv))."""

@@ -352,125 +352,6 @@ def test_auto_dispatch_threshold(rng, monkeypatch):
     assert calls == [attn_mod.AUTO_PALLAS_MIN_KV]
 
 
-class TestPackedLatentAttention:
-    """Packed-heads small-latent kernel: parity vs the XLA path (fwd + grads).
-
-    End-to-end it currently loses to XLA+bf16-logits at the MLM shapes
-    (PERF.md) — kept as an opt-in ('packed') with exact parity coverage.
-    """
-
-    def _args(self, rng, B=3, T=16, S=24, H=4, D=8, dtype=jnp.float32):
-        E = H * D
-        q = jnp.asarray(rng.normal(0, 1, (B, T, E)), dtype)
-        k = jnp.asarray(rng.normal(0, 1, (B, S, E)), dtype)
-        v = jnp.asarray(rng.normal(0, 1, (B, S, E)), dtype)
-        return q, k, v, H
-
-    def _ref(self, q, k, v, h, pad_mask):
-        from perceiver_io_tpu.ops.attention import _dot_product_attention
-
-        b, t, e = q.shape
-        s = k.shape[1]
-        d = e // h
-        out = _dot_product_attention(
-            q.reshape(b, t, h, d), k.reshape(b, s, h, d), v.reshape(b, s, h, d),
-            pad_mask, None, 0.0, None, True,
-        )
-        return out.reshape(b, t, e)
-
-    @pytest.mark.parametrize("masked", [False, True])
-    def test_forward_parity(self, rng, masked):
-        from perceiver_io_tpu.ops.pallas_attention import packed_latent_attention
-
-        q, k, v, h = self._args(rng)
-        pad = jnp.asarray(rng.random((3, 24)) < 0.3) if masked else None
-        out = packed_latent_attention(q, k, v, h, pad_mask=pad, interpret=True)
-        np.testing.assert_allclose(
-            np.asarray(out), np.asarray(self._ref(q, k, v, h, pad)), atol=2e-6
-        )
-
-    def test_grad_parity(self, rng):
-        from perceiver_io_tpu.ops.pallas_attention import packed_latent_attention
-
-        q, k, v, h = self._args(rng)
-        pad = jnp.asarray(rng.random((3, 24)) < 0.3)
-
-        def loss_packed(q, k, v):
-            out = packed_latent_attention(q, k, v, h, pad_mask=pad, interpret=True)
-            return jnp.sum(jnp.sin(out))
-
-        def loss_ref(q, k, v):
-            return jnp.sum(jnp.sin(self._ref(q, k, v, h, pad)))
-
-        gp = jax.grad(loss_packed, argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(gp, gr):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-6)
-
-    def test_validation(self, rng):
-        from perceiver_io_tpu.ops.pallas_attention import packed_latent_attention
-
-        q, k, v, h = self._args(rng)
-        with pytest.raises(ValueError, match="divisible"):
-            packed_latent_attention(q, k, v, 5, interpret=True)
-        with pytest.raises(ValueError, match="packed"):
-            packed_latent_attention(q[0], k, v, h, interpret=True)
-
-    def test_mha_packed_impl(self, rng):
-        """attn_impl='packed' through the module matches the XLA impl."""
-        from perceiver_io_tpu.ops.attention import MultiHeadAttention
-
-        xq = jnp.asarray(rng.normal(0, 1, (2, 8, 32)), jnp.float32)
-        xkv = jnp.asarray(rng.normal(0, 1, (2, 12, 32)), jnp.float32)
-        pad = jnp.asarray(rng.random((2, 12)) < 0.3)
-        kw = dict(num_q_channels=32, num_kv_channels=32, num_heads=4)
-        m_ref = MultiHeadAttention(**kw, attn_impl="xla")
-        params = m_ref.init(jax.random.key(0), xq, xkv)["params"]
-        m_packed = MultiHeadAttention(**kw, attn_impl="packed")
-        o1 = m_ref.apply({"params": params}, xq, xkv, pad_mask=pad)
-        o2 = m_packed.apply({"params": params}, xq, xkv, pad_mask=pad)
-        np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=2e-6)
-
-    def test_mha_packed_rejects_oversize(self, rng):
-        from perceiver_io_tpu.ops.attention import MultiHeadAttention
-
-        xq = jnp.asarray(rng.normal(0, 1, (1, 2048, 32)), jnp.float32)
-        m = MultiHeadAttention(num_q_channels=32, num_kv_channels=32,
-                               num_heads=4, attn_impl="packed")
-        with pytest.raises(ValueError, match="packed"):
-            m.init(jax.random.key(0), xq, xq)
-
-    def test_fully_masked_row_grads_match_xla(self, rng):
-        """A fully padded example must give zero dq/dk (XLA where-parity)."""
-        from perceiver_io_tpu.ops.pallas_attention import packed_latent_attention
-
-        q, k, v, h = self._args(rng, B=2)
-        pad = jnp.zeros((2, 24), bool).at[1].set(True)  # example 1 all-masked
-
-        def loss_packed(q, k, v):
-            out = packed_latent_attention(q, k, v, h, pad_mask=pad, interpret=True)
-            return jnp.sum(jnp.sin(out))
-
-        def loss_ref(q, k, v):
-            return jnp.sum(jnp.sin(self._ref(q, k, v, h, pad)))
-
-        gp = jax.grad(loss_packed, argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-        for name, a, b in zip("qkv", gp, gr):
-            np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), atol=2e-6,
-                err_msg=f"d{name} mismatch on fully-masked row",
-            )
-        np.testing.assert_allclose(np.asarray(gp[0][1]), 0.0, atol=1e-7)
-        np.testing.assert_allclose(np.asarray(gp[1][1]), 0.0, atol=1e-7)
-
-    def test_vmem_budget_rejects_oversize(self):
-        from perceiver_io_tpu.ops.pallas_attention import packed_fits_vmem
-
-        assert packed_fits_vmem(256, 512, 64)          # MLM cross
-        assert not packed_fits_vmem(1024, 1024, 512)   # backward can't fit
-
-
 # -- sequence-parallel fused attention ---------------------------------------
 
 

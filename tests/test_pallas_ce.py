@@ -121,10 +121,6 @@ class TestPallasLinearCE:
 
 
 class TestMLMFusedHeadPallas:
-    @pytest.mark.slow  # near-duplicate of tests/test_train_steps.py::
-    # test_mlm_step_fused_head_matches_unfused (full tier); op-level
-    # fused-head value+grad parity stays tier-1 in
-    # test_train_steps.py::test_fused_head_matches_unfused
     def test_train_step_matches_unfused(self, rng):
         """fused_head='pallas' must reproduce the unfused loss trajectory
         (gradient equivalence through Adam updates)."""
@@ -180,11 +176,12 @@ class TestMLMFusedHeadPallas:
 
         np.testing.assert_allclose(run("pallas"), run(False), atol=2e-5)
 
-    def test_invalid_fused_head_rejected(self):
+    @pytest.mark.parametrize("value", ["nope", True])
+    def test_invalid_fused_head_rejected(self, value):
         from perceiver_io_tpu.training import make_mlm_steps
 
         with pytest.raises(ValueError, match="fused_head"):
-            make_mlm_steps(object(), fused_head="nope")
+            make_mlm_steps(object(), fused_head=value)
 
 
 class TestRandomGeometryFuzz:

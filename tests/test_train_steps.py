@@ -24,8 +24,12 @@ from perceiver_io_tpu.training import (
     OptimizerConfig,
     cross_entropy_with_ignore,
     freeze_subtrees,
+    make_ar_steps,
     make_classifier_steps,
+    make_flow_steps,
+    make_lm_steps,
     make_mlm_steps,
+    make_multimodal_steps,
     make_optimizer,
 )
 
@@ -80,6 +84,108 @@ def build_mlm():
         vocab_size=VOCAB, unk_token_id=1, mask_token_id=2, num_special_tokens=3
     )
     return PerceiverMLM(encoder=enc, decoder=dec, masking=masking)
+
+
+def _token_batch(rng):
+    ids = jnp.asarray(rng.integers(3, VOCAB, (2, L)).astype(np.int32))
+    return {"token_ids": ids, "pad_mask": jnp.zeros((2, L), bool)}
+
+
+def _family_mlm(rng, schedule):
+    model = build_mlm()
+    batch = _token_batch(rng)
+    init = model.init({"params": jax.random.key(0), "masking": jax.random.key(1)},
+                      batch["token_ids"], batch["pad_mask"])
+    return make_mlm_steps(model, schedule, loss_gather_capacity=8)[:2], init, batch, set()
+
+
+def _family_ar(rng, schedule):
+    from perceiver_io_tpu.models.presets import tiny_ar
+
+    model = tiny_ar(vocab_size=VOCAB, max_seq_len=L, num_latents=8)
+    batch = _token_batch(rng)
+    init = model.init({"params": jax.random.key(0)}, batch["token_ids"], batch["pad_mask"])
+    return make_ar_steps(model, schedule)[:2], init, batch, set()
+
+
+def _family_lm(rng, schedule):
+    from perceiver_io_tpu.cli import train_lm
+    from perceiver_io_tpu.models.decoder_lm import DecoderLM, DecoderLMConfig
+
+    config = DecoderLMConfig.from_dict(dict(
+        train_lm.SMALL, vocab_size=VOCAB, hidden_size=32, intermediate_size=48,
+        moe_intermediate_size=16, num_hidden_layers=2, num_attention_heads=2, q_lora_rank=24,
+        kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8))
+    model = DecoderLM(config, dtype=jnp.float32)
+    batch = _token_batch(rng)
+    init = model.init({"params": jax.random.key(0)}, batch["token_ids"][:1])
+    return make_lm_steps(model, schedule)[:2], init, batch, {
+        "loss_main", "loss_mtp", "moe_load_max_over_mean", "moe_local_assignment_pct",
+        "moe_dropped_assignments"}
+
+
+def _family_classifier(rng, schedule):
+    model = build_image_classifier()
+    batch = {"image": jnp.asarray(rng.normal(0, 1, (2, 8, 8, 1)), jnp.float32),
+             "label": jnp.asarray([0, 3], jnp.int32)}
+    init = model.init({"params": jax.random.key(0)}, batch["image"])
+    return make_classifier_steps(model, schedule, input_kind="image"), init, batch, {"acc"}
+
+
+def _family_multimodal(rng, schedule):
+    from perceiver_io_tpu.models.multimodal import build_multimodal_autoencoder
+
+    model = build_multimodal_autoencoder(
+        video_shape=(2, 8, 8, 1), num_audio_samples=64, samples_per_patch=8, num_classes=3,
+        latent_shape=(8, 32), video_patch_shape=(1, 4, 4), num_self_attention_layers_per_block=1,
+        num_self_attention_heads=2, num_modality_channels=4, video_frequency_bands=2,
+        audio_frequency_bands=2)
+    batch = {"video": jnp.asarray(rng.normal(0, 1, (2, 2, 8, 8, 1)), jnp.float32),
+             "audio": jnp.asarray(rng.normal(0, 1, (2, 64, 1)), jnp.float32),
+             "label": jnp.asarray([0, 2], jnp.int32)}
+    init = model.init({"params": jax.random.key(0)},
+                      {"video": batch["video"], "audio": batch["audio"]})
+    return make_multimodal_steps(model, schedule), init, batch, {
+        "video_loss", "audio_loss", "label_loss", "video_psnr", "acc"}
+
+
+def _family_flow(rng, schedule):
+    from perceiver_io_tpu.models.flow import build_optical_flow_model
+
+    model = build_optical_flow_model(
+        image_shape=(8, 8, 1), latent_shape=(8, 32), num_self_attention_layers_per_block=1,
+        num_self_attention_heads=2, num_frequency_bands=2)
+    batch = {"frames": jnp.asarray(rng.normal(0, 1, (2, 2, 8, 8, 1)), jnp.float32),
+             "flow": jnp.asarray(rng.normal(0, 1, (2, 8, 8, 2)), jnp.float32)}
+    init = model.init({"params": jax.random.key(0)}, batch["frames"])
+    return make_flow_steps(model, schedule), init, batch, set()
+
+
+@pytest.mark.parametrize(
+    "family", [_family_mlm, _family_ar, _family_lm, _family_classifier, _family_multimodal,
+               _family_flow], ids=lambda f: f.__name__.removeprefix("_family_"))
+def test_every_factory_gives_the_same_step_contract(family, rng):
+    """The six factories share one skeleton: ``train_step(state, batch)``
+    advances the step and publishes a finite ``loss``, the family's own
+    metrics and the schedule's ``lr``; ``eval_step`` takes the Trainer's key
+    or none, and only a family that samples its masking reads it."""
+    tx, schedule = make_optimizer(OptimizerConfig(learning_rate=1e-3))
+    (train_step, eval_step), init, batch, aux = family(rng, schedule)
+    state = TrainState.create(init["params"], tx, jax.random.key(2))
+
+    new_state, metrics = jax.jit(train_step)(state, batch)
+    assert int(new_state.step) == int(state.step) + 1
+    assert np.isfinite(float(metrics["loss"]))
+    assert metrics.keys() >= {"loss", "lr"} | aux
+    np.testing.assert_allclose(float(metrics["lr"]), float(schedule(state.step)))
+
+    keyless, keyed = jax.jit(lambda s, b, k: (eval_step(s, b), eval_step(s, b, k)))(
+        state, batch, jax.random.key(9))
+    assert keyless.keys() == keyed.keys() >= {"loss"} | aux
+    assert all(np.isfinite(float(v)) for v in keyed.values())
+    if family is not _family_mlm:  # no masking stream: the key changes nothing
+        for name in keyed:
+            assert float(keyless[name]) == float(keyed[name]), name
 
 
 @pytest.mark.slow  # convergence smoke duplicated by the trainer fit
@@ -250,124 +356,12 @@ def test_lean_ce_matches_optax(rng):
         )
 
 
-def test_fused_head_matches_unfused(rng):
-    """fused_linear_cross_entropy_with_ignore == Dense + cross_entropy_with_ignore
-    in value AND gradients (all inputs), f32."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from perceiver_io_tpu.training.losses import (
-        cross_entropy_with_ignore,
-        fused_linear_cross_entropy_with_ignore,
-    )
-
-    B, K, C, V = 3, 7, 16, 1003  # V deliberately not a chunk multiple
-    x = jnp.asarray(rng.normal(0, 1, (B, K, C)).astype(np.float32))
-    w = jnp.asarray(rng.normal(0, 0.1, (C, V)).astype(np.float32))
-    b = jnp.asarray(rng.normal(0, 0.1, (V,)).astype(np.float32))
-    labels = jnp.asarray(rng.integers(0, V, (B, K)).astype(np.int32))
-    labels = labels.at[0, :3].set(-100).at[2, -1].set(-100)
-
-    def unfused(x, w, b):
-        return cross_entropy_with_ignore(x @ w + b, labels)
-
-    def fused(x, w, b):
-        return fused_linear_cross_entropy_with_ignore(
-            x, w, b, labels, chunk=256
-        )
-
-    ref, ref_grads = jax.value_and_grad(unfused, argnums=(0, 1, 2))(x, w, b)
-    got, got_grads = jax.value_and_grad(fused, argnums=(0, 1, 2))(x, w, b)
-    np.testing.assert_allclose(float(got), float(ref), rtol=1e-6)
-    for g, r in zip(got_grads, ref_grads):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=1e-6)
-
-
-@pytest.mark.slow  # tier-1 budget (r21): fused-vs-unfused value+grad parity
-# stays tier-1 at the op level (test_fused_head_matches_unfused,
-# test_fused_head_with_padded_vocab); the CLI flag e2e stays in
-# tests/test_cli.py::test_train_mlm_fused_head_flag
-def test_mlm_step_fused_head_matches_unfused(rng):
-    """Full MLM train step: fused_head=True tracks the unfused loss/grads."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    import perceiver_io_tpu as pit
-    from perceiver_io_tpu.ops.masking import TextMasking
-    from perceiver_io_tpu.training import (
-        OptimizerConfig,
-        TrainState,
-        make_mlm_steps,
-        make_optimizer,
-    )
-
-    VOCAB, L, C, NLAT = 60, 24, 16, 8
-    model = pit.PerceiverMLM(
-        encoder=pit.PerceiverEncoder(
-            input_adapter=pit.TextInputAdapter(
-                vocab_size=VOCAB, max_seq_len=L, num_channels=C),
-            latent_shape=(NLAT, C), num_layers=2,
-        ),
-        decoder=pit.PerceiverDecoder(
-            output_adapter=pit.TextOutputAdapter(
-                vocab_size=VOCAB, max_seq_len=L, num_output_channels=C),
-            latent_shape=(NLAT, C),
-        ),
-        masking=TextMasking(VOCAB, 1, 2, 3),
-    )
-    ids = jnp.asarray(rng.integers(3, VOCAB, (4, L)).astype(np.int32))
-    batch = {"token_ids": ids, "pad_mask": jnp.zeros((4, L), bool)}
-    variables = model.init(
-        {"params": jax.random.key(0), "masking": jax.random.key(1)}, ids,
-        batch["pad_mask"],
-    )
-    tx, sched = make_optimizer(OptimizerConfig(learning_rate=1e-3))
-
-    losses = {}
-    params_out = {}
-    for fused in (False, True):
-        state = TrainState.create(
-            jax.tree.map(jnp.copy, variables["params"]), tx, jax.random.key(2)
-        )
-        step, eval_step, _ = make_mlm_steps(
-            model, sched, loss_gather_capacity=8, fused_head=fused
-        )
-        jit_step = jax.jit(step)
-        ls = []
-        for _ in range(3):
-            state, m = jit_step(state, batch)
-            ls.append(float(m["loss"]))
-        losses[fused] = ls
-        params_out[fused] = state.params
-        # eval path too
-        losses[(fused, "eval")] = float(
-            eval_step(state, batch, jax.random.key(9))["loss"]
-        )
-    # the loss trajectory is the tight assertion: a wrong gradient would
-    # compound through the 3 Adam steps and break it
-    np.testing.assert_allclose(losses[True], losses[False], rtol=1e-5)
-    np.testing.assert_allclose(
-        losses[(True, "eval")], losses[(False, "eval")], rtol=1e-5
-    )
-    # params agree to Adam noise: where a gradient is ~0, float-level
-    # association differences (chunked vs full reductions) decide the
-    # update's sign, bounding per-step divergence at O(lr) — the same
-    # tolerance reasoning as test_golden_model's trajectory test
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), atol=2.5e-3
-        ),
-        params_out[True], params_out[False],
-    )
-
-
-@pytest.mark.slow  # tier-1 budget (r10): fused-head parity stays tier-1 in
-# test_mlm_step_fused_head_matches_unfused; padded-vocab head behavior in
+@pytest.mark.slow  # 23 s alone on the CPU (two step compiles, the kernel
+# interpreted); the padded head's masking is tier-1 in
 # tests/test_sharding.py::test_padded_vocab_projection_shards_under_tp
 def test_fused_head_with_padded_vocab(rng):
-    """pad_classes_to: padded columns must not leak into the fused lse."""
+    """pad_classes_to: padded columns must not leak into the fused head's
+    logsumexp (the flash-CE kernel, interpreted off-TPU)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -407,11 +401,11 @@ def test_fused_head_with_padded_vocab(rng):
     )
     tx, sched = make_optimizer(OptimizerConfig(learning_rate=1e-3))
     out = {}
-    for fused in (False, True):
+    for fused in (False, "pallas"):
         state = TrainState.create(
             jax.tree.map(jnp.copy, variables["params"]), tx, jax.random.key(2)
         )
         step, _, _ = make_mlm_steps(padded, sched, fused_head=fused)
         state, m = jax.jit(step)(state, batch)
         out[fused] = float(m["loss"])
-    np.testing.assert_allclose(out[True], out[False], rtol=1e-5)
+    np.testing.assert_allclose(out["pallas"], out[False], rtol=1e-5)

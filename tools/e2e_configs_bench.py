@@ -74,7 +74,7 @@ def _image_classifier(image_shape, num_classes, latents, channels, blocks,
 def _mlm_config(model_factory, batch_size: int, default_head: str,
                 seq: int = 512):
     """Shared MLM bench recipe (synthetic batch, gather decode, PIT_E2E_HEAD
-    override: 'pallas'|'xla'|'none' — 'none' also feeds hbm_roofline's
+    override: 'pallas'|'none' — 'none' also feeds hbm_roofline's
     MFU-numerator build, where cost analysis must see the head's flops;
     PIT_E2E_DEC_ATTN overrides the DECODER attention impl separately —
     the gather-decode cross is a many-queries/few-keys shape that can
@@ -92,7 +92,7 @@ def _mlm_config(model_factory, batch_size: int, default_head: str,
         batch["token_ids"], batch["pad_mask"],
     )
     head = os.environ.get("PIT_E2E_HEAD", default_head)
-    fused_head = {"pallas": "pallas", "xla": True, "none": False}[head]
+    fused_head = {"pallas": "pallas", "none": False}[head]
     train_step, _, _ = make_mlm_steps(
         model, loss_gather_capacity=mlm_gather_capacity(seq),
         fused_head=fused_head,
