@@ -25,11 +25,17 @@ mean CE_mtp``; each mean is over its own valid targets (the last position, the
 last two for the MTP term, and padding are ignored).
 
 Compute runs in ``dtype`` (bfloat16 on the chip) with float32 parameters,
-router, norms and softmaxes. Each block is rematerialised whole
-(``nn.remat``, nothing saved but the block's input): its backward pass
-recomputes the block once. The two head-and-loss computations are
-rematerialised too, so that no (tokens, vocabulary) float32 logits wait for
-the backward pass.
+router, norms and softmaxes. Each block is rematerialised (``nn.remat``): its
+backward pass recomputes the block once from its input. Where the causal
+attention is the Pallas kernel, the block also keeps what the kernel's
+backward needs of its forward (the output and one max and one denominator a
+row, ``REMAT_FUSED_*`` of ``ops/pallas_attention.py``), as long as those bytes
+over all layers fit the share of the device's memory that
+``models.perceiver.remat_keeps`` allows: the recomputation then holds
+everything but the kernel (``DecoderLM._remat_policy``; the loss's
+``attention_residuals_kept_pct`` is 100 there and 0 elsewhere). The two
+head-and-loss computations are rematerialised whole, so that no (tokens,
+vocabulary) float32 logits wait for the backward pass.
 
 Scopes a device trace is cut by: ``embed``, ``mla_attention``, ``moe/*``,
 ``mtp`` (everything the module runs, its attention and experts included) and
@@ -45,8 +51,13 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from perceiver_io_tpu.models.perceiver import remat_keeps
 from perceiver_io_tpu.ops.attention import torch_linear_kernel_init
-from perceiver_io_tpu.ops.latent_attention import MultiHeadLatentAttention, RMSNorm
+from perceiver_io_tpu.ops.latent_attention import (
+    MultiHeadLatentAttention,
+    RMSNorm,
+    resolve_causal_impl,
+)
 from perceiver_io_tpu.ops.masking import IGNORE_LABEL
 from perceiver_io_tpu.ops.moe import Kernel, MoELayer, SwiGLU
 from perceiver_io_tpu.training.losses import cross_entropy_with_ignore
@@ -142,15 +153,8 @@ class DecoderLM(nn.Module):
 
     def setup(self):
         c = self.config
-        block = nn.remat(DecoderBlock)
-
-        def make(routed, name):
-            return block(c, routed, self.attn_impl, self.expert_impl, self.dtype, name=name)
-
         self.embed = nn.Embed(c.vocab_size, c.hidden_size, dtype=self.dtype,
                               embedding_init=nn.initializers.normal(0.02), name="embed")
-        self.layers = [make(i >= c.first_k_dense_replace, f"layer_{i}")
-                       for i in range(c.num_hidden_layers)]
         self.final_norm = RMSNorm(c.rms_norm_eps, self.dtype, name="final_norm")
         self.head = Kernel((c.hidden_size, c.vocab_size), name="head")
         if c.num_nextn_predict_layers:
@@ -158,29 +162,59 @@ class DecoderLM(nn.Module):
             self.mtp_hnorm = RMSNorm(c.rms_norm_eps, self.dtype, name="mtp_hnorm")
             self.mtp_eh_proj = nn.Dense(c.hidden_size, use_bias=False, dtype=self.dtype,
                                         kernel_init=torch_linear_kernel_init, name="mtp_eh_proj")
-            self.mtp_block = make(True, "mtp_block")
             self.mtp_final_norm = RMSNorm(c.rms_norm_eps, self.dtype, name="mtp_final_norm")
 
-    def hidden_states(self, token_ids: Array) -> Tuple[Array, Optional[Array], list]:
-        """``(h, h_mtp, stats)``: the main stack's and the MTP module's
+    def _remat_policy(self, b: int, t: int):
+        """The ``jax.checkpoint`` policy of the blocks for (B, T) ids; None
+        recomputes whole blocks (the bare ``nn.remat``). Decided once per
+        trace, from shapes and the device (``remat_keeps``).
+
+        Kept where the causal attention is the Pallas kernel: its output, B x
+        T x H x ``v_head_dim`` in ``dtype``, and its two float32 statistics a
+        row and head, for every block (the MTP module's too). The blocked XLA
+        path names nothing (its blocks sit under checkpoints of their own)."""
+        c = self.config
+        layers = c.num_hidden_layers + c.num_nextn_predict_layers
+        saved = 0
+        if resolve_causal_impl(self.attn_impl) == "pallas":
+            saved = layers * b * t * c.num_attention_heads * (
+                c.v_head_dim * jnp.dtype(self.dtype).itemsize + 8)
+        if not remat_keeps(saved, b, layers):
+            return None
+        from perceiver_io_tpu.ops.pallas_attention import REMAT_FUSED_OUT, REMAT_FUSED_STATS
+
+        return jax.checkpoint_policies.save_only_these_names(REMAT_FUSED_OUT, REMAT_FUSED_STATS)
+
+    @nn.compact
+    def hidden_states(self, token_ids: Array) -> Tuple[Array, Optional[Array], list, bool]:
+        """``(h, h_mtp, stats, kept)``: the main stack's and the MTP module's
         outputs after their final norms (``h_mtp`` None without the module),
-        and the expert layers' statistics, one dict a layer."""
+        the expert layers' statistics, one dict a layer, and whether the
+        blocks keep the causal kernel's residuals (``_remat_policy``)."""
+        c = self.config
+        policy = self._remat_policy(*token_ids.shape)
+        kept = policy is not None
+        block = nn.remat(DecoderBlock, policy=policy)
+
+        def make(routed, name):
+            return block(c, routed, self.attn_impl, self.expert_impl, self.dtype, name=name)
+
         with jax.named_scope("embed"):
             x = self.embed(token_ids)
         stats = []
-        for layer in self.layers:
-            x, s = layer(x)
+        for i in range(c.num_hidden_layers):
+            x, s = make(i >= c.first_k_dense_replace, f"layer_{i}")(x)
             stats.append(s)
         h = self.final_norm(x)
-        if not self.config.num_nextn_predict_layers:
-            return h, None, stats
+        if not c.num_nextn_predict_layers:
+            return h, None, stats, kept
         with jax.named_scope("mtp"):
             with jax.named_scope("embed"):
                 following = self.mtp_enorm(self.embed(jnp.roll(token_ids, -1, axis=1)))
             x = self.mtp_eh_proj(jnp.concatenate([following, self.mtp_hnorm(h)], axis=-1))
-            x, s = self.mtp_block(x)
+            x, s = make(True, "mtp_block")(x)
             stats.append(s)
-            return h, self.mtp_final_norm(x), stats
+            return h, self.mtp_final_norm(x), stats, kept
 
     def _logits(self, h: Array, kernel: Array) -> Array:
         return jnp.dot(h, kernel.astype(self.dtype), preferred_element_type=jnp.float32)
@@ -188,13 +222,13 @@ class DecoderLM(nn.Module):
     def __call__(self, token_ids: Array) -> Tuple[Array, Optional[Array]]:
         """(B, T) ids -> float32 logits ``(main, mtp)``, each (B, T, vocab):
         ``main[:, i]`` scores ``t_{i+1}``, ``mtp[:, i]`` scores ``t_{i+2}``."""
-        h, h_mtp, _ = self.hidden_states(token_ids)
+        h, h_mtp, _, _ = self.hidden_states(token_ids)
         kernel = self.head()
         return self._logits(h, kernel), None if h_mtp is None else self._logits(h_mtp, kernel)
 
     def loss(self, token_ids: Array, pad_mask: Optional[Array] = None) -> Tuple[Array, dict]:
         """The two-term loss and the step's metrics (float32 scalars)."""
-        h, h_mtp, stats = self.hidden_states(token_ids)
+        h, h_mtp, stats, kept = self.hidden_states(token_ids)
         kernel = self.head()
 
         def head_loss(hidden, kernel, labels):
@@ -203,7 +237,8 @@ class DecoderLM(nn.Module):
 
         head_loss = jax.checkpoint(head_loss)
         loss_main = head_loss(h, kernel, next_token_labels(token_ids, pad_mask, 1))
-        metrics = {"loss_main": loss_main}
+        metrics = {"loss_main": loss_main,
+                   "attention_residuals_kept_pct": jnp.float32(100.0 if kept else 0.0)}
         loss = loss_main
         if h_mtp is not None:
             with jax.named_scope("mtp"):

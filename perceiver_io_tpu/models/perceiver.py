@@ -70,6 +70,26 @@ def _device_bytes_limit() -> Optional[int]:
     return stats.get("bytes_limit") if stats else None
 
 
+def remat_keeps(saved: int, batch: int, layers: int) -> bool:
+    """Whether rematerialised layers may keep ``saved`` bytes of named
+    residuals (all ``layers`` together, for ``batch`` rows): per device where
+    a mesh shares the batch, they must fit ``REMAT_KEEP_FRACTION`` of the
+    device's memory. ``saved`` 0 (nothing on this path is worth a name) and a
+    device that reports no limit keep nothing. Called once per trace by the
+    model that owns the layers; reports the decision as one ``remat.policy``
+    event."""
+    mesh = active_step_mesh()
+    shards = mesh.shape.get(AXIS_DATA, 1) if mesh is not None else 1
+    if batch % shards == 0:
+        saved //= shards
+    limit = _device_bytes_limit()
+    budget = None if limit is None else int(limit * REMAT_KEEP_FRACTION)
+    engaged = budget is not None and 0 < saved <= budget
+    obs.event("remat.policy", engaged=engaged, layers=layers,
+              saved_bytes=saved, budget_bytes=budget)
+    return engaged
+
+
 def latent_init(std: float = 0.02, clamp: float = 2.0):
     """~N(0, std) clamped to ±clamp (reference ``model.py:169-174``)."""
 
@@ -182,16 +202,7 @@ class PerceiverEncoder(nn.Module):
             kv_sets = 1 + (min(shared, 1) if self.reuse_kv else shared)
             saved = (self.num_layers * (b * h * t * s + b * t * e)
                      + kv_sets * 2 * b * s * e) * jnp.dtype(self.dtype).itemsize
-            mesh = active_step_mesh()
-            shards = mesh.shape.get(AXIS_DATA, 1) if mesh is not None else 1
-            if b % shards == 0:
-                saved //= shards
-        limit = _device_bytes_limit()
-        budget = None if limit is None else int(limit * REMAT_KEEP_FRACTION)
-        engaged = budget is not None and 0 < saved <= budget
-        obs.event("remat.policy", engaged=engaged, layers=self.num_layers,
-                  saved_bytes=saved, budget_bytes=budget)
-        if not engaged:
+        if not remat_keeps(saved, b, self.num_layers):
             return None
         return jax.checkpoint_policies.save_only_these_names(
             REMAT_CROSS_LOGITS, REMAT_CROSS_CONTEXT, REMAT_CROSS_KV)

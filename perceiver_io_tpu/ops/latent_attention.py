@@ -74,12 +74,19 @@ def _causal_block(q: Array, k: Array, v: Array, first_row: int) -> Array:
                       ).astype(v.dtype)
 
 
+def resolve_causal_impl(impl: str) -> str:
+    """What ``'auto'`` stands for: the kernel on a TPU, the blocked path
+    elsewhere. Any other string is returned as it is."""
+    if impl == "auto":
+        return "pallas" if jax.default_backend() == "tpu" else "xla"
+    return impl
+
+
 def causal_attention(q: Array, k: Array, v: Array, impl: str = "auto",
                      query_block: int = XLA_QUERY_BLOCK) -> Array:
     """Causal self-attention of (B, T, H, D) q and k with (B, T, H, Dv) v:
     row i sees keys 0..i. Returns (B, T, H, Dv)."""
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
+    impl = resolve_causal_impl(impl)
     if impl == "pallas":
         from perceiver_io_tpu.ops.pallas_attention import fused_attention
 

@@ -23,12 +23,22 @@ Design:
 - backward: fused flash backward — two Pallas kernels (dq; dk/dv) recompute
   the probabilities blockwise as exp(logits − m)/l from the saved softmax
   max ``m`` and denominator ``l``, so the (T, S) logits never materialize in
-  HBM in either direction. ``m``/``l`` are saved lane-broadcast as
-  (B, H, T, 128) f32 (the layout jax's own TPU flash-attention kernel uses —
-  sublane↔lane moves are not free on Mosaic) and kept separate rather than
-  folded into a logsumexp, which would absorb log l on fully padded rows
-  (m = -1e30 in f32); ``delta = Σ_d g·out`` is computed in XLA and passed in
-  the same layout. On a fully padded row the probabilities recompute as
+  HBM in either direction. The kernels write and read ``m``/``l``
+  lane-broadcast as (B, H, T, 128) f32 (the layout jax's own TPU
+  flash-attention kernel uses — sublane↔lane moves are not free on Mosaic),
+  but the ``custom_vjp`` saves one float a row, (B, H, T), and broadcasts it
+  back for the backward kernels: 128 times less to hold between the two
+  passes. (Not (B, H, T, 1): the TPU's tiled layout pads a minor dimension
+  of 1 to the 128 lanes again, and the slice becomes a bitcast that saves
+  nothing; seen in a compile for a described v5e.) They are kept separate
+  rather than folded into a logsumexp, which would absorb log l on fully
+  padded rows (m = -1e30 in f32); ``delta = Σ_d g·out`` is computed in XLA
+  and passed in the lane-broadcast layout too.
+  The saved output and the two statistics carry ``checkpoint_name``s
+  (``REMAT_FUSED_OUT``, ``REMAT_FUSED_STATS``): a ``jax.checkpoint`` whose
+  policy keeps those names recomputes its function without this forward
+  kernel (``models/decoder_lm.py``); without such a policy the names do
+  nothing. On a fully padded row the probabilities recompute as
   uniform 1/l (the -1e30 bias absorbs the logits in f32 rounding), ``dv``
   keeps the uniform contribution, and ``ds`` is zeroed so dq/dk match the
   XLA path's where-style masking (zero grads through the mask).
@@ -44,6 +54,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -62,6 +73,10 @@ _LANES = 128
 KERNEL_FWD = "fused_attention_fwd"
 KERNEL_DQ = "fused_attention_dq"
 KERNEL_DKV = "fused_attention_dkv"
+# ``checkpoint_name``s of what ``_fused_attention`` saves for its backward: the
+# output, and the two row statistics (one name for both)
+REMAT_FUSED_OUT = "fused_attention_out"
+REMAT_FUSED_STATS = "fused_attention_stats"
 DEFAULT_KV_BLOCK = 512
 DEFAULT_Q_BLOCK = 512
 # Test hook (tests/test_pallas_attention.py fuzz): force the COMPILED lane
@@ -537,11 +552,17 @@ def _fwd(q, k, v, bias, t_blk, s_blk, interpret, causal_offset, skip_masked):
         q, k, v, bias, t_blk, s_blk, interpret, with_lse=True,
         causal_offset=causal_offset, skip_masked=skip_masked,
     )
-    return out, (q, k, v, bias, out, m, l)
+    # the primal output is the named one too: whatever reads it in a
+    # recomputation then reads the kept copy and needs no kernel
+    out = checkpoint_name(out, REMAT_FUSED_OUT)
+    m1 = checkpoint_name(m[..., 0], REMAT_FUSED_STATS)
+    l1 = checkpoint_name(l[..., 0], REMAT_FUSED_STATS)
+    return out, (q, k, v, bias, out, m1, l1)
 
 
 def _bwd(t_blk, s_blk, interpret, causal_offset, skip_masked, residuals, g):
-    q, k, v, bias, out, m, l = residuals
+    q, k, v, bias, out, m1, l1 = residuals
+    m, l = (jnp.broadcast_to(x[..., None], x.shape + (_LANES,)) for x in (m1, l1))
     dq, dk, dv = _fused_attention_bwd_impl(
         q, k, v, bias, out, m, l, g, t_blk, s_blk, interpret,
         causal_offset=causal_offset, skip_masked=skip_masked,

@@ -287,8 +287,9 @@ def make_lm_steps(model, schedule: Optional[Schedule] = None):
     next-token CE plus the weighted multi-token-prediction term); the step's
     metrics carry its parts (``loss_main``, ``loss_mtp``) and the expert
     layers' routing statistics (``moe_load_max_over_mean``,
-    ``moe_local_assignment_pct``, ``moe_dropped_assignments``), which the
-    Trainer's log boundary turns into registry gauges. Nothing is sampled:
+    ``moe_local_assignment_pct``, ``moe_dropped_assignments``) and
+    ``attention_residuals_kept_pct`` (what the blocks' remat keeps), which
+    the Trainer's log boundary turns into registry gauges. Nothing is sampled:
     no rng stream is drawn."""
 
     def loss_fn(params, batch, rngs, deterministic):

@@ -28,3 +28,24 @@ import pytest
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture
+def remat_policy_events(tmp_path):
+    """A reader of the ``remat.policy`` records written since the test
+    began (``models.perceiver.remat_keeps`` emits one a trace)."""
+    import json
+
+    from perceiver_io_tpu import obs
+
+    path = tmp_path / "events.jsonl"
+    obs.configure_event_log(str(path))
+
+    def read():
+        obs.configure_event_log(None)  # drains, then closes
+        with open(path) as f:
+            records = [json.loads(line) for line in f]
+        return [r for r in records if r.get("event") == "remat.policy"]
+
+    yield read
+    obs.configure_event_log(None)

@@ -340,6 +340,62 @@ def test_tiles_are_skipped_only_where_that_is_exact(case):
     assert worst(got[0, 0, 0], first_tile) > 0.05
 
 
+@pytest.mark.parametrize("case", ["fits", "over_budget", "no_limit", "xla"])
+def test_blocks_keep_the_causal_kernels_residuals(case, monkeypatch, remat_policy_events):
+    """The blocks' rematerialisation (``DecoderLM._remat_policy``) on the
+    kernel path (interpreter) with the MTP module: where the kept bytes fit
+    the allowed share of the device's memory (the CPU reports none: one is put
+    in through ``_device_bytes_limit``), a step runs the forward kernel once a
+    block and not twice, the loss and every gradient leaf are the bare
+    ``nn.remat`` build's, and the metric reads 100; over the budget, without a
+    limit and on the ``'xla'`` path the program IS the bare build."""
+    from perceiver_io_tpu.models import decoder_lm, perceiver
+    from perceiver_io_tpu.ops import pallas_attention as pa
+    from test_pallas_attention import _kernel_calls
+
+    cfg, mix, builder = tiny_cell()
+    cfg["attn_impl"] = "xla" if case == "xla" else "pallas"
+    params, ids, pad = seeded(cfg, mix, builder)
+    blocks = cfg["num_hidden_layers"] + 1  # the MTP module's
+    b, t = ids.shape
+    # float32: the output's v_head_dim x 4 bytes and two float32 statistics a row and head
+    formula = blocks * b * t * cfg["num_attention_heads"] * (cfg["v_head_dim"] * 4 + 8)
+    limit = {"fits": 16e9, "xla": 16e9, "no_limit": None,
+             "over_budget": formula / perceiver.REMAT_KEEP_FRACTION - 8}[case]
+    monkeypatch.setattr(perceiver, "_device_bytes_limit", lambda: limit)
+    model, _ = builder.build_model(cfg)
+
+    def step(p):
+        return jax.value_and_grad(
+            lambda p: model.apply({"params": p}, ids, pad, method=model.loss), has_aux=True)(p)
+
+    def lowered():  # a function of its own each time: jit keeps a trace by function
+        return jax.jit(lambda p: step(p)).lower(params).as_text()
+
+    engaged = case == "fits"
+    (loss, metrics), grads = step(params)
+    record = remat_policy_events()[-1]
+    assert record["engaged"] is engaged and record["layers"] == blocks
+    assert record["saved_bytes"] == (0 if case == "xla" else formula)
+    assert record["budget_bytes"] == (
+        None if limit is None else int(limit * perceiver.REMAT_KEEP_FRACTION))
+    assert float(metrics["attention_residuals_kept_pct"]) == (100.0 if engaged else 0.0)
+    calls = [_kernel_calls(jax.make_jaxpr(step)(params).jaxpr, kernel)
+             for kernel in (pa.KERNEL_FWD, pa.KERNEL_DQ, pa.KERNEL_DKV)]
+    per_block = {"fits": [1, 1, 1], "xla": [0, 0, 0]}.get(case, [2, 1, 1])
+    assert calls == [blocks * n for n in per_block]
+    text = lowered()
+
+    # the bare nn.remat: the program before the blocks kept anything
+    monkeypatch.setattr(decoder_lm, "remat_keeps", lambda *a: False)
+    (loss_bare, _), grads_bare = step(params)
+    assert float(loss) == float(loss_bare)
+    for (path, got), want in zip(jax.tree_util.tree_leaves_with_path(grads),
+                                 jax.tree.leaves(grads_bare)):
+        assert np.array_equal(np.asarray(got), np.asarray(want)), jax.tree_util.keystr(path)
+    assert (text == lowered()) is not engaged
+
+
 def test_published_config_gives_the_cells_parameter_count():
     """The configuration as run (all published widths, 5 layers, 8 of 256
     experts, 16,160 vocabulary rows) is 491.7 M parameters = 7.87 GB at 16 B."""
@@ -374,3 +430,4 @@ def test_train_lm_cli_three_synthetic_steps(tmp_path):
                       rtol=1e-5)
     assert {"moe_load_max_over_mean", "moe_local_assignment_pct"} <= set(gauges)
     assert gauges["moe_bounded_path_pct"] == 100.0  # half the experts held: one path
+    assert gauges["attention_residuals_kept_pct"] == 0.0  # off a TPU: the blocked XLA path

@@ -1,7 +1,6 @@
 """Structural tests for the Perceiver core: weight sharing, shapes, masking flow."""
 
 import contextlib
-import json
 import re
 
 import numpy as np
@@ -494,24 +493,8 @@ class TestSelectiveRemat:
         monkeypatch.setattr(attention, "checkpoint_name", lambda x, name: x)
         assert lowered() == named
 
-    @pytest.fixture
-    def events(self, tmp_path):
-        from perceiver_io_tpu import obs
-
-        path = tmp_path / "events.jsonl"
-        obs.configure_event_log(str(path))
-
-        def read():
-            obs.configure_event_log(None)  # drains, then closes
-            with open(path) as f:
-                records = [json.loads(line) for line in f]
-            return [r for r in records if r.get("event") == "remat.policy"]
-
-        yield read
-        obs.configure_event_log(None)
-
     @pytest.mark.parametrize("case", ["engaged", "over_budget", "no_limit", "dp_mesh"])
-    def test_remat_policy_event(self, case, events, monkeypatch):
+    def test_remat_policy_event(self, case, remat_policy_events, monkeypatch):
         from perceiver_io_tpu.models import perceiver
         from perceiver_io_tpu.parallel import make_mesh
         from perceiver_io_tpu.parallel.mesh import step_mesh_context
@@ -533,7 +516,7 @@ class TestSelectiveRemat:
         with (step_mesh_context(make_mesh(dp=8)) if case == "dp_mesh"
               else contextlib.nullcontext()):
             jax.eval_shape(lambda p, x: enc.apply({"params": p}, x), params, image)
-        record = events()[-1]
+        record = remat_policy_events()[-1]
         engaged = case in ("engaged", "dp_mesh")
         assert record["engaged"] is engaged and record["layers"] == layers
         assert record["saved_bytes"] == (reckoned // 8 if case == "dp_mesh" else reckoned)

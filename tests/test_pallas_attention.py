@@ -231,21 +231,97 @@ def test_bfloat16(rng):
     )
 
 
-def test_gradients_match_xla(rng):
-    b, t, s, h, d = 2, 4, 32, 2, 8
+def _kernel_calls(jaxpr, name):
+    """Times ``jaxpr`` (sub-jaxprs included) calls the pallas_call named
+    ``name``; a kernel's own body is not searched."""
+    count = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            count += eqn.params["name"] == name
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    count += _kernel_calls(sub, name)
+    return count
+
+
+# b, t, s, h, d, padded share of the keys, causal_offset, kv_block, q_block
+GRADIENT_CASES = {
+    "causal_tile_skipping": (2, 32, 32, 2, 8, 0.0, 0, 16, 8),
+    "causal_pad_mask": (2, 32, 32, 2, 8, 0.25, 0, 16, 8),
+    "non_causal": (2, 16, 32, 2, 8, 0.0, None, 16, 8),
+    "pad_mask": (2, 4, 32, 2, 8, 0.25, None, 16, None),
+    "query_blocks": (1, 12, 24, 1, 8, 0.0, None, 8, 4),
+}
+
+
+@pytest.mark.parametrize("case", list(GRADIENT_CASES))
+def test_gradients_and_kept_residuals(rng, case, capsys):
+    """Two attention layers in a row, three ways: no checkpoint, each layer
+    under a bare ``jax.checkpoint``, each under one whose policy keeps the
+    kernel's named residuals. The gradients match the XLA path's and are the
+    same bits all three ways; the bare checkpoint runs the forward kernel
+    twice a layer, the policy once (the recomputation holds none), the two
+    backward kernels once either way; what the policy keeps is the output and
+    ONE float a row of each statistic."""
+    import perceiver_io_tpu.ops.pallas_attention as pa
+
+    b, t, s, h, d, padded, offset, kv_blk, q_blk = GRADIENT_CASES[case]
+    layers = 2
     q, k, v = (_rand(rng, b, n, h, d) for n in (t, s, s))
-    pad_mask = jnp.asarray(rng.random((b, s)) < 0.25)
+    pad_mask = None
+    if padded:
+        # key 0 stays: under the causal rule every row then sees a key
+        pad_mask = jnp.asarray(rng.random((b, s)) < padded).at[:, 0].set(False)
+    above = (None if offset is None
+             else jnp.arange(s)[None, :] > jnp.arange(t)[:, None] + offset)
 
-    def loss_fused(q, k, v):
-        return jnp.sum(fused_attention(q, k, v, pad_mask, kv_block_size=16) ** 2)
+    def fused(x, k, v):
+        return x + fused_attention(x, k, v, pad_mask, kv_block_size=kv_blk,
+                                   q_block_size=q_blk, causal_offset=offset)
 
-    def loss_xla(q, k, v):
-        return jnp.sum(_xla(q, k, v, pad_mask) ** 2)
+    def xla(x, k, v):
+        return x + _dot_product_attention(x, k, v, pad_mask, above, 0.0, None, True)
 
-    g_fused = jax.grad(loss_fused, argnums=(0, 1, 2))(q, k, v)
-    g_xla = jax.grad(loss_xla, argnums=(0, 1, 2))(q, k, v)
-    for gf, gx in zip(g_fused, g_xla):
-        np.testing.assert_allclose(np.asarray(gf), np.asarray(gx), atol=1e-5)
+    def loss(layer):
+        def fn(x, k, v):
+            for _ in range(layers):
+                x = layer(x, k, v)
+            return jnp.sum(x ** 2)
+        return fn
+
+    def grad(layer):
+        return jax.grad(loss(layer), argnums=(0, 1, 2))
+
+    keeping = jax.checkpoint(fused, policy=jax.checkpoint_policies.save_only_these_names(
+        pa.REMAT_FUSED_OUT, pa.REMAT_FUSED_STATS))
+    grads = {"none": grad(fused), "bare": grad(jax.checkpoint(fused)),
+             "policy": grad(keeping)}
+    calls = {
+        name: [_kernel_calls(jax.make_jaxpr(grad)(q, k, v).jaxpr, kernel)
+               for kernel in (pa.KERNEL_FWD, pa.KERNEL_DQ, pa.KERNEL_DKV)]
+        for name, grad in grads.items()}
+    assert calls == {"none": [layers] * 3, "bare": [2 * layers, layers, layers],
+                     "policy": [layers] * 3}
+
+    got = {name: grad(q, k, v) for name, grad in grads.items()}
+    for g_fused, g_xla in zip(got["none"], grad(xla)(q, k, v)):
+        np.testing.assert_allclose(np.asarray(g_fused), np.asarray(g_xla), atol=2e-5)
+    for name in ("bare", "policy"):
+        for g, g_none in zip(got[name], got["none"]):
+            assert np.array_equal(np.asarray(g), np.asarray(g_none)), name
+
+    # kept between the passes under the policy: per layer the (B, H, T, D)
+    # output and two (B, H, T) statistics, nothing lane-broadcast
+    capsys.readouterr()
+    jax.ad_checkpoint.print_saved_residuals(loss(keeping), q, k, v)
+    kept = [line.split()[0] for line in capsys.readouterr().out.splitlines()
+            if "from the argument" not in line]
+    assert kept.count(f"f32[{b},{h},{t}]") == 2 * layers
+    assert kept.count(f"f32[{b},{h},{t},{d}]") == layers
+    assert not any(shape.endswith((",128]", ",1]")) for shape in kept)
 
 
 def test_fully_masked_row_zero_qk_grads(rng):
@@ -299,23 +375,6 @@ def test_query_blocking_matches_xla(rng, t, s, q_blk):
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(_xla(q, k, v, pad)), atol=2e-5
     )
-
-
-def test_query_blocking_gradients(rng):
-    q = _rand(rng, 1, 12, 1, 8)
-    k = _rand(rng, 1, 24, 1, 8)
-    v = _rand(rng, 1, 24, 1, 8)
-
-    def loss_fused(q, k, v):
-        return jnp.sum(fused_attention(q, k, v, kv_block_size=8, q_block_size=4) ** 2)
-
-    def loss_xla(q, k, v):
-        return jnp.sum(_xla(q, k, v) ** 2)
-
-    g_fused = jax.grad(loss_fused, argnums=(0, 1, 2))(q, k, v)
-    g_xla = jax.grad(loss_xla, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g_fused, g_xla):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
 
 
 def test_auto_dispatch_threshold(rng, monkeypatch):

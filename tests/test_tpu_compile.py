@@ -168,3 +168,46 @@ def test_kernel_compiles_for_v5e(name, one_chip):
             for shape, dtype in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_kept_attention_residuals_are_compact_on_v5e(one_chip):
+    """What the decoder's blocks keep of the causal kernel between the passes
+    (``DecoderLM._remat_policy``), compiled for the chip at the cell's shape
+    and depth: six checkpointed layers run the forward kernel once each
+    instead of twice, and the program's temporaries grow by no more than the
+    bytes the policy reckons (the output and ONE float a row and head of each
+    statistic). That bound is what interpret mode cannot see: kept as (B, H,
+    T, 1), the tiled layout pads each statistic back to 128 lanes, 268 MB
+    where 2 are meant, and the six layers cost 1.3 GB more, not less."""
+    import re
+
+    from perceiver_io_tpu.ops import pallas_attention as pa
+
+    b, t, h, d, dv, layers = 4, 4096, 32, 192, 128, 6
+    blocks = _mla_blocks()
+
+    def layer(q, k, v):
+        return pa.fused_attention(q, k, v, causal_offset=0, interpret=False,
+                                  q_block_size=blocks[0], kv_block_size=blocks[1])
+
+    def compiled(checkpoint):
+        def loss(q, k, v):
+            for _ in range(layers):
+                v = checkpoint(layer)(q, k, v)
+            return jnp.sum(v.astype(jnp.float32) ** 2)
+
+        args = [jax.ShapeDtypeStruct((b, t, h, depth), jnp.bfloat16, sharding=one_chip)
+                for depth in (d, d, dv)]
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*args).compile()
+
+    def forward_kernels(program):
+        return len(re.findall(rf"%{pa.KERNEL_FWD}(\.\d+)? = ", program.as_text()))
+
+    policy = jax.checkpoint_policies.save_only_these_names(
+        pa.REMAT_FUSED_OUT, pa.REMAT_FUSED_STATS)
+    bare = compiled(jax.checkpoint)
+    keeping = compiled(functools.partial(jax.checkpoint, policy=policy))
+    assert (forward_kernels(bare), forward_kernels(keeping)) == (2 * layers, layers)
+    more = (keeping.memory_analysis().temp_size_in_bytes
+            - bare.memory_analysis().temp_size_in_bytes)
+    assert more <= layers * b * t * h * (dv * 2 + 8)
