@@ -14,13 +14,15 @@ expert's rows are padded up to whole tiles of ``tile_rows`` in a row buffer,
 and the grouped matmul (``ops/pallas_grouped_matmul``) skips the tiles no
 expert owns. The buffer's size follows the load. A router that favours no
 expert sends ``tokens * top_k * held / num_experts`` rows here; the layer
-sizes its buffer for ``CAPACITY_FACTOR`` times that (``capacity_tiles``, from
-its own static shapes), counts the tiles the step's routing needs, and a
-``lax.cond`` runs the routed part over that buffer where the routing fits and
-over the worst-case buffer (every token choosing ``min(top_k, experts_held)``
-experts held here: ``worst_case_tiles``) where it does not. Where the
-capacity is no smaller than the worst case (all experts held, or a large
-share) there is one path and no branch.
+sizes its buffer for ``CAPACITY_FACTOR`` times that, and for no more than the
+expected rows plus one part in ``WORST_CASE_PART`` of the way from them to the
+worst case (``capacity_tiles``, from its own static shapes: the first binds a
+small share, the second a large one), counts the tiles the step's routing
+needs, and a ``lax.cond`` runs the routed part over that buffer where the
+routing fits and over the worst-case buffer (every assignment of every token
+falling to an expert held here: ``worst_case_tiles``) where it does not. Only
+where every expert is held are the expected rows the worst case: there is one
+path then and no branch.
 
 Moving rows. Over the bounded buffer everything is done in buffer space: a row
 knows its token and its gate, tokens become rows by a gather of buffer rows
@@ -50,8 +52,13 @@ Array = jax.Array
 
 TILE_ROWS = 256  # rows of one tile of the grouped matmul: an expert's rows are padded to these
 # the bounded buffer holds this many times the rows of a router that favours
-# no expert: a share trained alone was seen to drift from 1x to 2.6-2.9x
+# no expert: a share of 1/32 trained alone was seen to drift from 1x to 2.6-2.9x
 CAPACITY_FACTOR = 4
+# ... and never more than the expected rows plus one part in this many of the
+# way from them to the worst case: a share of a quarter cannot drift by 4x
+# without holding every assignment, and was seen to drift by +0.7 points in 50
+# steps (PERF.md section 6, PR 37, has the three parts that were measured)
+WORST_CASE_PART = 3
 
 
 def worst_case_tiles(assignments: int, held: int, tile_rows: int) -> int:
@@ -61,12 +68,18 @@ def worst_case_tiles(assignments: int, held: int, tile_rows: int) -> int:
 
 
 def capacity_tiles(tokens: int, top_k: int, held: int, num_experts: int, tile_rows: int) -> int:
-    """Tiles of the buffer the common path runs over: ``CAPACITY_FACTOR``
-    times the rows a router that favours no expert sends to ``held`` of
-    ``num_experts``, and ``held`` more (an expert's last tile is part
-    padding); the worst case where that is no larger."""
-    bounded = -(-CAPACITY_FACTOR * tokens * top_k * held // (num_experts * tile_rows)) + held
-    return min(bounded, worst_case_tiles(tokens * top_k, held, tile_rows))
+    """Tiles of the buffer the common path runs over. With ``expected`` the
+    rows a router that favours no expert sends to ``held`` of ``num_experts``
+    and ``worst`` every assignment: the smaller of ``CAPACITY_FACTOR *
+    expected`` and ``expected + (worst - expected) / WORST_CASE_PART`` rows,
+    and ``held`` tiles more (an expert's last tile is part padding); never
+    more than the worst case, which it is where every expert is held."""
+    assignments = tokens * top_k
+    scale = num_experts * WORST_CASE_PART  # the rows below are times this: they stay whole
+    expected, worst = assignments * held * WORST_CASE_PART, assignments * scale
+    rows = min(CAPACITY_FACTOR * expected, expected + (worst - expected) // WORST_CASE_PART)
+    return min(-(-rows // (scale * tile_rows)) + held,
+               worst_case_tiles(assignments, held, tile_rows))
 
 
 class Routing(NamedTuple):

@@ -8,9 +8,10 @@ is a plain tiled matmul whose weight block is chosen by a prefetched scalar.
 tile past the last group. Such a tile is not computed and not fetched (its
 block indices stay where the last real tile left them; a block whose index
 does not change is not copied again) and its output is zeros. The buffer holds
-a multiple of the expected load (``ops/moe.py`` ``capacity_tiles``; the worst
-routing only where a step overflows that), so such tiles are the larger part
-of it, not nearly all.
+a bounded multiple of the expected load (``ops/moe.py`` ``capacity_tiles``:
+four times that of a small share, twice that of a quarter; the worst routing
+only where a step overflows it), so such tiles are half to three quarters of
+it, not nearly all.
 
 Backward: the gradient of ``lhs`` is the same product against the transposed
 weights; the gradient of ``rhs`` (``grouped_matmul_transposed``) sums

@@ -151,58 +151,82 @@ def test_no_assignment_is_dropped_when_every_token_picks_the_same_experts(impl):
     assert float(stats["load_max_over_mean"]) == 2.0     # 48 each, the other two held idle
 
 
-# A layer whose capacity is smaller than its worst case: 2 of 16 experts (5
-# and 6) held, top 2, 48 tokens in tiles of 8. A router that favours no expert
-# sends 12 rows here, so the bounded buffer is ceil(4 * 12 / 8) + 2 = 8 tiles
-# of the worst case's 96 / 8 + 2 = 14; two experts with 48 rows each need 12.
-BOUNDED = dict(num_experts=PUBLISHED_EXPERTS, top_k=2, experts_held=2, expert_offset=5,
-               tile_rows=8)
+# Layers whose capacity is smaller than their worst case, 48 tokens in tiles of
+# 8. SMALL share: 2 of 16 experts (5 and 6) held, top 2. A router that favours
+# no expert sends 12 rows here; four times that is 6 tiles, a third of the way
+# from 12 rows to the worst case's 96 is 40 rows = 5 tiles, so the bounded
+# buffer is 5 + 2 = 7 tiles of the worst case's 96 / 8 + 2 = 14. QUARTER share
+# (the LFM2 cell's): 4 of 16 (4 to 7) held, top 4. 48 rows are expected, a third
+# of the way to the worst case's 192 is 96 rows: 12 + 4 = 16 tiles of 24 + 4 = 28.
+BOUNDED = {
+    "small": dict(num_experts=PUBLISHED_EXPERTS, top_k=2, experts_held=2, expert_offset=5,
+                  tile_rows=8),
+    "quarter": dict(num_experts=PUBLISHED_EXPERTS, top_k=4, experts_held=4, expert_offset=4,
+                    tile_rows=8),
+}
+CAPACITY = {"small": (7, 14), "quarter": (16, 28)}  # the bounded buffer's tiles, the worst case's
 
 
-def _bias_that_needs(case, scores):
-    """A selection bias under which the 48 tokens' routing to experts 5 and
-    6 needs fewer tiles than the capacity, exactly its 8, or the 12 over it."""
+def _bias_that_needs(case, scores, share):
+    """A selection bias under which the 48 tokens' routing to the held
+    experts needs fewer tiles than the capacity, exactly the capacity, or
+    more: the share of the 48 * top_k assignments computed here with it."""
+    kw, (capacity, _) = BOUNDED[share], CAPACITY[share]
+    first, held, top_k = kw["expert_offset"], kw["experts_held"], kw["top_k"]
     bias = jnp.zeros(PUBLISHED_EXPERTS)
     if case == "under":
-        return bias
-    bias = bias.at[5].set(10.0)  # every token's first choice: 6 tiles
-    if case == "over":
-        return bias.at[6].set(10.0)
-    # 'exact': 12 tokens choose expert 6 second, 2 tiles more: its bias lies
-    # between the 12th and the 13th smallest lead of the best other expert
-    lead = jnp.sort(jnp.max(scores.at[:, 5:7].set(0.0), axis=-1) - scores[:, 6])
-    return bias.at[6].set((lead[11] + lead[12]) / 2)
+        return bias, None
+    if case == "over":  # every choice of every token is held here: 6 tiles an expert
+        return bias.at[first:first + held].set(10.0), 100.0
+    # 'exact': ``full`` experts are every token's choice (6 tiles each), the
+    # next is chosen last by as many tokens as fill the tiles left, the rest
+    # of the held ones by none
+    full, left = divmod(capacity, 6)
+    bias = bias.at[first:first + held].set(-10.0).at[first:first + full].set(10.0)
+    chosen = 8 * left
+    # the next expert's bias lies between the ``chosen``-th and the following
+    # smallest lead of the last choice among the experts not held
+    others = jnp.sort(scores.at[:, first:first + held].set(0.0), axis=-1)[:, -(top_k - full)]
+    lead = jnp.sort(others - scores[:, first + full])
+    bias = bias.at[first + full].set((lead[chosen - 1] + lead[chosen]) / 2)
+    return bias, 100.0 * (48 * full + chosen) / (48 * top_k)
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-@pytest.mark.parametrize("case, bounded_pct, local_pct", [
-    ("under", 100.0, None), ("exact", 100.0, 62.5), ("over", 0.0, 100.0), ("all_held", 100.0, 100.0)])
-def test_row_buffer_follows_the_load(case, bounded_pct, local_pct, impl):
+@pytest.mark.parametrize("case, share, bounded_pct", [
+    ("under", "small", 100.0), ("exact", "small", 100.0), ("over", "small", 0.0),
+    ("under", "quarter", 100.0), ("exact", "quarter", 100.0), ("over", "quarter", 0.0),
+    ("all_held", "small", 100.0)])
+def test_row_buffer_follows_the_load(case, share, bounded_pct, impl):
     """Output and the gradients of the input, the router and the three expert
     kernels, through ``nn.remat`` and ``value_and_grad`` as the step takes
     them, against the plain reference: on the bounded buffer, at its last
-    tile, over it (the worst-case buffer), and with every expert held, where
-    the layer builds one path and no ``cond``."""
+    tile, over it (the worst-case buffer), for a small share and for a
+    quarter, and with every expert held, where the layer builds one path and
+    no ``cond``."""
     cfg, mix, builder = tiny_cell()
     params, _, _ = seeded(cfg, mix, builder)
     p = dict(params["layer_1"]["moe"])
     x = jax.random.normal(jax.random.key(9), (2, 24, cfg["hidden_size"]))
     weight = jax.random.normal(jax.random.key(10), x.shape)
-    kw = dict(BOUNDED, width=cfg["moe_intermediate_size"], expert_impl=impl,
+    kw = dict(BOUNDED[share], width=cfg["moe_intermediate_size"], expert_impl=impl,
               routed_scaling_factor=cfg["routed_scaling_factor"])
+    local_pct = 100.0
     if case == "all_held":
         kw.update(experts_held=None, expert_offset=0)
     else:
         scores = jax.nn.sigmoid(jnp.dot(x.reshape(-1, x.shape[-1]), p["router"]["kernel"],
                                         precision=jax.lax.Precision.HIGHEST))
-        p["e_score_correction_bias"] = _bias_that_needs(case, scores)
-        p.update({k: {"kernel": p[k]["kernel"][5:7]}
+        p["e_score_correction_bias"], local_pct = _bias_that_needs(case, scores, share)
+        mine = slice(kw["expert_offset"], kw["expert_offset"] + kw["experts_held"])
+        p.update({k: {"kernel": p[k]["kernel"][mine]}
                   for k in ("experts_gate", "experts_up", "experts_down")})
-        assert moe.capacity_tiles(48, 2, 2, PUBLISHED_EXPERTS, 8) == 8
-        assert moe.worst_case_tiles(96, 2, 8) == 14
+        assert (moe.capacity_tiles(48, kw["top_k"], kw["experts_held"], PUBLISHED_EXPERTS, 8),
+                moe.worst_case_tiles(48 * kw["top_k"], kw["experts_held"], 8)) == CAPACITY[share]
     layer = nn.remat(moe.MoELayer)(**kw)
     held = kw["experts_held"] or PUBLISHED_EXPERTS
-    sz = dict(builder.sizes(cfg), top_k=2, experts_held=held, expert_offset=kw["expert_offset"])
+    sz = dict(builder.sizes(cfg), top_k=kw["top_k"], experts_held=held,
+              expert_offset=kw["expert_offset"])
 
     def program(p, x):
         y, stats = layer.apply({"params": p}, x)
@@ -220,10 +244,35 @@ def test_row_buffer_follows_the_load(case, bounded_pct, local_pct, impl):
         assert worst(got[0][leaf]["kernel"], want[0][leaf]["kernel"]) < GRAD_TOL, leaf
     assert float(stats["dropped_assignments"]) == 0
     assert float(stats["bounded_path_pct"]) == bounded_pct
-    if local_pct is not None:  # 48 + 12 and 48 + 48 of the 96 assignments
-        assert float(stats["local_assignment_pct"]) == local_pct
+    if local_pct is not None:
+        assert np.isclose(float(stats["local_assignment_pct"]), local_pct)
     if impl == "xla":  # the interpreted kernel's ``pl.when``s are conds too
         assert str(jax.make_jaxpr(program)(p, x)).count("cond[") == (case != "all_held")
+
+
+# (tokens, top_k, held, experts, tile rows) -> tiles: the two decoder cells, a
+# share between theirs, and every expert held (the worst case: one path)
+@pytest.mark.parametrize("shapes, tiles", [
+    ((16384, 8, 8, 256, 256), 72), ((16384, 4, 8, 32, 256), 136),
+    ((16384, 8, 32, 256, 256), 246), ((16384, 4, 32, 32, 256), 288),
+    ((16384, 8, 256, 256, 256), 768)],
+    ids=["joyai_cell", "lfm2_cell", "an_eighth", "lfm2_all_held", "joyai_all_held"])
+def test_capacity_follows_the_static_shapes(shapes, tiles):
+    """``capacity_tiles`` for the two real cells (the JoyAI cell's is PR 33's
+    72: its step does not move; the LFM2 cell's lies well under its worst
+    case's 264 and over the 74 tiles a step needs), the worst case exactly where
+    every expert is held, under it for every share, and never smaller for
+    more experts held."""
+    tokens, top_k, held, experts, tile_rows = shapes
+    worst_case = moe.worst_case_tiles(tokens * top_k, held, tile_rows)
+    assert moe.capacity_tiles(*shapes) == tiles
+    assert (tiles == worst_case) == (held == experts)
+    by_held = [moe.capacity_tiles(tokens, top_k, h, experts, tile_rows)
+               for h in range(1, experts + 1)]
+    assert by_held == sorted(by_held) and by_held[-1] == moe.worst_case_tiles(
+        tokens * top_k, experts, tile_rows)
+    assert all(c < moe.worst_case_tiles(tokens * top_k, h, tile_rows)
+               for h, c in enumerate(by_held[:-1], start=1))
 
 
 def test_grouped_matmul_kernel_matches_masked_matmuls():
@@ -429,5 +478,7 @@ def test_train_lm_cli_three_synthetic_steps(tmp_path):
     assert np.isclose(gauges["train_loss"], gauges["loss_main"] + 0.3 * gauges["loss_mtp"],
                       rtol=1e-5)
     assert {"moe_load_max_over_mean", "moe_local_assignment_pct"} <= set(gauges)
-    assert gauges["moe_bounded_path_pct"] == 100.0  # half the experts held: one path
+    # 4 of 8 experts held, 256 tokens x top 2 in tiles of 256: capacity and worst
+    # case are the same 6 tiles, one path
+    assert gauges["moe_bounded_path_pct"] == 100.0
     assert gauges["attention_residuals_kept_pct"] == 0.0  # off a TPU: the blocked XLA path

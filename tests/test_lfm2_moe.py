@@ -94,7 +94,9 @@ def program_and_reference(dtype, held, offset):
         lambda p: model.apply({"params": p}, ids, pad, method=model.loss), has_aux=True)(params)
     assert float(metrics["loss_main"]) == float(loss) and "loss_mtp" not in metrics
     assert float(metrics["moe_dropped_assignments"]) == 0
-    assert float(metrics["moe_bounded_path_pct"]) == 100.0  # a quarter held: one path
+    # 48 tokens x top 4 are one tile of 256 and 8 tiles of padding, whatever the
+    # share: capacity and worst case are the same 9 tiles, the layers build one path
+    assert float(metrics["moe_bounded_path_pct"]) == 100.0
     leaves = jax.tree.map(lambda g, w: worst(g, w) if float(jnp.max(jnp.abs(w))) else
                           float(jnp.max(jnp.abs(g))), got, want)
     return (worst(main, want_logits), abs(float(loss) - float(want_loss)) / float(want_loss),
@@ -120,15 +122,21 @@ def test_bfloat16_fails_the_tolerances():
 def test_four_shares_of_eight_experts_add_up_to_the_uncut_layer():
     """The guide's share test: the parts that the four shares of 8 experts
     give add up to what the uncut reference gives for the whole 32-expert
-    layer. There is no shared expert to count once."""
+    layer. There is no shared expert to count once. In tiles of 8 rows a
+    quarter share's bounded buffer is 20 tiles of the worst case's 32: each
+    share's routing fits it, and a bias that sends every token's four choices
+    to the first share overflows it onto the worst-case buffer; nothing is
+    dropped on either path."""
     cfg, mix, builder = tiny_cell(held=PUBLISHED_EXPERTS)
     params, _, _ = seeded(cfg, mix, builder)
     p = params["layer_2"]["moe"]
     assert "shared_expert" not in p
     x = jax.random.normal(jax.random.key(7), (2, 24, cfg["hidden_size"]))
     whole = ref.expert_layer(ref_common.F32, p, x, builder.sizes(cfg))
+    assert moe.capacity_tiles(48, 4, 8, PUBLISHED_EXPERTS, 8) == 20
+    assert moe.worst_case_tiles(192, 8, 8) == 32
 
-    def share(offset, held=8):
+    def share(offset, p=p, bounded_pct=100.0, held=8):
         layer = moe.MoELayer(
             num_experts=PUBLISHED_EXPERTS, top_k=cfg["num_experts_per_tok"],
             width=cfg["moe_intermediate_size"], num_shared=0, gate_eps=ref.GATE_EPS,
@@ -139,7 +147,7 @@ def test_four_shares_of_eight_experts_add_up_to_the_uncut_layer():
                           for k in ("experts_gate", "experts_up", "experts_down")})
         y, stats = layer.apply({"params": mine}, x)
         assert float(stats["dropped_assignments"]) == 0
-        assert float(stats["bounded_path_pct"]) == 100.0
+        assert float(stats["bounded_path_pct"]) == bounded_pct
         # the same share of the reference
         sz = dict(builder.sizes(cfg), experts_held=held, expert_offset=offset)
         assert worst(y, ref.expert_layer(ref_common.F32, mine, x, sz)) < LOGIT_TOL
@@ -148,6 +156,8 @@ def test_four_shares_of_eight_experts_add_up_to_the_uncut_layer():
     parts, shares = zip(*(share(offset) for offset in range(0, PUBLISHED_EXPERTS, 8)))
     assert worst(sum(parts), whole) < LOGIT_TOL
     assert np.isclose(sum(shares), 100.0)
+    crowded = dict(p, expert_bias={"scale": jnp.zeros(PUBLISHED_EXPERTS).at[:8].set(10.0)})
+    assert share(0, crowded, bounded_pct=0.0)[1] == 100.0  # 192 rows: 24 tiles or more of the 20
 
 
 def test_gate_epsilon_is_the_familys():
@@ -318,8 +328,9 @@ def test_published_config_gives_the_cells_parameter_count():
     assert (c.n_routed_experts, c.experts_held, c.first_k_dense_replace) == (32, 8, 1)
     assert (c.num_key_value_heads, c.head_dim, c.conv_L_cache) == (8, 64, 3)
     assert c.tie_word_embeddings and c.n_shared_experts == 0 and c.gate_eps == 1e-6
-    assert moe.capacity_tiles(16384, 4, 8, 32, moe.TILE_ROWS) == 264 == moe.worst_case_tiles(
-        65536, 8, moe.TILE_ROWS)  # 4 x the expected rows IS the worst case: one path
+    # twice the expected rows and 8 tiles: a cond between that buffer and the worst case's
+    assert moe.capacity_tiles(16384, 4, 8, 32, moe.TILE_ROWS) == 136 < moe.worst_case_tiles(
+        65536, 8, moe.TILE_ROWS) == 264
 
 
 @pytest.mark.parametrize("change, match", [
@@ -381,24 +392,32 @@ def test_remat_policy_counts_the_attention_layers(case, monkeypatch, remat_polic
     assert calls == ([1, 1, 1] if case == "fits" else [0, 0, 0])
 
 
-# The three older cells' ``train_step`` at tiny widths, lowered: the text's
-# SHA-256 as the parent of PR 36 lowered it (commit 9a37da4; the decoder once on
-# the blocked XLA path and once on the kernels in interpret mode). A PR that
-# MEANS to change one of these steps puts the new hash here and says so.
+# The cells' ``train_step`` at tiny widths, lowered: the text's SHA-256. The
+# three older cells' as the parent of PR 36 lowered them (commit 9a37da4; the
+# decoder once on the blocked XLA path and once on the kernels in interpret
+# mode; that tiny decoder holds all of its 16 experts, so these guard
+# everything in ``ops/moe.py`` BUT ``capacity_tiles``, whose 72 tiles for the
+# real cell ``test_decoder_lm.py`` pins), this family's as PR 37 left it (a
+# quarter share: each expert layer lowers a ``cond`` between a buffer of 10
+# tiles and the worst case's 11). A PR that MEANS to change one of these steps
+# puts the new hash here and says so.
 LOWERED = {
     "joyai": "f89fcdb957e2cfde72a6e1c9163034347002db24d77e33757cff8a5c41e4da84",
     "joyai_kernels": "1ed0657c28754918315bc621dd1f1cc9467a7bf867709b77da50609be10db580",
     "mlm": "fba30f389c39c9d1dc3333dfe497a7bb344dd00fbb4203a7d8509315f6e48cd2",
     "images": "2c8254997124a2bb19229df934c63e950fbd52615d9fb1feed432d205a2f7e38",
+    "lfm2": "482eb96528611199ebb6e268f3b40cb3295acf9853556c99766482d66fcde5d7",
 }
 
 
 @pytest.mark.parametrize("which", sorted(LOWERED))
-def test_older_cells_lowered_steps_are_unchanged(which, tmp_path):
+def test_cells_lowered_steps_are_unchanged(which, tmp_path):
     if which.startswith("joyai"):
         cfg, mix, builder = test_decoder_lm.tiny_cell()
         if which == "joyai_kernels":
             cfg["attn_impl"] = "pallas"
+    elif which == "lfm2":
+        cfg, mix, builder = tiny_cell()
     else:
         _, cfg, mix, builder = getattr(tiny, which)()
     mix["batch_size"] = 8  # divides by the 8 virtual devices
@@ -411,6 +430,8 @@ def test_older_cells_lowered_steps_are_unchanged(which, tmp_path):
         text = jax.jit(trainer._raw_train_step).lower(trainer.state, batch).as_text()
     finally:
         trainer.close()
+    if which == "lfm2":  # forward and backward of the four expert layers
+        assert text.count("stablehlo.case") == 8
     assert hashlib.sha256(text.encode()).hexdigest() == LOWERED[which]
 
 
@@ -445,5 +466,7 @@ def test_train_lm_cli_builds_the_family_from_its_model_type(tmp_path):
     assert row["train_loss"] == row["loss_main"] and "loss_mtp" not in row
     assert row["moe_local_assignment_pct"] < 100.0
     gauges = obs.get_registry().snapshot()["gauges"]
-    assert gauges["moe_bounded_path_pct"] == 100.0  # a quarter of the 8 experts held: one path
+    # 2 of 8 experts held, 256 tokens x top 2 in tiles of 256: a bounded buffer of
+    # 3 tiles of the worst case's 4, which two experts overflow only with 256 rows each
+    assert gauges["moe_bounded_path_pct"] == 100.0
     assert gauges["attention_residuals_kept_pct"] == 0.0  # off a TPU: the blocked XLA path

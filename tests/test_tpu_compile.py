@@ -153,8 +153,9 @@ CASES = {
     # the lfm2_moe cell's grouped-query attention (2 rows x 8192, 32 query heads
     # over 8 key/value heads of 64: the block index maps send a head to its
     # group, dk / dv are summed over the group in the kernel) and its held
-    # experts' products (16,384 tokens x top-4, 8 of 32 experts: the bounded
-    # buffer IS the worst case, 264 tiles; 2048 <-> 1792)
+    # experts' products (16,384 tokens x top-4, 8 of 32 experts, 2048 <-> 1792:
+    # over the bounded buffer, 136 tiles = 34,816 rows, and over the worst
+    # case's 264 = 67,584, the branch a step takes where its routing overflows)
     "attn-gqa-causal-fwd": lambda: _attention(
         2, 8192, 8192, 32, 64, causal_offset=0, blocks=_gqa_blocks(), kv_heads=8),
     "attn-gqa-causal-grad": lambda: _attention(
@@ -163,6 +164,10 @@ CASES = {
         16384, 4, 8, 2048, 1792, grad=True, of_experts=32),
     "gmm-lfm2-experts-down-grad": lambda: _grouped_matmul(
         16384, 4, 8, 1792, 2048, grad=True, of_experts=32),
+    "gmm-lfm2-experts-up-grad-worst-case": lambda: _grouped_matmul(
+        16384, 4, 8, 2048, 1792, grad=True),
+    "gmm-lfm2-experts-down-grad-worst-case": lambda: _grouped_matmul(
+        16384, 4, 8, 1792, 2048, grad=True),
     # the float32 (parity) path: blocks of the bfloat16 size ran out of VMEM
     # on the chip (PR 32)
     "gmm-experts-up-grad-f32": lambda: _grouped_matmul(
