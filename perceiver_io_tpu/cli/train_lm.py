@@ -1,8 +1,10 @@
 """Causal decoder LM pretraining entry point (``models/decoder_lm.py``: one
 skeleton for the DeepSeek-V3 family, latent attention + routed and shared
-experts + multi-token prediction, and for ``lfm2_moe``, gated short
-convolutions and grouped-query attention mixed by ``layer_types`` + routed
-experts + a tied head).
+experts + multi-token prediction; for ``lfm2_moe``, gated short convolutions
+and grouped-query attention mixed by ``layer_types`` + routed experts + a tied
+head; and for ``nemotron_h``, one sublayer a block by
+``hybrid_override_pattern``: Mamba-2 mixers, attention without positions,
+squared-ReLU experts beside a shared expert of its own width).
 
 The model's sizes are the keys of a published ``config.json`` (``--config``),
 each also a flag of its own name that overrides the file; the published
@@ -21,6 +23,8 @@ Usage:
         --experts_held 8 --expert_offset 0 ...
     python -m perceiver_io_tpu.cli.train_lm --synthetic --model_type lfm2_moe \
         --layer_types conv,full_attention,conv ...
+    python -m perceiver_io_tpu.cli.train_lm --synthetic --model_type nemotron_h \
+        --hybrid_override_pattern 'MEMEM*EME' ...
 """
 
 from __future__ import annotations
@@ -37,8 +41,13 @@ from perceiver_io_tpu import obs
 from perceiver_io_tpu.aot import configure_compile_cache
 from perceiver_io_tpu.cli import common
 from perceiver_io_tpu.data.imdb import IMDBDataModule
-from perceiver_io_tpu.models.decoder_lm import LFM2_MOE, DecoderLM, DecoderLMConfig
-from perceiver_io_tpu.ops import moe
+from perceiver_io_tpu.models.decoder_lm import (
+    LFM2_MOE,
+    NEMOTRON_H,
+    DecoderLM,
+    DecoderLMConfig,
+)
+from perceiver_io_tpu.ops import mamba2, moe
 from perceiver_io_tpu.training import TrainState, make_lm_steps
 from perceiver_io_tpu.training.trainer import Trainer
 
@@ -61,13 +70,27 @@ SMALL_LFM2 = dict(
     num_experts_per_tok=2, routed_scaling_factor=1.0, norm_topk_prob=True,
     use_expert_bias=True, conv_bias=False, rope_theta=1000000.0, norm_eps=1e-5,
 )
+# the same for ``--model_type nemotron_h``: the three kinds of block, several
+# heads a group in both mixers, 4 taps with bias, a shared expert of another
+# width than the experts'
+SMALL_NEMOTRON_H = dict(
+    hidden_size=64, moe_intermediate_size=32, moe_shared_expert_intermediate_size=48,
+    hybrid_override_pattern="MEM*E", num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+    mamba_num_heads=8, mamba_head_dim=8, n_groups=2, ssm_state_size=16, conv_kernel=4,
+    chunk_size=16, n_routed_experts=8, n_shared_experts=1, num_experts_per_tok=2,
+    routed_scaling_factor=2.5, norm_topk_prob=True, mlp_hidden_act="relu2",
+    use_conv_bias=True, layer_norm_epsilon=1e-5,
+)
+SMALL_SIZES = {LFM2_MOE: SMALL_LFM2, NEMOTRON_H: SMALL_NEMOTRON_H}
 Flag = collections.namedtuple("Flag", "name type")
 # every size is a flag: the skeleton's fields (the vocabulary is the
 # tokenizer's, ``--vocab_size`` of the data flags), and the published keys
-# that the ``lfm2_moe`` family names otherwise
+# that the ``lfm2_moe`` and ``nemotron_h`` families name otherwise
 MODEL_FIELDS = [f for f in dataclasses.fields(DecoderLMConfig) if f.name != "vocab_size"] + [
     Flag("model_type", "str"), Flag("num_dense_layers", "int"), Flag("num_experts", "int"),
-    Flag("norm_eps", "float"), Flag("use_expert_bias", "bool"), Flag("conv_bias", "bool")]
+    Flag("norm_eps", "float"), Flag("use_expert_bias", "bool"), Flag("conv_bias", "bool"),
+    Flag("hybrid_override_pattern", "str"), Flag("layer_norm_epsilon", "float"),
+    Flag("use_conv_bias", "bool")]
 FLAG_TYPES = {"int": int, "float": float, "Optional[int]": int, "str": str,
               "bool": lambda text: text.lower() in ("1", "true"),
               "Tuple[str, ...]": lambda text: tuple(text.split(","))}
@@ -97,11 +120,12 @@ def model_config(args, vocab_size: int) -> DecoderLMConfig:
         with open(args.config) as f:
             published = json.load(f)
     family = args.model_type or published.get("model_type")
-    small = SMALL_LFM2 if family == LFM2_MOE else SMALL
-    sizes = {**small, **published, "vocab_size": vocab_size}
+    sizes = {**SMALL_SIZES.get(family, SMALL), **published, "vocab_size": vocab_size}
     for field in MODEL_FIELDS:
         if getattr(args, field.name) is not None:
             sizes[field.name] = getattr(args, field.name)
+    if family == NEMOTRON_H:  # the depth is the pattern's, whichever of the two was given
+        sizes["num_hidden_layers"] = len(sizes["hybrid_override_pattern"])
     return DecoderLMConfig.from_dict(sizes)
 
 
@@ -116,12 +140,24 @@ def build_model(args, vocab_size: int) -> DecoderLM:
                   tokens, config.num_experts_per_tok, held, config.n_routed_experts, moe.TILE_ROWS),
               worst_case_rows=moe.TILE_ROWS * moe.worst_case_tiles(
                   tokens * config.num_experts_per_tok, held, moe.TILE_ROWS))
+    scans = "mamba2" in config.mixers
     obs.event("lm.layers", mixers=list(config.mixers),
+              one_sublayer_blocks=config.one_sublayer_blocks,
               dense_layers=config.first_k_dense_replace,
               kv_group=(config.num_attention_heads // config.num_key_value_heads
                         if "full_attention" in config.mixers else 1),
               experts_held=held, experts_published=config.n_routed_experts,
+              shared_expert_width=(config.moe_shared_expert_intermediate_size
+                                   or config.n_shared_experts * config.moe_intermediate_size),
+              ssd_chunk=config.chunk_size if scans else 0,
+              ssd_state=[config.mamba_num_heads, config.mamba_head_dim,
+                         config.ssm_state_size] if scans else [],
               tied_head=config.tie_word_embeddings)
+    # float32 bytes of the states that a Mamba-2 layer's backward pass holds for
+    # one row: one a chunk, not one a token (0 without such a layer)
+    obs.get_registry().gauge("ssd_state_bytes").set(mamba2.state_bytes(
+        args.max_seq_len, config.chunk_size, config.mamba_num_heads, config.mamba_head_dim,
+        config.ssm_state_size) if scans else 0)
     # the model rematerialises every block (``--remat`` is the Perceiver
     # encoders' switch) and the experts' path follows the backend
     return DecoderLM(config, attn_impl=args.attn_impl, dtype=common.DTYPES[args.dtype])

@@ -1,31 +1,49 @@
-"""A token-level causal decoder: ONE skeleton for two public families.
+"""A token-level causal decoder: ONE skeleton for three public families.
 
 The skeleton: an embedding, a Python loop of rematerialised pre-norm blocks
 
     h = x + Mixer_l(RMSNorm(x));   y = h + FFN_l(RMSNorm(h))
 
+or, with ``one_sublayer_blocks``, blocks of ONE sublayer each,
+
+    y = x + Sub_l(RMSNorm(x))      Sub_l a mixer or the expert layer
+
 a final RMSNorm, an output head (a matrix of its own, or the embedding's
 transpose: ``tie_word_embeddings``), the loss, and the expert layers'
 statistics as the step's metrics. What a layer IS is a per-layer fact of the
-configuration (:class:`DecoderLMConfig`): ``mixers[l]`` names its token mixer,
-and its FFN is a dense SwiGLU in the first ``first_k_dense_replace`` layers
-and the routed expert layer (``ops/moe.py``) after. The mixers:
+configuration (:class:`DecoderLMConfig`, ``blocks``): ``layer_types[l]`` names
+its token mixer, and its FFN is a dense SwiGLU in the first
+``first_k_dense_replace`` layers and the routed expert layer (``ops/moe.py``)
+after; in a stack of one-sublayer blocks ``layer_types[l]`` is a mixer or
+``'moe'``, the expert layer alone. The mixers:
 
 - ``'mla'``: multi-head latent attention (``ops/latent_attention.py``);
-- ``'full_attention'``: grouped-query attention with per-head RMSNorm of
-  queries and keys and half-split rotary (``ops/grouped_query_attention.py``);
-- ``'conv'``: the gated short convolution (``ops/short_conv.py``).
+- ``'full_attention'``: grouped-query attention, with per-head RMSNorm of
+  queries and keys (``qk_norm``) and half-split rotary (``rotary``) or
+  without either (``ops/grouped_query_attention.py``);
+- ``'conv'``: the gated short convolution (``ops/short_conv.py``);
+- ``'mamba2'``: the Mamba-2 state-space mixer, its recurrence a chunked scan
+  (``ops/mamba2.py``).
 
 Each family's published ``config.json`` maps onto the skeleton in a
 ``from_dict`` of its own, chosen by the published ``model_type``:
 
 - the DeepSeek-V3 family (``joyai_llm_flash`` is one; any ``model_type`` but
-  the one below): ``'mla'`` in every layer, a shared expert beside the routed
+  the two below): ``'mla'`` in every layer, a shared expert beside the routed
   ones, an untied head, and one multi-token-prediction module;
 - ``lfm2_moe`` (LFM2-8B-A1B): ``layer_types`` mixes ``'conv'`` and
   ``'full_attention'``; ``num_dense_layers`` leading dense layers; 32 experts,
   top 4 of ``sigmoid + bias``, gates normalised with 1e-6, NO shared expert;
-  the head tied to the embedding; no MTP module.
+  the head tied to the embedding; no MTP module;
+- ``nemotron_h`` (the Nemotron-H stack, as Nemotron-Labs-TwoTower-30B-A3B's
+  ``config.json`` configures it): one sublayer a block by
+  ``hybrid_override_pattern`` (``M`` ``'mamba2'``, ``*`` ``'full_attention'``
+  without norm or positions, ``E`` ``'moe'``); non-gated squared-ReLU experts
+  (``mlp_hidden_act`` ``relu2``) beside one shared expert of a width of its
+  own; an untied head; no MTP module. It is the language model that those keys
+  define, trained by next-token cross-entropy: the second (denoiser) tower
+  that the model's card describes has no key in that file and nothing here
+  stands in for it.
 
 Nothing here knows a width. The expert layers are told which experts this
 chip holds (``experts_held``, ``expert_offset``; default all): with a share,
@@ -58,10 +76,10 @@ head-and-loss computations are rematerialised whole, so that no (tokens,
 vocabulary) float32 logits wait for the backward pass.
 
 Scopes a device trace is cut by: ``embed``, ``mla_attention``,
-``gqa_attention``, ``short_conv``, ``moe/*``, ``mtp`` (everything the module
-runs, its attention and experts included) and ``head_loss``; no operation of
-the main stack lies outside a layer's scope but norms, residual adds and the
-dense SwiGLU.
+``gqa_attention``, ``short_conv``, ``mamba2`` (with ``mamba2/ssd_scan`` around
+the scan alone), ``moe/*``, ``mtp`` (everything the module runs, its attention
+and experts included) and ``head_loss``; no operation of the main stack lies
+outside a layer's scope but norms, residual adds and the dense SwiGLU.
 """
 
 from __future__ import annotations
@@ -81,6 +99,7 @@ from perceiver_io_tpu.ops.latent_attention import (
     RMSNorm,
     resolve_causal_impl,
 )
+from perceiver_io_tpu.ops.mamba2 import Mamba2Mixer
 from perceiver_io_tpu.ops.masking import IGNORE_LABEL
 from perceiver_io_tpu.ops.moe import Kernel, MoELayer, SwiGLU
 from perceiver_io_tpu.ops.short_conv import GatedShortConv
@@ -88,8 +107,13 @@ from perceiver_io_tpu.training.losses import cross_entropy_with_ignore
 
 Array = jax.Array
 
-MIXERS = ("mla", "full_attention", "conv")
-LFM2_MOE = "lfm2_moe"  # the published ``model_type`` of the second family
+MIXERS = ("mla", "full_attention", "conv", "mamba2")
+EXPERTS = "moe"  # the routed expert layer: an FFN's kind, and a one-sublayer block's
+LFM2_MOE = "lfm2_moe"      # the published ``model_type`` of the second family
+NEMOTRON_H = "nemotron_h"  # ... and of the third
+# a character of ``hybrid_override_pattern`` -> the block's one sublayer (the
+# family's ``-``, a dense MLP block, is in no configuration here)
+_PATTERN = {"M": "mamba2", "*": "full_attention", "E": EXPERTS}
 
 # what the published keys must say for this module to be the model they
 # describe, by family
@@ -99,12 +123,23 @@ _REQUIRED = {
     "hidden_act": "silu", "moe_layer_freq": 1, "attention_bias": False,
 }
 _REQUIRED_LFM2 = {"conv_bias": False, "use_expert_bias": True, "tie_word_embeddings": True}
+_REQUIRED_NEMOTRON_H = {
+    "use_conv_bias": True, "use_bias": False, "mamba_proj_bias": False, "mlp_bias": False,
+    "attention_bias": False, "mlp_hidden_act": "relu2", "mamba_hidden_act": "silu",
+    "n_group": 1, "topk_group": 1, "tie_word_embeddings": False, "sliding_window": None,
+}
 # the LFM2 family's names for what the skeleton already has a field for
 _LFM2_NAMES = {"num_dense_layers": "first_k_dense_replace", "num_experts": "n_routed_experts",
                "norm_eps": "rms_norm_eps"}
 # fields no DeepSeek-V3 ``config.json`` sets, whatever keys of those names it carries
 _LFM2_ONLY = ("layer_types", "num_key_value_heads", "head_dim", "conv_L_cache",
                   "gate_eps", "expert_bias_buffer", "tie_word_embeddings")
+# ... nor any LFM2 one: the Nemotron-H family's published keys that are fields
+# of their own name, and what its ``from_dict`` sets
+_NEMOTRON_H_KEYS = ("mamba_num_heads", "mamba_head_dim", "n_groups", "ssm_state_size",
+                    "conv_kernel", "chunk_size", "time_step_min", "time_step_max",
+                    "time_step_floor", "mlp_hidden_act", "moe_shared_expert_intermediate_size")
+_NEMOTRON_H_ONLY = _NEMOTRON_H_KEYS + ("one_sublayer_blocks", "qk_norm", "rotary")
 
 
 def _require(config: Dict[str, Any], required: Dict[str, Any]) -> None:
@@ -116,8 +151,8 @@ def _require(config: Dict[str, Any], required: Dict[str, Any]) -> None:
 @dataclasses.dataclass(frozen=True, kw_only=True)
 class DecoderLMConfig:
     """The skeleton's sizes, under the DeepSeek-V3 family's published names
-    where it has one for the thing, and the LFM2 family's for what only that
-    family has (``from_dict`` translates the rest)."""
+    where it has one for the thing, and the LFM2 and Nemotron-H families' for
+    what only they have (``from_dict`` translates the rest)."""
 
     vocab_size: int
     hidden_size: int
@@ -135,6 +170,8 @@ class DecoderLMConfig:
     expert_bias_buffer: bool = False  # the selection bias is the LFM2 family's buffer (ops/moe.py)
     # the token mixer of every layer, of ``MIXERS``; empty: 'mla' in every layer
     layer_types: Tuple[str, ...] = ()
+    # a block is ONE sublayer: ``layer_types[l]`` is a mixer or ``EXPERTS``
+    one_sublayer_blocks: bool = False
     # 'mla' layers
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
@@ -144,6 +181,24 @@ class DecoderLMConfig:
     # 'full_attention' layers
     num_key_value_heads: int = 0
     head_dim: int = 0
+    qk_norm: bool = True  # per-head RMSNorm of queries and keys
+    rotary: bool = True   # rotary position embedding (without: no position encoding at all)
+    # 'mamba2' layers: heads, a head's channels, groups of B / C, a head's state
+    # is ``mamba_head_dim x ssm_state_size``, taps, the scan's chunk, and the
+    # range of the time step's initialisation
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    n_groups: int = 0
+    ssm_state_size: int = 0
+    conv_kernel: int = 0
+    chunk_size: int = 0
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    # the experts' form: 'silu' a SwiGLU, 'relu2' the non-gated squared ReLU
+    mlp_hidden_act: str = "silu"
+    # the shared expert's width; 0: ``n_shared_experts * moe_intermediate_size``
+    moe_shared_expert_intermediate_size: int = 0
     # 'conv' layers: the taps of the short convolution
     conv_L_cache: int = 0
     tie_word_embeddings: bool = False
@@ -162,8 +217,10 @@ class DecoderLMConfig:
         module computes)."""
         if config.get("model_type") == LFM2_MOE:
             return cls._from_lfm2_moe(config)
+        if config.get("model_type") == NEMOTRON_H:
+            return cls._from_nemotron_h(config)
         _require(config, _REQUIRED)
-        names = {f.name for f in dataclasses.fields(cls)} - set(_LFM2_ONLY)
+        names = {f.name for f in dataclasses.fields(cls)} - set(_LFM2_ONLY + _NEMOTRON_H_ONLY)
         return cls(**{k: v for k, v in config.items() if k in names})
 
     @classmethod
@@ -184,31 +241,78 @@ class DecoderLMConfig:
                    head_dim=config["hidden_size"] // config["num_attention_heads"],
                    gate_eps=1e-6, expert_bias_buffer=True, tie_word_embeddings=True, **sizes)
 
+    @classmethod
+    def _from_nemotron_h(cls, config: Dict[str, Any]) -> "DecoderLMConfig":
+        """The Nemotron-H stack's keys: one sublayer a block by
+        ``hybrid_override_pattern``; attention heads of the published
+        ``head_dim`` with neither norm nor position encoding (the family's
+        public implementation applies none: its ``rope_theta`` and
+        ``partial_rotary_factor`` are read by nothing); the Mamba-2 sizes
+        under their published names (``expand`` is read by nothing: the
+        mixer's inner width is ``mamba_num_heads x mamba_head_dim``);
+        ``relu2`` experts and a shared expert of
+        ``moe_shared_expert_intermediate_size``; 1e-20 in the gates'
+        normalisation; the selection bias a buffer; an untied head."""
+        _require(config, _REQUIRED_NEMOTRON_H)
+        if tuple(config.get("time_step_limit") or (0, None)) not in ((0, None), (0, float("inf"))):
+            raise ValueError(f"time_step_limit={config['time_step_limit']!r} is not supported "
+                             "(only (0, inf), which clamps nothing)")
+        pattern = config["hybrid_override_pattern"]
+        if set(pattern) - set(_PATTERN):
+            raise ValueError(f"hybrid_override_pattern {pattern!r}: only blocks of "
+                             f"{sorted(_PATTERN)} are supported")
+        names = {"vocab_size", "hidden_size", "moe_intermediate_size", "num_attention_heads",
+                 "num_key_value_heads", "head_dim", "n_routed_experts", "num_experts_per_tok",
+                 "n_shared_experts", "routed_scaling_factor", "norm_topk_prob", "experts_held",
+                 "expert_offset", *_NEMOTRON_H_KEYS}
+        sizes = {k: v for k, v in config.items() if k in names}
+        return cls(layer_types=tuple(_PATTERN[kind] for kind in pattern),
+                   num_hidden_layers=config.get("num_hidden_layers", len(pattern)),
+                   intermediate_size=config.get("intermediate_size", 0), first_k_dense_replace=0,
+                   rms_norm_eps=config["layer_norm_epsilon"], one_sublayer_blocks=True,
+                   qk_norm=False, rotary=False, expert_bias_buffer=True, **sizes)
+
     def __post_init__(self):
         if self.num_nextn_predict_layers not in (0, 1):
             raise ValueError("num_nextn_predict_layers must be 0 or 1")
-        if len(self.mixers) != self.num_hidden_layers or set(self.mixers) - set(MIXERS):
+        kinds = MIXERS + ((EXPERTS,) if self.one_sublayer_blocks else ())
+        if len(self.mixers) != self.num_hidden_layers or set(self.mixers) - set(kinds):
             raise ValueError(f"layer_types {self.layer_types} for {self.num_hidden_layers} "
-                             f"layers of {MIXERS}")
+                             f"layers of {kinds}")
         if "full_attention" in self.mixers and self.num_attention_heads % max(
                 self.num_key_value_heads, 1):
             raise ValueError(f"{self.num_attention_heads} query heads over "
                              f"{self.num_key_value_heads} key/value heads")
+        if "mamba2" in self.mixers and (self.n_groups < 1 or self.mamba_num_heads % self.n_groups):
+            raise ValueError(f"{self.mamba_num_heads} Mamba heads over {self.n_groups} groups")
+        if self.one_sublayer_blocks and self.num_nextn_predict_layers:
+            raise ValueError("the MTP module is a two-sublayer block")
 
     @property
     def mixers(self) -> Tuple[str, ...]:
-        """The token mixer of every layer of the main stack."""
+        """``layer_types`` of every layer of the main stack: its token mixer
+        (in a stack of one-sublayer blocks, a mixer or ``EXPERTS``)."""
         return self.layer_types or ("mla",) * self.num_hidden_layers
+
+    @property
+    def blocks(self) -> Tuple[Tuple[str, str], ...]:
+        """``(mixer, ffn)`` of every block of the main stack: a mixer of
+        ``MIXERS`` and an FFN, ``'dense'`` or ``EXPERTS``; in a stack of
+        one-sublayer blocks one of the two is ``''``."""
+        if self.one_sublayer_blocks:
+            return tuple(("", EXPERTS) if kind == EXPERTS else (kind, "") for kind in self.mixers)
+        return tuple((mixer, EXPERTS if i >= self.first_k_dense_replace else "dense")
+                     for i, mixer in enumerate(self.mixers))
 
     def value_depth(self, mixer: str) -> int:
         """Channels a head of ``mixer``'s causal kernel writes (0: no kernel)."""
-        return {"mla": self.v_head_dim, "full_attention": self.head_dim, "conv": 0}[mixer]
+        return {"mla": self.v_head_dim, "full_attention": self.head_dim}.get(mixer, 0)
 
 
 class DecoderBlock(nn.Module):
     config: DecoderLMConfig
-    mixer: str    # of ``MIXERS``
-    routed: bool  # the FFN is the expert layer, not the dense SwiGLU
+    mixer: str  # of ``MIXERS``, or '' (an FFN alone)
+    ffn: str    # 'dense' (the SwiGLU), ``EXPERTS`` (the expert layer), or '' (a mixer alone)
     attn_impl: str = "auto"
     expert_impl: str = "auto"
     dtype: Any = jnp.float32
@@ -217,10 +321,18 @@ class DecoderBlock(nn.Module):
         c = self.config
         if self.mixer == "conv":
             return GatedShortConv(taps=c.conv_L_cache, dtype=self.dtype, name="conv")
+        if self.mixer == "mamba2":
+            return Mamba2Mixer(
+                num_heads=c.mamba_num_heads, head_dim=c.mamba_head_dim, n_groups=c.n_groups,
+                state_size=c.ssm_state_size, conv_kernel=c.conv_kernel, chunk_size=c.chunk_size,
+                eps=c.rms_norm_eps, time_step_min=c.time_step_min,
+                time_step_max=c.time_step_max, time_step_floor=c.time_step_floor,
+                dtype=self.dtype, name="mamba")
         if self.mixer == "full_attention":
             return GroupedQueryAttention(
                 num_heads=c.num_attention_heads, num_kv_heads=c.num_key_value_heads,
                 head_dim=c.head_dim, rope_theta=c.rope_theta, rms_norm_eps=c.rms_norm_eps,
+                qk_norm=c.qk_norm, rotary=c.rotary,
                 attn_impl=self.attn_impl, dtype=self.dtype, name="attn")
         return MultiHeadLatentAttention(
             num_heads=c.num_attention_heads, q_lora_rank=c.q_lora_rank,
@@ -229,6 +341,20 @@ class DecoderBlock(nn.Module):
             rope_theta=c.rope_theta, rms_norm_eps=c.rms_norm_eps,
             attn_impl=self.attn_impl, dtype=self.dtype, name="attn")
 
+    def _ffn(self, x: Array) -> Tuple[Array, dict]:
+        c = self.config
+        if self.ffn == "dense":
+            return SwiGLU(c.intermediate_size, self.dtype, name="mlp")(x), {}
+        return MoELayer(
+            num_experts=c.n_routed_experts, top_k=c.num_experts_per_tok,
+            width=c.moe_intermediate_size, num_shared=c.n_shared_experts,
+            shared_width=c.moe_shared_expert_intermediate_size,
+            expert_form="swiglu" if c.mlp_hidden_act == "silu" else c.mlp_hidden_act,
+            routed_scaling_factor=c.routed_scaling_factor, norm_topk_prob=c.norm_topk_prob,
+            gate_eps=c.gate_eps, expert_bias_buffer=c.expert_bias_buffer,
+            experts_held=c.experts_held, expert_offset=c.expert_offset,
+            expert_impl=self.expert_impl, dtype=self.dtype, name="moe")(x)
+
     @nn.compact
     def __call__(self, x: Array) -> Tuple[Array, dict]:
         c = self.config
@@ -236,16 +362,13 @@ class DecoderBlock(nn.Module):
         def norm(name):
             return RMSNorm(c.rms_norm_eps, self.dtype, name=name)
 
+        if not self.ffn:  # ONE sublayer under one norm
+            return x + self._mixer()(norm("norm")(x)), {}
+        if not self.mixer:
+            y, stats = self._ffn(norm("norm")(x))
+            return x + y, stats
         h = x + self._mixer()(norm("attn_norm")(x))
-        if not self.routed:
-            return h + SwiGLU(c.intermediate_size, self.dtype, name="mlp")(norm("ffn_norm")(h)), {}
-        y, stats = MoELayer(
-            num_experts=c.n_routed_experts, top_k=c.num_experts_per_tok,
-            width=c.moe_intermediate_size, num_shared=c.n_shared_experts,
-            routed_scaling_factor=c.routed_scaling_factor, norm_topk_prob=c.norm_topk_prob,
-            gate_eps=c.gate_eps, expert_bias_buffer=c.expert_bias_buffer,
-            experts_held=c.experts_held, expert_offset=c.expert_offset,
-            expert_impl=self.expert_impl, dtype=self.dtype, name="moe")(norm("ffn_norm")(h))
+        y, stats = self._ffn(norm("ffn_norm")(h))
         return h + y, stats
 
 
@@ -303,15 +426,14 @@ class DecoderLM(nn.Module):
         kept = policy is not None
         block = nn.remat(DecoderBlock, policy=policy)
 
-        def make(mixer, routed, name):
-            return block(c, mixer, routed, self.attn_impl, self.expert_impl, self.dtype,
-                         name=name)
+        def make(mixer, ffn, name):
+            return block(c, mixer, ffn, self.attn_impl, self.expert_impl, self.dtype, name=name)
 
         with jax.named_scope("embed"):
             x = self.embed(token_ids)
         stats = []
-        for i, mixer in enumerate(c.mixers):
-            x, s = make(mixer, i >= c.first_k_dense_replace, f"layer_{i}")(x)
+        for i, (mixer, ffn) in enumerate(c.blocks):
+            x, s = make(mixer, ffn, f"layer_{i}")(x)
             stats.append(s)
         h = self.final_norm(x)
         if not c.num_nextn_predict_layers:
@@ -320,7 +442,7 @@ class DecoderLM(nn.Module):
             with jax.named_scope("embed"):
                 following = self.mtp_enorm(self.embed(jnp.roll(token_ids, -1, axis=1)))
             x = self.mtp_eh_proj(jnp.concatenate([following, self.mtp_hnorm(h)], axis=-1))
-            x, s = make(c.mixers[-1], True, "mtp_block")(x)
+            x, s = make(c.mixers[-1], EXPERTS, "mtp_block")(x)
             stats.append(s)
             return h, self.mtp_final_norm(x), stats, kept
 

@@ -32,6 +32,12 @@ worst-case buffer it is gathers in both directions (``_gather_tokens`` /
 ``_gather_buffer``: the transpose of a gather by a one-to-one map is the
 gather by its inverse, which XLA cannot know and a ``custom_vjp`` can say).
 
+An expert's form is a field (``expert_form``): ``'swiglu'``, ``down(silu(gate(x))
+* up(x))``, three grouped matmuls forward, or ``'relu2'``, the Nemotron-H
+family's non-gated ``down(relu(up(x))^2)``, two. The shared expert has the
+experts' form and a width of its own (``shared_width``; by default
+``num_shared`` times the experts').
+
 Scopes (``jax.named_scope``) the device trace is cut by: ``moe/router``,
 ``moe/dispatch``, ``moe/experts``, ``moe/combine``, ``moe/shared_expert``.
 """
@@ -252,6 +258,33 @@ class SwiGLU(nn.Module):
         return dense("down", x.shape[-1])(hidden)
 
 
+def _relu2(x: Array, up: Array, down: Array, matmul) -> Array:
+    return matmul(jnp.square(jax.nn.relu(matmul(x, up))), down)
+
+
+class SquaredReLU(nn.Module):
+    """``down(relu(up(x))^2)``, no gate and no biases."""
+
+    width: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: Array) -> Array:
+        def dense(name, features):
+            return nn.Dense(features, use_bias=False, dtype=self.dtype,
+                            kernel_init=torch_linear_kernel_init, name=name)
+
+        return dense("down", x.shape[-1])(jnp.square(jax.nn.relu(dense("up", self.width)(x))))
+
+
+# form -> (the held experts' stacked kernels, in the order the function takes
+# them; the function; the module of the same form, for the shared expert)
+EXPERT_FORMS = {
+    "swiglu": (("gate", "up", "down"), _swiglu, SwiGLU),
+    "relu2": (("up", "down"), _relu2, SquaredReLU),
+}
+
+
 class Kernel(nn.Module):
     """One ``kernel`` leaf of a given shape under the module's name."""
 
@@ -309,9 +342,10 @@ def _either(fits: Array, common, fallback, x, gates, kernels, *integers):
 
 
 class MoELayer(nn.Module):
-    """Router over ``num_experts``, the held experts' SwiGLUs of ``width``,
-    and ``num_shared`` always-on shared experts (one SwiGLU of ``num_shared *
-    width``; none with 0). The held experts' rows go through a buffer of ``capacity_tiles``
+    """Router over ``num_experts``, the held experts' FFNs of ``width`` and
+    of ``expert_form`` (``EXPERT_FORMS``), and ``num_shared`` always-on shared
+    experts (one FFN of the same form and of ``shared_width``, by default
+    ``num_shared * width``; none with 0). The held experts' rows go through a buffer of ``capacity_tiles``
     tiles where the step's routing fits it and through the worst-case buffer
     where it does not (the module docstring). Returns ``(y, stats)``;
     ``stats`` are float32 scalars: ``load_max_over_mean`` (assignments of the
@@ -326,6 +360,8 @@ class MoELayer(nn.Module):
     top_k: int
     width: int
     num_shared: int = 1
+    shared_width: int = 0  # 0: ``num_shared * width``
+    expert_form: str = "swiglu"  # of ``EXPERT_FORMS``
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
     gate_eps: float = 1e-20  # added to the selected scores' sum (1e-6 in the LFM2 family)
@@ -366,9 +402,9 @@ class MoELayer(nn.Module):
                                      local_expert, held)
             plan = plan_dispatch(local_expert, held, tile_rows)
 
-        kernels = tuple(Kernel((held, *shape_), name=f"experts_{name}")()
-                        for name, shape_ in (("gate", (d, self.width)), ("up", (d, self.width)),
-                                             ("down", (self.width, d))))
+        names, ffn, shared_ffn = EXPERT_FORMS[self.expert_form]
+        kernels = tuple(Kernel((held, *((self.width, d) if name == "down" else (d, self.width))),
+                               name=f"experts_{name}")() for name in names)
         impl = self.expert_impl
         if impl == "auto":
             impl = "pallas" if jax.default_backend() == "tpu" else "xla"
@@ -376,7 +412,7 @@ class MoELayer(nn.Module):
 
         def experts(rows, kernels, tile_group):
             with jax.named_scope("moe/experts"):
-                return _swiglu(rows, *kernels, lambda a, w: product(
+                return ffn(rows, *kernels, lambda a, w: product(
                     a, w.astype(self.dtype), tile_group, tile_rows))
 
         def over_worst_case(x, gates, kernels, plan, local_expert):
@@ -420,8 +456,8 @@ class MoELayer(nn.Module):
 
         if self.num_shared:
             with jax.named_scope("moe/shared_expert"):
-                y = y + SwiGLU(self.num_shared * self.width, dtype=self.dtype,
-                               name="shared_expert")(x)
+                y = y + shared_ffn(self.shared_width or self.num_shared * self.width,
+                                   dtype=self.dtype, name="shared_expert")(x)
 
         sizes = plan.sizes.astype(jnp.float32)
         assigned = jnp.sum(sizes)

@@ -44,14 +44,20 @@ COLUMN_BLOCK = 512  # output columns per grid step of 2-byte operands, where the
 def _block(dim: int, target: int, interpret: bool, itemsize: int = 2) -> int:
     """Largest lane-aligned divisor of ``dim`` up to ``target`` (halved for
     4-byte operands: float32 blocks of the 2-byte size do not fit VMEM at
-    2048 x 768); the whole dimension where there is none (always legal)."""
+    2048 x 768). A dimension with no such divisor (1856 = 14.5 x 128) gets the
+    lane-aligned target itself and a last block that is part outside the
+    array: every blocked dimension here is one of the OUTPUT's (the contracted
+    one is never blocked), what a block reads outside its operand only reaches
+    what it would write outside the output, and that is not written. (One
+    block of the whole 1856 is legal too and does not fit VMEM beside a
+    2688-deep operand.)"""
     target = target * 2 // max(itemsize, 2)
     if interpret or dim <= target:
         return dim
     for cand in range(target - target % _LANES, 0, -_LANES):
         if dim % cand == 0:
             return cand
-    return dim
+    return target - target % _LANES
 
 
 def _gmm_kernel(group_ref, fetch_ref, lhs_ref, rhs_ref, out_ref, *, num_groups: int):
@@ -108,7 +114,7 @@ def _gmm(lhs: Array, rhs: Array, tile_group: Array, tile_rows: int, interpret: b
             # tiles innermost: a column block of one group's weights stays
             # put over that group's tiles, and over all the tiles past the last
             # group nothing is fetched at all
-            grid=(n // tn, tiles),
+            grid=(pl.cdiv(n, tn), tiles),
             in_specs=[
                 pl.BlockSpec((tile_rows, k), lambda j, t, grp, fetch: (fetch[t], 0)),
                 pl.BlockSpec((1, k, tn), lambda j, t, grp, fetch: (
@@ -137,7 +143,7 @@ def _tgmm(lhs: Array, grad: Array, tile_group: Array, num_groups: int,
         functools.partial(_tgmm_kernel, num_groups=num_groups),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(k // tk, n // tn, tiles),
+            grid=(pl.cdiv(k, tk), pl.cdiv(n, tn), tiles),
             in_specs=[
                 pl.BlockSpec((tile_rows, tk), lambda i, j, t, grp, fetch: (fetch[t], i)),
                 pl.BlockSpec((tile_rows, tn), lambda i, j, t, grp, fetch: (fetch[t], j)),

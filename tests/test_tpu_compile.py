@@ -82,6 +82,12 @@ def _gqa_blocks():
     return latent_attention.pallas_blocks(64)
 
 
+def _gqa16_blocks():
+    from perceiver_io_tpu.ops import latent_attention
+
+    return latent_attention.pallas_blocks(128)
+
+
 def _grouped_matmul(tokens, top_k, held, k, n, grad=False, dtype=jnp.bfloat16, of_experts=None):
     """Over the worst-case buffer, or over the bounded one of a layer that
     holds ``held`` ``of_experts``."""
@@ -168,6 +174,24 @@ CASES = {
         16384, 4, 8, 2048, 1792, grad=True),
     "gmm-lfm2-experts-down-grad-worst-case": lambda: _grouped_matmul(
         16384, 4, 8, 1792, 2048, grad=True),
+    # the nemotron_h cell's attention block (1 row x 8192, 32 query heads over 2
+    # key/value heads of 128: groups of 16) and its held experts' two products
+    # (8,192 tokens x top-6, 8 of 128 experts, 2688 <-> 1856: 1856 is 14.5 x 128,
+    # so the kernels block it by 512 with a last block part outside the array;
+    # one block of the whole width ran out of VMEM in the sandbox's compile, PR
+    # 38; over the bounded buffer, 56 tiles, and over the worst case's 200)
+    "attn-gqa16-causal-fwd": lambda: _attention(
+        1, 8192, 8192, 32, 128, causal_offset=0, blocks=_gqa16_blocks(), kv_heads=2),
+    "attn-gqa16-causal-grad": lambda: _attention(
+        1, 8192, 8192, 32, 128, causal_offset=0, blocks=_gqa16_blocks(), kv_heads=2, grad=True),
+    "gmm-nemotron-experts-up-grad": lambda: _grouped_matmul(
+        8192, 6, 8, 2688, 1856, grad=True, of_experts=128),
+    "gmm-nemotron-experts-down-grad": lambda: _grouped_matmul(
+        8192, 6, 8, 1856, 2688, grad=True, of_experts=128),
+    "gmm-nemotron-experts-up-grad-worst-case": lambda: _grouped_matmul(
+        8192, 6, 8, 2688, 1856, grad=True),
+    "gmm-nemotron-experts-down-grad-worst-case": lambda: _grouped_matmul(
+        8192, 6, 8, 1856, 2688, grad=True),
     # the float32 (parity) path: blocks of the bfloat16 size ran out of VMEM
     # on the chip (PR 32)
     "gmm-experts-up-grad-f32": lambda: _grouped_matmul(
@@ -192,6 +216,27 @@ def test_kernel_compiles_for_v5e(name, one_chip):
             for shape, dtype in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_chunked_scan_compiles_for_v5e_inside_its_memory(one_chip):
+    """The nemotron_h cell's state-space scan (``ops/mamba2.ssd_scan``: plain
+    XLA einsums, no kernel) at its real size, 1 row x 8192 tokens, 64 heads of
+    64 over 8 groups of state 128 in chunks of 128, forward and backward under
+    its checkpoint: the temporaries are the chunked form's (the decay matrix,
+    the chunks' states), nowhere near one state a token (17 GB)."""
+    from perceiver_io_tpu.ops.mamba2 import ssd_scan
+
+    def loss(x, delta, a, b, c, d):
+        return jnp.sum(jax.checkpoint(ssd_scan, static_argnums=(6,))(
+            x, delta, a, b, c, d, 128).astype(jnp.float32) ** 2)
+
+    shapes = [((1, 8192, 64, 64), jnp.bfloat16), ((1, 8192, 64), jnp.float32),
+              ((64,), jnp.float32), ((1, 8192, 8, 128), jnp.bfloat16),
+              ((1, 8192, 8, 128), jnp.bfloat16), ((64,), jnp.float32)]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip) for shape, dtype in shapes]
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(*args).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
 
 
 def test_kept_attention_residuals_are_compact_on_v5e(one_chip):
