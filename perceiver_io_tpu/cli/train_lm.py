@@ -141,6 +141,10 @@ def build_model(args, vocab_size: int) -> DecoderLM:
               worst_case_rows=moe.TILE_ROWS * moe.worst_case_tiles(
                   tokens * config.num_experts_per_tok, held, moe.TILE_ROWS))
     scans = "mamba2" in config.mixers
+    # the mixer's own question: the kernel pair or the einsums
+    scan_kernel = scans and mamba2.scan_impl(
+        config.mamba_num_heads, config.mamba_head_dim, config.n_groups,
+        config.ssm_state_size, config.chunk_size, args.max_seq_len) == "pallas"
     obs.event("lm.layers", mixers=list(config.mixers),
               one_sublayer_blocks=config.one_sublayer_blocks,
               dense_layers=config.first_k_dense_replace,
@@ -152,12 +156,15 @@ def build_model(args, vocab_size: int) -> DecoderLM:
               ssd_chunk=config.chunk_size if scans else 0,
               ssd_state=[config.mamba_num_heads, config.mamba_head_dim,
                          config.ssm_state_size] if scans else [],
+              ssd_scan_kernel=scan_kernel,
               tied_head=config.tie_word_embeddings)
     # float32 bytes of the states that a Mamba-2 layer's backward pass holds for
     # one row: one a chunk, not one a token (0 without such a layer)
     obs.get_registry().gauge("ssd_state_bytes").set(mamba2.state_bytes(
         args.max_seq_len, config.chunk_size, config.mamba_num_heads, config.mamba_head_dim,
         config.ssm_state_size) if scans else 0)
+    if scans:  # a constant of the traced program: 100 the kernel pair, 0 the einsums
+        obs.get_registry().gauge("ssd_scan_kernel_pct").set(100.0 if scan_kernel else 0.0)
     # the model rematerialises every block (``--remat`` is the Perceiver
     # encoders' switch) and the experts' path follows the backend
     return DecoderLM(config, attn_impl=args.attn_impl, dtype=common.DTYPES[args.dtype])

@@ -23,7 +23,8 @@ after; in a stack of one-sublayer blocks ``layer_types[l]`` is a mixer or
   without either (``ops/grouped_query_attention.py``);
 - ``'conv'``: the gated short convolution (``ops/short_conv.py``);
 - ``'mamba2'``: the Mamba-2 state-space mixer, its recurrence a chunked scan
-  (``ops/mamba2.py``).
+  (``ops/mamba2.py``: a pair of Pallas kernels on a TPU, ``ops/pallas_ssd.py``,
+  XLA einsums elsewhere; the backend decides, no flag).
 
 Each family's published ``config.json`` maps onto the skeleton in a
 ``from_dict`` of its own, chosen by the published ``model_type``:
@@ -77,7 +78,7 @@ vocabulary) float32 logits wait for the backward pass.
 
 Scopes a device trace is cut by: ``embed``, ``mla_attention``,
 ``gqa_attention``, ``short_conv``, ``mamba2`` (with ``mamba2/ssd_scan`` around
-the scan alone), ``moe/*``, ``mtp`` (everything the module runs, its attention
+the scan alone, its backward kernel included), ``moe/*``, ``mtp`` (everything the module runs, its attention
 and experts included) and ``head_loss``; no operation of the main stack lies
 outside a layer's scope but norms, residual adds and the dense SwiGLU.
 """

@@ -35,17 +35,29 @@ between chunks, which stays float32 (``Precision.HIGHEST``) like the state
 itself. A row whose length is no multiple of the chunk is padded with tokens
 of ``Delta = 0``, which neither decay the state nor add to it.
 
-Memory. The scan sits under a ``jax.checkpoint`` of its own: what waits for
-its backward pass is its inputs (``x``, ``B``, ``C``, ``Delta``: ``T (H P + 2
-G N + H)`` numbers), and the backward pass recomputes the chunked form, whose
-largest arrays are ``L`` (``T x chunk x H``) and the chunks' states (``T /
-chunk`` states of ``H P N`` float32: :func:`state_bytes`; every token's state
-would be ``chunk`` times that: 17 GB a row of 8,192 at the published sizes).
+Two forms of the scan, picked by what the code observes (:func:`scan_impl`: the
+backend and the sizes), never by a flag:
 
-Plain XLA einsums, no Pallas kernel: the trace of the cell that runs it has
-the scan's share of the step (PERF.md section 5). Everything here sits under
-the ``mamba2`` scope of a device trace, the scan alone (from the split of
-``xBC`` and ``Delta`` to ``y`` before the gate) under ``mamba2/ssd_scan``.
+- on a TPU the pair of Pallas kernels of ``ops/pallas_ssd.py`` under one
+  ``jax.custom_vjp``: a chunk's ``L``, ``C B^T``, ``Delta x`` and state stay
+  in VMEM, forward and backward (only ``cs``, a number a token and head, is
+  made outside), and the carry between chunks is the float32 recurrence
+  itself in a scratch. What waits for the backward pass is
+  the scan's inputs and the states ENTERING each chunk (``T / chunk`` states of
+  ``H P N`` float32: :func:`state_bytes`, 134 MB a row of 8,192 at the
+  published sizes; every token's state would be ``chunk`` times that, 17 GB),
+  alive between a block's recomputation and its backward pass, a layer at a
+  time;
+- elsewhere :func:`ssd_scan`, plain XLA einsums under a ``jax.checkpoint`` of
+  their own: what waits is the inputs (``x``, ``B``, ``C``, ``Delta``: ``T (H P
+  + 2 G N + H)`` numbers), and the backward pass recomputes the chunked form,
+  whose largest arrays are ``L`` (``T x chunk x H``) and the chunks' states. It
+  is the CPU's path and the kernels' oracle.
+
+Everything here sits under the ``mamba2`` scope of a device trace, the scan
+alone (from the split of ``xBC`` and ``Delta`` to ``y`` before the gate,
+backward included) under ``mamba2/ssd_scan``: PERF.md section 5 has its share
+of the step in the cell that runs it.
 """
 
 from __future__ import annotations
@@ -57,6 +69,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from perceiver_io_tpu.ops import pallas_ssd
 from perceiver_io_tpu.ops.attention import torch_linear_kernel_init
 from perceiver_io_tpu.ops.short_conv import causal_depthwise_conv
 
@@ -67,6 +80,18 @@ def state_bytes(tokens: int, chunk: int, heads: int, head_dim: int, state: int) 
     """Float32 bytes of the states that the scan's backward pass holds for a
     row of ``tokens``: one ``heads x head_dim x state`` state a chunk."""
     return -(-tokens // min(chunk, tokens)) * heads * head_dim * state * 4
+
+
+def scan_impl(heads: int, head_dim: int, groups: int, state: int, chunk: int,
+              tokens: int) -> str:
+    """Which form of the scan a mixer of these sizes runs here over rows of
+    ``tokens``: ``'pallas'``, the kernel pair, on a TPU whose compiler takes
+    their blocks (``pallas_ssd.kernel_fits``: the published sizes do);
+    ``'xla'``, the einsums, elsewhere."""
+    if jax.default_backend() == "tpu" and pallas_ssd.kernel_fits(
+            heads, head_dim, groups, state, chunk, tokens):
+        return "pallas"
+    return "xla"
 
 
 def _masked_exp(exponent: Array, keep: Array) -> Array:
@@ -215,8 +240,12 @@ class Mamba2Mixer(nn.Module):
             with jax.named_scope("ssd_scan"):
                 x, b, c = jnp.split(xbc, [inner, inner + bc], axis=-1)
                 delta = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
-                y = jax.checkpoint(ssd_scan, static_argnums=(6,))(
-                    x.reshape(rows, t, h, p), delta, -jnp.exp(a_log),
-                    b.reshape(rows, t, g, n), c.reshape(rows, t, g, n), d, self.chunk_size)
+                if scan_impl(h, p, g, n, self.chunk_size, t) == "pallas":
+                    y = pallas_ssd.ssd_scan(x, delta, -jnp.exp(a_log), b, c, d, h, g,
+                                            self.chunk_size)
+                else:
+                    y = jax.checkpoint(ssd_scan, static_argnums=(6,))(
+                        x.reshape(rows, t, h, p), delta, -jnp.exp(a_log),
+                        b.reshape(rows, t, g, n), c.reshape(rows, t, g, n), d, self.chunk_size)
             y = GatedGroupNorm(g, self.eps, self.dtype, name="norm")(y.reshape(rows, t, inner), z)
             return dense("out_proj", width)(y)

@@ -31,7 +31,7 @@ from benchmarks import run as run_mod, traffic
 from benchmarks.reference import common as ref_common, nemotron_h as ref
 from benchmarks.weights import make_weights_fn, seed_words, train_rng
 from perceiver_io_tpu.models.decoder_lm import DecoderLMConfig
-from perceiver_io_tpu.ops import mamba2, moe
+from perceiver_io_tpu.ops import mamba2, moe, pallas_ssd
 from perceiver_io_tpu.ops.grouped_query_attention import GroupedQueryAttention
 from perceiver_io_tpu.ops.latent_attention import RMSNorm, causal_attention
 from perceiver_io_tpu.ops.pallas_attention import fused_attention
@@ -136,6 +136,16 @@ def test_program_matches_the_plain_reference(regime, held, offset):
     assert grad_gap < GRAD_TOL
 
 
+def test_program_through_the_scan_kernels_matches_the_plain_reference(monkeypatch):
+    """The whole program with its four mixers on the kernel pair (interpret
+    mode; the resolver answers as it does on a TPU), under the blocks' remat:
+    the kernels' ``custom_vjp`` inside ``nn.remat``, rows of 40 tokens in
+    chunks of 16, under the published initialisation."""
+    monkeypatch.setattr(mamba2, "scan_impl", lambda *sizes: "pallas")
+    logits_gap, loss_gap, grad_gap = program_and_reference("float32", 8, 0, "published")
+    assert logits_gap < LOGIT_TOL and loss_gap < LOSS_TOL and grad_gap < GRAD_TOL
+
+
 def test_bfloat16_fails_the_tolerances():
     logits_gap, loss_gap, grad_gap = program_and_reference("bfloat16", 8, 0, "published")
     assert logits_gap > 10 * LOGIT_TOL and loss_gap > 10 * LOSS_TOL and grad_gap > 10 * GRAD_TOL
@@ -144,7 +154,7 @@ def test_bfloat16_fails_the_tolerances():
 # -- the chunked scan -----------------------------------------------------------------
 
 
-def scan_operands(regime, t=512, heads=4, p=8, groups=2, n=16):
+def scan_operands(regime, t=512, heads=4, p=8, groups=2, n=16, dtype=jnp.float32):
     keys = jax.random.split(jax.random.key(11), 8)
     x = jax.random.normal(keys[0], (2, t, heads, p))
     b, c = (jax.random.normal(k, (2, t, groups, n)) for k in keys[1:3])
@@ -156,7 +166,8 @@ def scan_operands(regime, t=512, heads=4, p=8, groups=2, n=16):
         delta = jax.nn.softplus(jax.random.normal(keys[3], (2, t, heads)))
         a = -jnp.exp(0.02 * jax.random.normal(keys[4], (heads,)))
     d = 1.0 + 0.1 * jax.random.normal(keys[5], (heads,))
-    return (x, delta, a, b, c, d), jax.random.normal(keys[6], x.shape)
+    return ((x.astype(dtype), delta, a, b.astype(dtype), c.astype(dtype), d),
+            jax.random.normal(keys[6], x.shape))
 
 
 def token_loop(x, delta, a, b, c, d):
@@ -190,15 +201,89 @@ def test_chunked_scan_matches_the_token_loop(regime, chunk):
     assert max(worst(g, w) for g, w in zip(got_grads, want_grads)) < room * GRAD_TOL
 
 
-def test_scan_carries_the_state_between_chunks():
+def scan_kernels(x, delta, a, b, c, d, chunk):
+    """The kernel pair (interpret mode) behind the einsum form's signature: the
+    kernels take the mixer's layouts, heads and groups folded into the channels."""
+    rows, t, heads, p = x.shape
+    groups, n = b.shape[2:]
+    y = pallas_ssd.ssd_scan(x.reshape(rows, t, heads * p), delta, a, b.reshape(rows, t, groups * n),
+                            c.reshape(rows, t, groups * n), d, heads, groups, chunk, interpret=True)
+    return y.reshape(x.shape)
+
+
+SCANS = {"einsums": mamba2.ssd_scan, "kernels": scan_kernels}
+
+
+@pytest.mark.parametrize("sizes", [dict(heads=4, p=8), dict(heads=8, p=64, n=32)],
+                         ids=["one_slab_a_group", "two_slabs_a_group"])
+@pytest.mark.parametrize("t", [512, 200], ids=["whole_chunks", "padded_row"])
+@pytest.mark.parametrize("regime", ["published", "cell"])
+def test_scan_kernels_match_the_token_loop(regime, t, sizes):
+    """The Pallas kernel pair in interpret mode against the reference's
+    recurrence: rows of 512 tokens (4 chunks of 128) and of 200 (the second
+    chunk padded with ``Delta = 0`` tokens); two heads a group side by side in
+    one slab of lanes, and four heads of 64 a group in two slabs of 128 lanes
+    (the cell's shape of slab). Forward, and the gradient of all six operands
+    through the backward kernel (the states entering each chunk kept by the
+    forward, the state's cotangent carried from the last chunk to the first).
+    Heads of 64 channels under the cell's draw get three times the room: ``A``'s
+    gradient sums 64 channels' nearly cancelling terms of exponents near -100,
+    and the chunked form in float32 keeps 4e-5 of it, kernels (4.6e-5) and
+    einsums (3.2e-5) alike."""
+    operands, weight = scan_operands(regime, t=t, **sizes)
+    room = 3 if regime == "cell" and sizes["p"] == 64 else 1
+    want = token_loop(*operands)
+    assert worst(scan_kernels(*operands, 128), want) < LOGIT_TOL
+    every = tuple(range(6))
+    got_grads = jax.grad(lambda *o: jnp.sum(scan_kernels(*o, 128) * weight), every)(*operands)
+    want_grads = jax.grad(lambda *o: jnp.sum(token_loop(*o) * weight), every)(*operands)
+    assert [g.shape for g in got_grads] == [o.shape for o in operands]
+    assert max(worst(g, w) for g, w in zip(got_grads, want_grads)) < room * GRAD_TOL
+
+
+@pytest.mark.parametrize("dtype, tol", [(jnp.float32, GRAD_TOL), (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bfloat16"])
+def test_scan_kernels_match_the_einsums_on_the_same_operands(dtype, tol):
+    """The two forms of one scan: to float32 round-off on float32 operands, and
+    on bfloat16 operands to the rounding both share (every contraction's
+    operands to bfloat16, float32 sums)."""
+    operands, weight = scan_operands("published", t=200, dtype=dtype)
+    every = tuple(range(6))
+
+    def value_and_grads(scan):
+        return jax.value_and_grad(
+            lambda *o: jnp.sum(scan(*o, 128).astype(jnp.float32) * weight), every)(*operands)
+
+    (got, got_grads), (want, want_grads) = (value_and_grads(SCANS[k]) for k in ("kernels", "einsums"))
+    assert abs(float(got) - float(want)) < tol * abs(float(want))
+    assert [g.dtype for g in got_grads] == [w.dtype for w in want_grads]
+    assert max(worst(g, w.astype(jnp.float32)) for g, w in zip(got_grads, want_grads)) < tol
+
+
+def test_scan_runs_its_kernels_on_a_tpu_alone():
+    """The mixer's question, which ``build_model`` asks too: off a TPU the
+    einsums, whatever the sizes; the kernels' blocks are legal at the published
+    sizes and not at the tests'."""
+    assert mamba2.scan_impl(64, 64, 8, 128, 128, 8192) == "xla"
+    fits = pallas_ssd.kernel_fits
+    assert fits(64, 64, 8, 128, 128, 8192) and fits(128, 64, 8, 128, 128, 4096)
+    # the tests' sizes; four heads a group; a row shorter than a chunk and no lane tile
+    assert not (fits(8, 4, 2, 8, 16, 40) or fits(64, 64, 16, 128, 128, 8192)
+                or fits(64, 64, 8, 128, 128, 40))
+
+
+@pytest.mark.parametrize("form", sorted(SCANS))
+def test_scan_carries_the_state_between_chunks(form):
     """A scan that dropped the carry would pass every test of the short-memory
     regime but for a few tokens a chunk: under the published initialisation
-    the carried part is a large share of the output."""
+    the carried part is a large share of the output. On the kernel path a
+    kernel that zeroed its scratch at every chunk is what fails here."""
     operands, _ = scan_operands("published")
     x, delta, a, b, c, d = operands
-    whole = mamba2.ssd_scan(*operands, 128)
-    alone = jnp.concatenate([mamba2.ssd_scan(x[:, lo:lo + 128], delta[:, lo:lo + 128], a,
-                                             b[:, lo:lo + 128], c[:, lo:lo + 128], d, 128)
+    scan = SCANS[form]
+    whole = scan(*operands, 128)
+    alone = jnp.concatenate([scan(x[:, lo:lo + 128], delta[:, lo:lo + 128], a,
+                                  b[:, lo:lo + 128], c[:, lo:lo + 128], d, 128)
                              for lo in range(0, 512, 128)], axis=1)
     assert np.array_equal(np.asarray(whole[:, :128]), np.asarray(alone[:, :128]))
     assert worst(alone[:, 128:], whole[:, 128:]) > 0.1
@@ -575,6 +660,7 @@ def test_train_lm_cli_builds_the_family_from_its_model_type(tmp_path):
     assert (layers["kv_group"], layers["tied_head"], layers["shared_expert_width"]) == (2, False, 48)
     assert (layers["experts_held"], layers["experts_published"]) == (2, 8)
     assert (layers["ssd_chunk"], layers["ssd_state"]) == (16, [8, 8, 16])
+    assert layers["ssd_scan_kernel"] is False  # off a TPU: the einsums
     assert events["moe.share"]["held"] == 2 and events["moe.share"]["offset"] == 4
     rows = [r for r in read_metrics(run_dir) if "train_loss" in r]
     assert [r["step"] for r in rows] == [2]
@@ -587,3 +673,28 @@ def test_train_lm_cli_builds_the_family_from_its_model_type(tmp_path):
     assert gauges["ssd_state_bytes"] == 2 * 8 * 8 * 16 * 4
     assert gauges["moe_bounded_path_pct"] == 100.0
     assert gauges["attention_residuals_kept_pct"] == 0.0  # off a TPU: the blocked XLA path
+    assert gauges["ssd_scan_kernel_pct"] == 0.0
+
+
+@pytest.mark.parametrize("impl, share", [("pallas", 100.0), ("xla", 0.0)])
+def test_build_model_publishes_which_form_of_the_scan_runs(impl, share, monkeypatch):
+    """``ssd_scan_kernel_pct`` is the answer of the mixer's own resolver, asked
+    with the configuration's sizes, and only a stack with a Mamba-2 mixer
+    publishes it."""
+    from perceiver_io_tpu import obs
+    from perceiver_io_tpu.cli import train_lm
+
+    asked = []
+    monkeypatch.setattr(mamba2, "scan_impl", lambda *sizes: asked.append(sizes) or impl)
+    registry = obs.get_registry()
+    registry.remove("ssd_scan_kernel_pct")
+    cfg, _, builder = tiny_cell()
+    builder.build_model(cfg)
+    assert asked == [(cfg["mamba_num_heads"], cfg["mamba_head_dim"], cfg["n_groups"],
+                      cfg["ssm_state_size"], cfg["chunk_size"], 8)]  # the builder's rows of 8
+    assert registry.snapshot()["gauges"]["ssd_scan_kernel_pct"] == share
+    registry.remove("ssd_scan_kernel_pct")
+    args = train_lm.build_parser().parse_args(
+        ["--model_type", "lfm2_moe", "--batch_size", "2", "--max_seq_len", "16"])
+    train_lm.build_model(args, 50)
+    assert len(asked) == 1 and "ssd_scan_kernel_pct" not in registry.snapshot()["gauges"]

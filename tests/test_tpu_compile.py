@@ -16,6 +16,7 @@ for a described chip is written to it but cannot be read back).
 """
 
 import functools
+import math
 import os
 
 import jax
@@ -218,23 +219,58 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# the nemotron_h cell's state-space scan at its real size: 1 row x 8192 tokens,
+# 64 heads of 64 over 8 groups of state 128, in chunks of 128
+_SCAN = [((1, 8192, 64, 64), jnp.bfloat16), ((1, 8192, 64), jnp.float32),
+         ((64,), jnp.float32), ((1, 8192, 8, 128), jnp.bfloat16),
+         ((1, 8192, 8, 128), jnp.bfloat16), ((64,), jnp.float32)]
+
+
+def _compiled_scan_grad(scan, one_chip):
+    def loss(*operands):
+        return jnp.sum(scan(*operands).astype(jnp.float32) ** 2)
+
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip) for shape, dtype in _SCAN]
+    return jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(*args).compile()
+
+
 def test_chunked_scan_compiles_for_v5e_inside_its_memory(one_chip):
-    """The nemotron_h cell's state-space scan (``ops/mamba2.ssd_scan``: plain
-    XLA einsums, no kernel) at its real size, 1 row x 8192 tokens, 64 heads of
-    64 over 8 groups of state 128 in chunks of 128, forward and backward under
-    its checkpoint: the temporaries are the chunked form's (the decay matrix,
-    the chunks' states), nowhere near one state a token (17 GB)."""
+    """The scan as the chip runs it (``ops/pallas_ssd.py``: a forward and a
+    backward kernel under one ``custom_vjp``), forward and backward: both
+    lower through Mosaic inside their VMEM, the temporaries are the states
+    entering the 64 chunks (134 MB) and not the einsum form's, and nothing of
+    the size of the decay matrix (``rows x chunks x H x 128 x 128``) is in the
+    program."""
+    import re
+
+    from perceiver_io_tpu.ops import pallas_ssd
+
+    def scan(x, delta, a, b, c, d):
+        return pallas_ssd.ssd_scan(x.reshape(1, 8192, 4096), delta, a, b.reshape(1, 8192, 1024),
+                                   c.reshape(1, 8192, 1024), d, 64, 8, 128, interpret=False)
+
+    compiled = _compiled_scan_grad(scan, one_chip)
+    text = compiled.as_text()
+    kernels = [len(re.findall(rf"%{name}(\.\d+)? = ", text))
+               for name in (pallas_ssd.KERNEL_FWD, pallas_ssd.KERNEL_BWD)]
+    assert kernels == [1, 1] and text.count("tpu_custom_call") >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+    shapes = {tuple(map(int, dims.split(","))) for dims in re.findall(r"\[([\d,]+)\]", text)}
+    assert not [dims for dims in shapes if dims[-2:] == (128, 128)
+                and math.prod(dims) >= 64 * 64 * 128 * 128]
+
+
+def test_chunked_scan_einsums_compile_for_v5e_inside_their_memory(one_chip):
+    """The einsum form (``ops/mamba2.ssd_scan``: plain XLA, no kernel; the
+    CPU's path and the kernels' oracle) at the same size, forward and backward
+    under its checkpoint: the temporaries are the chunked form's (the decay
+    matrix, the chunks' states), nowhere near one state a token (17 GB)."""
     from perceiver_io_tpu.ops.mamba2 import ssd_scan
 
-    def loss(x, delta, a, b, c, d):
-        return jnp.sum(jax.checkpoint(ssd_scan, static_argnums=(6,))(
-            x, delta, a, b, c, d, 128).astype(jnp.float32) ** 2)
+    def scan(*operands):
+        return jax.checkpoint(ssd_scan, static_argnums=(6,))(*operands, 128)
 
-    shapes = [((1, 8192, 64, 64), jnp.bfloat16), ((1, 8192, 64), jnp.float32),
-              ((64,), jnp.float32), ((1, 8192, 8, 128), jnp.bfloat16),
-              ((1, 8192, 8, 128), jnp.bfloat16), ((64,), jnp.float32)]
-    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip) for shape, dtype in shapes]
-    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(*args).compile()
+    compiled = _compiled_scan_grad(scan, one_chip)
     assert "tpu_custom_call" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 3e9
 
