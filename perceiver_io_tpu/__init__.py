@@ -14,7 +14,14 @@ Public API mirrors the reference package surface (reference
 `perceiver/__init__.py:1-13`).
 """
 
-import jax as _jax
+import time as _time
+
+_IMPORT_START_NS = _time.monotonic_ns()  # first statement: the `import` span's start
+
+from perceiver_io_tpu.obs.tracing import add_span as _add_span, span as _span
+
+with _span("import", module="jax"):
+    import jax as _jax
 
 # Sharding-invariant PRNG (the modern jax default; this build ships it off):
 # the same key must draw the same bits whether a step runs replicated or
@@ -90,3 +97,6 @@ __all__ = [
     "export_forward",
     "load_exported",
 ]
+
+# last statement: everything above, the third-party imports' own spans inside it
+_add_span("import", _IMPORT_START_NS, _time.monotonic_ns(), module="perceiver_io_tpu")

@@ -10,6 +10,10 @@ DDP-flags replacement) and compute (dtype / attention impl / remat).
 
 from __future__ import annotations
 
+import time
+
+_IMPORT_START_NS = time.monotonic_ns()  # first statement: the `import` span's start
+
 import argparse
 import os
 from typing import Optional, Tuple
@@ -17,6 +21,7 @@ from typing import Optional, Tuple
 import jax.numpy as jnp
 
 import perceiver_io_tpu as pit
+from perceiver_io_tpu import obs
 from perceiver_io_tpu.ops.masking import TextMasking
 from perceiver_io_tpu.parallel.mesh import make_mesh
 from perceiver_io_tpu.training.optim import OptimizerConfig, make_optimizer
@@ -1206,3 +1211,8 @@ def resume_state(args, state):
         # parse_with_resume guard but has no checkpoint steps to restore
         raise SystemExit(_nothing_to_resume(args.resume)) from None
     return state, args.resume
+
+
+# last statement: this module's imports (the package, the Trainer's, orbax's own span inside)
+obs.add_span("import", _IMPORT_START_NS, time.monotonic_ns(),
+             module="perceiver_io_tpu.cli.common")

@@ -129,7 +129,6 @@ def model_config(args, vocab_size: int) -> DecoderLMConfig:
     return DecoderLMConfig.from_dict(sizes)
 
 
-@obs.span("lm.build_model")
 def build_model(args, vocab_size: int) -> DecoderLM:
     config = model_config(args, vocab_size)
     held = config.n_routed_experts if config.experts_held is None else config.experts_held

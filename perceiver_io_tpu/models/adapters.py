@@ -23,7 +23,11 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from flax import linen as nn
+
+from perceiver_io_tpu import obs
+
+with obs.span("import", module="flax"):
+    from flax import linen as nn
 
 from perceiver_io_tpu.ops.attention import (
     _LinearParams,

@@ -11,7 +11,8 @@ One subsystem, four pieces, every layer wired through it:
   bounded per span name, read back with ``spans()``, and mirrored while open
   as a profiler annotation ``pio.<name>`` once jax is imported; ``add_span``
   enters one measured elsewhere (the compile path's ``jax.trace`` /
-  ``jax.lower`` / ``jax.backend_compile``, the Trainer's ``train.step``).
+  ``jax.lower`` / ``jax.backend_compile``, the Trainer's ``train.step``, a
+  module's own ``import``).
   ``event`` and the JSONL sink (compiles, warmups, stalls; every record
   dual-stamped wall + monotonic and pid-labeled) stay opt-in.
 - :mod:`reqtrace` — distributed request tracing: ``TraceContext``

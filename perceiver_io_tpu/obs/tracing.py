@@ -11,7 +11,13 @@ record:
   first, drops counted per name), so a week of ``train.step`` records can
   never evict the dozen set-up spans. ``spans()`` returns a snapshot;
   ``add_span`` enters a span whose ends were measured elsewhere (the
-  ``jax.monitoring`` listener in ``obs.watchdog``, the Trainer's loop). While
+  ``jax.monitoring`` listener in ``obs.watchdog``, the Trainer's loop, and a
+  module's own import: the package and ``cli.common`` take the clock in their
+  first statement and enter ``import`` with ``module`` in their last; a
+  third-party import that costs a quarter second or more on the chip host is
+  ``with span("import", module=...)`` where the program first performs it,
+  so a process's timeline starts at the package's first statement; PERF.md
+  section 3 lists them). While
   open, a span is also a ``jax.profiler.TraceAnnotation("pio." + name)`` if
   ``jax`` is already imported in the process (this module never imports it):
   under an active profiler the program's spans lie in the capture's host

@@ -54,8 +54,12 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from perceiver_io_tpu import obs
+
+with obs.span("import", module="jax.experimental.pallas"):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
 from flax.linen import dtypes as _flax_dtypes
 

@@ -25,7 +25,11 @@ from typing import Any, Dict, Optional
 
 import jax
 import numpy as np
-import orbax.checkpoint as ocp
+
+from perceiver_io_tpu import obs
+
+with obs.span("import", module="orbax.checkpoint"):
+    import orbax.checkpoint as ocp
 
 HPARAMS_FILE = "hparams.json"
 LAST_SUBDIR = "last"  # unconditional newest-state slot (preemption/crash)

@@ -42,7 +42,11 @@ from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import optax
+
+from perceiver_io_tpu import obs
+
+with obs.span("import", module="optax"):
+    import optax
 
 
 def torch_one_cycle_schedule(

@@ -915,3 +915,94 @@ def test_engine_slo_wiring_records_completions_and_sheds():
     finally:
         release.set()
         eng.close()
+
+
+# -- the start-up timeline: `import` spans ------------------------------------
+
+_IMPORT_SPANS_CHILD = """
+import json, sys
+import perceiver_io_tpu.cli.common
+from perceiver_io_tpu import obs
+found = obs.spans("import")
+json.dump({"spans": list(found), "dropped": obs.spans().dropped,
+           "names": sorted({r["name"] for r in obs.spans()})}, sys.stdout)
+"""
+
+
+@pytest.fixture(scope="module")
+def import_spans():
+    """What ONE fresh process holds after ``import perceiver_io_tpu.cli.common``
+    (the module every training entry point and benchmark builder imports
+    first): its ``import`` spans, the drops, and every span name."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": root}
+    child = subprocess.run([sys.executable, "-c", _IMPORT_SPANS_CHILD], env=env, cwd=root,
+                           capture_output=True, text=True, timeout=120)
+    assert child.returncode == 0, child.stderr[-2000:]
+    return json.loads(child.stdout)
+
+
+def _import_span(import_spans, module):
+    mine = [r for r in import_spans["spans"] if r["module"] == module]
+    assert len(mine) == 1, (module, [r["module"] for r in import_spans["spans"]])
+    return mine[0]
+
+
+def test_import_spans_name_the_package_and_its_entry_module(import_spans):
+    """The package's own import and ``cli.common``'s are spans; the package
+    is imported before ``cli.common``'s body runs, so the two lie apart and
+    the package's comes first."""
+    package = _import_span(import_spans, "perceiver_io_tpu")
+    common = _import_span(import_spans, "perceiver_io_tpu.cli.common")
+    assert package["end_ns"] <= common["start_ns"]
+    assert import_spans["spans"][0]["start_ns"] == package["start_ns"]  # the timeline's first
+    # importing is all the process has done: nothing else is on the timeline
+    assert import_spans["names"] == ["import"]
+
+
+@pytest.mark.parametrize("inner, outer", [
+    ("jax", "perceiver_io_tpu"),
+    ("flax", "perceiver_io_tpu"),
+    ("jax.experimental.pallas", "perceiver_io_tpu"),
+    ("optax", "perceiver_io_tpu.cli.common"),
+    ("orbax.checkpoint", "perceiver_io_tpu.cli.common"),
+])
+def test_third_party_import_span_lies_inside_its_importer(import_spans, inner, outer):
+    """A wrapped third-party import is timed where the program first performs
+    it, so its interval lies inside its importer's: readers take unions."""
+    inside, around = _import_span(import_spans, inner), _import_span(import_spans, outer)
+    assert around["start_ns"] <= inside["start_ns"] < inside["end_ns"] <= around["end_ns"]
+
+
+def test_import_spans_are_few_whole_and_none_dropped(import_spans):
+    records = import_spans["spans"]
+    assert 7 <= len(records) <= 15
+    assert all(r["start_ns"] < r["end_ns"] and r["ok"] for r in records)
+    assert all(isinstance(r["module"], str) and r["module"] for r in records)
+    assert len({r["module"] for r in records}) == len(records)  # one a module
+    assert import_spans["dropped"] == {}
+
+
+def test_tracing_module_still_imports_no_jax():
+    """The recorder's contract (its docstring): entry points pick their
+    platform before the first ``import jax``, so ``obs/tracing.py`` never
+    imports it, at any depth of its source; now that the package's first
+    statement imports it BEFORE jax, that is what keeps the package's order."""
+    import ast
+
+    from perceiver_io_tpu.obs import tracing
+
+    with open(tracing.__file__) as f:
+        tree = ast.parse(f.read())
+    imported = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            imported |= {alias.name.split(".")[0] for alias in node.names}
+        elif isinstance(node, ast.ImportFrom):
+            imported.add((node.module or "").split(".")[0])
+    assert imported and "jax" not in imported and "jaxlib" not in imported
+    assert not hasattr(tracing, "jax")
