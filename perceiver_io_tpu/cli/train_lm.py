@@ -2,9 +2,12 @@
 skeleton for the DeepSeek-V3 family, latent attention + routed and shared
 experts + multi-token prediction; for ``lfm2_moe``, gated short convolutions
 and grouped-query attention mixed by ``layer_types`` + routed experts + a tied
-head; and for ``nemotron_h``, one sublayer a block by
+head; for ``nemotron_h``, one sublayer a block by
 ``hybrid_override_pattern``: Mamba-2 mixers, attention without positions,
-squared-ReLU experts beside a shared expert of its own width).
+squared-ReLU experts beside a shared expert of its own width; and for
+``sdar_moe``, grouped-query attention and 128 softmax-routed experts in every
+layer, trained by block diffusion when the configuration's ``objective`` says
+so: ``training.make_block_diffusion_steps``).
 
 The model's sizes are the keys of a published ``config.json`` (``--config``),
 each also a flag of its own name that overrides the file; the published
@@ -25,6 +28,8 @@ Usage:
         --layer_types conv,full_attention,conv ...
     python -m perceiver_io_tpu.cli.train_lm --synthetic --model_type nemotron_h \
         --hybrid_override_pattern 'MEMEM*EME' ...
+    python -m perceiver_io_tpu.cli.train_lm --synthetic --model_type sdar_moe \
+        --block_length 4 ...
 """
 
 from __future__ import annotations
@@ -44,11 +49,12 @@ from perceiver_io_tpu.data.imdb import IMDBDataModule
 from perceiver_io_tpu.models.decoder_lm import (
     LFM2_MOE,
     NEMOTRON_H,
+    SDAR_MOE,
     DecoderLM,
     DecoderLMConfig,
 )
 from perceiver_io_tpu.ops import mamba2, moe
-from perceiver_io_tpu.training import TrainState, make_lm_steps
+from perceiver_io_tpu.training import TrainState, make_block_diffusion_steps, make_lm_steps
 from perceiver_io_tpu.training.trainer import Trainer
 
 # CPU-scale sizes for a run that names none (the shape of the family: one
@@ -81,7 +87,16 @@ SMALL_NEMOTRON_H = dict(
     routed_scaling_factor=2.5, norm_topk_prob=True, mlp_hidden_act="relu2",
     use_conv_bias=True, layer_norm_epsilon=1e-5,
 )
-SMALL_SIZES = {LFM2_MOE: SMALL_LFM2, NEMOTRON_H: SMALL_NEMOTRON_H}
+# the same for ``--model_type sdar_moe``: groups of two query heads, a softmax
+# router over 8 experts, trained by block diffusion over blocks of 4 (the mask
+# id is the vocabulary's last row)
+SMALL_SDAR = dict(
+    hidden_size=64, moe_intermediate_size=32, num_hidden_layers=2, num_attention_heads=4,
+    num_key_value_heads=2, head_dim=16, num_experts=8, num_experts_per_tok=2,
+    norm_topk_prob=True, rope_theta=1000000.0, rms_norm_eps=1e-6, objective="block_diffusion",
+    block_length=4,
+)
+SMALL_SIZES = {LFM2_MOE: SMALL_LFM2, NEMOTRON_H: SMALL_NEMOTRON_H, SDAR_MOE: SMALL_SDAR}
 Flag = collections.namedtuple("Flag", "name type")
 # every size is a flag: the skeleton's fields (the vocabulary is the
 # tokenizer's, ``--vocab_size`` of the data flags), and the published keys
@@ -126,13 +141,17 @@ def model_config(args, vocab_size: int) -> DecoderLMConfig:
             sizes[field.name] = getattr(args, field.name)
     if family == NEMOTRON_H:  # the depth is the pattern's, whichever of the two was given
         sizes["num_hidden_layers"] = len(sizes["hybrid_override_pattern"])
+    if family == SDAR_MOE and "mask_token_id" not in sizes:
+        sizes["mask_token_id"] = vocab_size - 1
     return DecoderLMConfig.from_dict(sizes)
 
 
 def build_model(args, vocab_size: int) -> DecoderLM:
     config = model_config(args, vocab_size)
     held = config.n_routed_experts if config.experts_held is None else config.experts_held
-    tokens = args.batch_size * args.max_seq_len
+    diffusion = config.objective == "block_diffusion"
+    # a row of block diffusion runs as two: its noised and its clean copy
+    tokens = args.batch_size * args.max_seq_len * (2 if diffusion else 1)
     obs.event("moe.share", held=held, total=config.n_routed_experts,
               offset=config.expert_offset,
               capacity_rows=moe.TILE_ROWS * moe.capacity_tiles(
@@ -156,7 +175,8 @@ def build_model(args, vocab_size: int) -> DecoderLM:
               ssd_state=[config.mamba_num_heads, config.mamba_head_dim,
                          config.ssm_state_size] if scans else [],
               ssd_scan_kernel=scan_kernel,
-              tied_head=config.tie_word_embeddings)
+              tied_head=config.tie_word_embeddings,
+              objective=config.objective, block_length=config.block_length)
     # float32 bytes of the states that a Mamba-2 layer's backward pass holds for
     # one row: one a chunk, not one a token (0 without such a layer)
     obs.get_registry().gauge("ssd_state_bytes").set(mamba2.state_bytes(
@@ -202,7 +222,9 @@ def main(argv: Optional[Sequence[str]] = None):
     state = TrainState.create(variables["params"], tx, jax.random.key(args.seed + 2))
     state, resume_dir = common.resume_state(args, state)
 
-    train_step, eval_step, _ = make_lm_steps(model, schedule)
+    steps = (make_block_diffusion_steps if model.config.objective == "block_diffusion"
+             else make_lm_steps)
+    train_step, eval_step, _ = steps(model, schedule)
     trainer = Trainer(
         train_step,
         eval_step,

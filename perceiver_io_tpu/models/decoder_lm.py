@@ -1,4 +1,4 @@
-"""A token-level causal decoder: ONE skeleton for three public families.
+"""A token-level causal decoder: ONE skeleton for four public families.
 
 The skeleton: an embedding, a Python loop of rematerialised pre-norm blocks
 
@@ -44,7 +44,25 @@ Each family's published ``config.json`` maps onto the skeleton in a
   own; an untied head; no MTP module. It is the language model that those keys
   define, trained by next-token cross-entropy: the second (denoiser) tower
   that the model's card describes has no key in that file and nothing here
-  stands in for it.
+  stands in for it;
+- ``sdar_moe`` (SDAR-30B-A3B-Chat, a Qwen3-MoE stack trained by block
+  diffusion): ``'full_attention'`` with per-head q/k norms and half-split
+  rotary in every layer, the routed expert layer in every layer, 128 experts
+  under a SOFTMAX router (top 8 of the scores, no bias, renormalised), NO
+  shared expert, an untied head, no MTP module; its ``objective`` is
+  ``'block_diffusion'`` (below).
+
+Block diffusion (BD3-LM, arXiv:2503.09573, the vectorised form). A row ``x``
+of L tokens is cut into blocks of ``block_length``; each block draws a noise
+level ``t_b`` and each of its tokens becomes ``mask_token_id`` with
+probability ``t_b`` (``training/steps.py`` draws them): ``x_t``. The stack
+runs over the 2L positions ``[x_t ; x]`` (``hidden_states(x_t, x)``), both
+halves at rotary positions 0..L-1, under the block-diffusion mask
+(``ops/masking.py``): a noised token reads its own noised block and the clean
+blocks before it, a clean token the clean blocks up to its own. The loss
+(``diffusion_loss``) is over the noised half alone: ``(1/L) sum over masked i
+of CE(logits_i, x_i) / t_{b(i)}``, a mean over rows; the last layer's clean
+half feeds no loss and is computed all the same.
 
 Nothing here knows a width. The expert layers are told which experts this
 chip holds (``experts_held``, ``expert_offset``; default all): with a share,
@@ -111,7 +129,9 @@ Array = jax.Array
 MIXERS = ("mla", "full_attention", "conv", "mamba2")
 EXPERTS = "moe"  # the routed expert layer: an FFN's kind, and a one-sublayer block's
 LFM2_MOE = "lfm2_moe"      # the published ``model_type`` of the second family
-NEMOTRON_H = "nemotron_h"  # ... and of the third
+NEMOTRON_H = "nemotron_h"  # ... of the third
+SDAR_MOE = "sdar_moe"      # ... and of the fourth
+OBJECTIVES = ("next_token", "block_diffusion")
 # a character of ``hybrid_override_pattern`` -> the block's one sublayer (the
 # family's ``-``, a dense MLP block, is in no configuration here)
 _PATTERN = {"M": "mamba2", "*": "full_attention", "E": EXPERTS}
@@ -124,6 +144,11 @@ _REQUIRED = {
     "hidden_act": "silu", "moe_layer_freq": 1, "attention_bias": False,
 }
 _REQUIRED_LFM2 = {"conv_bias": False, "use_expert_bias": True, "tie_word_embeddings": True}
+_REQUIRED_SDAR = {
+    "use_sliding_window": False, "mlp_only_layers": [], "decoder_sparse_step": 1,
+    "rope_scaling": None, "tie_word_embeddings": False, "attention_bias": False,
+    "hidden_act": "silu",
+}
 _REQUIRED_NEMOTRON_H = {
     "use_conv_bias": True, "use_bias": False, "mamba_proj_bias": False, "mlp_bias": False,
     "attention_bias": False, "mlp_hidden_act": "relu2", "mamba_hidden_act": "silu",
@@ -141,6 +166,8 @@ _NEMOTRON_H_KEYS = ("mamba_num_heads", "mamba_head_dim", "n_groups", "ssm_state_
                     "conv_kernel", "chunk_size", "time_step_min", "time_step_max",
                     "time_step_floor", "mlp_hidden_act", "moe_shared_expert_intermediate_size")
 _NEMOTRON_H_ONLY = _NEMOTRON_H_KEYS + ("one_sublayer_blocks", "qk_norm", "rotary")
+# ... nor any of those: what only the SDAR family's ``from_dict`` reads or sets
+_SDAR_ONLY = ("objective", "block_length", "mask_token_id", "scoring_func")
 
 
 def _require(config: Dict[str, Any], required: Dict[str, Any]) -> None:
@@ -200,6 +227,13 @@ class DecoderLMConfig:
     mlp_hidden_act: str = "silu"
     # the shared expert's width; 0: ``n_shared_experts * moe_intermediate_size``
     moe_shared_expert_intermediate_size: int = 0
+    # the router's scores: 'sigmoid' (with a selection bias) or 'softmax' (ops/moe.py)
+    scoring_func: str = "sigmoid"
+    # what the model is trained for: next-token CE, or block diffusion over
+    # blocks of ``block_length`` tokens with ``mask_token_id`` as the noise
+    objective: str = "next_token"
+    block_length: int = 0
+    mask_token_id: int = 0
     # 'conv' layers: the taps of the short convolution
     conv_L_cache: int = 0
     tie_word_embeddings: bool = False
@@ -220,8 +254,11 @@ class DecoderLMConfig:
             return cls._from_lfm2_moe(config)
         if config.get("model_type") == NEMOTRON_H:
             return cls._from_nemotron_h(config)
+        if config.get("model_type") == SDAR_MOE:
+            return cls._from_sdar_moe(config)
         _require(config, _REQUIRED)
-        names = {f.name for f in dataclasses.fields(cls)} - set(_LFM2_ONLY + _NEMOTRON_H_ONLY)
+        names = {f.name for f in dataclasses.fields(cls)} - set(
+            _LFM2_ONLY + _NEMOTRON_H_ONLY + _SDAR_ONLY)
         return cls(**{k: v for k, v in config.items() if k in names})
 
     @classmethod
@@ -273,7 +310,37 @@ class DecoderLMConfig:
                    rms_norm_eps=config["layer_norm_epsilon"], one_sublayer_blocks=True,
                    qk_norm=False, rotary=False, expert_bias_buffer=True, **sizes)
 
+    @classmethod
+    def _from_sdar_moe(cls, config: Dict[str, Any]) -> "DecoderLMConfig":
+        """SDAR's keys (``sdar_moe``, Qwen3-MoE's under another name):
+        ``full_attention`` and the expert layer in every layer
+        (``decoder_sparse_step`` 1, no ``mlp_only_layers``), heads of the
+        published ``head_dim`` with per-head q/k norms and half-split rotary at
+        ``rope_theta``, ``num_experts`` under a softmax router, no shared
+        expert, an untied head, no MTP module. ``objective``,
+        ``block_length`` and ``mask_token_id`` say how it is trained (no key
+        of the published file names them: the configuration's)."""
+        _require(config, _REQUIRED_SDAR)
+        names = {"vocab_size", "hidden_size", "moe_intermediate_size", "num_hidden_layers",
+                 "num_attention_heads", "num_key_value_heads", "head_dim", "num_experts_per_tok",
+                 "norm_topk_prob", "rope_theta", "rms_norm_eps", "experts_held", "expert_offset",
+                 "routed_scaling_factor", "objective", "block_length", "mask_token_id"}
+        sizes = {k: v for k, v in config.items() if k in names}
+        return cls(n_routed_experts=config["num_experts"],
+                   intermediate_size=config.get("intermediate_size", 0), first_k_dense_replace=0,
+                   n_shared_experts=0, scoring_func="softmax",
+                   layer_types=("full_attention",) * config["num_hidden_layers"], **sizes)
+
     def __post_init__(self):
+        if self.objective not in OBJECTIVES or self.scoring_func not in ("sigmoid", "softmax"):
+            raise ValueError(f"objective {self.objective!r} / scoring_func {self.scoring_func!r}")
+        if self.objective == "block_diffusion" and (
+                self.block_length < 1 or self.block_length & (self.block_length - 1)
+                or self.num_nextn_predict_layers or self.one_sublayer_blocks
+                or set(self.mixers) - {"full_attention"}):
+            raise ValueError(f"block diffusion takes blocks of a power of two "
+                             f"({self.block_length}) and grouped-query attention in every "
+                             "layer, without an MTP module")
         if self.num_nextn_predict_layers not in (0, 1):
             raise ValueError("num_nextn_predict_layers must be 0 or 1")
         kinds = MIXERS + ((EXPERTS,) if self.one_sublayer_blocks else ())
@@ -334,6 +401,7 @@ class DecoderBlock(nn.Module):
                 num_heads=c.num_attention_heads, num_kv_heads=c.num_key_value_heads,
                 head_dim=c.head_dim, rope_theta=c.rope_theta, rms_norm_eps=c.rms_norm_eps,
                 qk_norm=c.qk_norm, rotary=c.rotary,
+                block_diffusion=c.block_length if c.objective == "block_diffusion" else 0,
                 attn_impl=self.attn_impl, dtype=self.dtype, name="attn")
         return MultiHeadLatentAttention(
             num_heads=c.num_attention_heads, q_lora_rank=c.q_lora_rank,
@@ -353,6 +421,7 @@ class DecoderBlock(nn.Module):
             expert_form="swiglu" if c.mlp_hidden_act == "silu" else c.mlp_hidden_act,
             routed_scaling_factor=c.routed_scaling_factor, norm_topk_prob=c.norm_topk_prob,
             gate_eps=c.gate_eps, expert_bias_buffer=c.expert_bias_buffer,
+            scoring_func=c.scoring_func,
             experts_held=c.experts_held, expert_offset=c.expert_offset,
             expert_impl=self.expert_impl, dtype=self.dtype, name="moe")(x)
 
@@ -417,12 +486,17 @@ class DecoderLM(nn.Module):
         return jax.checkpoint_policies.save_only_these_names(REMAT_FUSED_OUT, REMAT_FUSED_STATS)
 
     @nn.compact
-    def hidden_states(self, token_ids: Array) -> Tuple[Array, Optional[Array], list, bool]:
+    def hidden_states(self, token_ids: Array, clean_ids: Optional[Array] = None
+                      ) -> Tuple[Array, Optional[Array], list, bool]:
         """``(h, h_mtp, stats, kept)``: the main stack's and the MTP module's
         outputs after their final norms (``h_mtp`` None without the module),
         the expert layers' statistics, one dict a layer, and whether the
-        blocks keep the causal kernel's residuals (``_remat_policy``)."""
+        blocks keep the causal kernel's residuals (``_remat_policy``). With
+        ``clean_ids`` (block diffusion) ``token_ids`` are the noised ``x_t``:
+        the stack runs over ``[x_t ; x]`` and ``h`` is the noised half's."""
         c = self.config
+        if clean_ids is not None:
+            token_ids = jnp.concatenate([token_ids, clean_ids], axis=1)
         policy = self._remat_policy(*token_ids.shape)
         kept = policy is not None
         block = nn.remat(DecoderBlock, policy=policy)
@@ -436,6 +510,8 @@ class DecoderLM(nn.Module):
         for i, (mixer, ffn) in enumerate(c.blocks):
             x, s = make(mixer, ffn, f"layer_{i}")(x)
             stats.append(s)
+        if clean_ids is not None:
+            x = x[:, :clean_ids.shape[1]]
         h = self.final_norm(x)
         if not c.num_nextn_predict_layers:
             return h, None, stats, kept
@@ -484,11 +560,45 @@ class DecoderLM(nn.Module):
                 loss_mtp = head_loss(h_mtp, kernel, next_token_labels(token_ids, pad_mask, 2))
             metrics["loss_mtp"] = loss_mtp
             loss = loss + self.config.mtp_loss_factor * loss_mtp
-        routed = [s for s in stats if s]
-        for name in (routed[0] if routed else ()):
-            reduce = jnp.sum if name.startswith("dropped") else jnp.mean
-            metrics[f"moe_{name}"] = reduce(jnp.stack([s[name] for s in routed]))
+        metrics.update(routing_metrics(stats))
         return loss, metrics
+
+    def diffusion_logits(self, noisy_ids: Array, token_ids: Array) -> Array:
+        """(B, L) ``x_t`` and ``x`` -> float32 logits of the noised half,
+        (B, L, vocab): ``[:, i]`` scores ``x_i``."""
+        h, _, _, _ = self.hidden_states(noisy_ids, token_ids)
+        return self._logits(h, self._head())
+
+    def diffusion_loss(self, noisy_ids: Array, token_ids: Array, weights: Array
+                       ) -> Tuple[Array, dict]:
+        """The block-diffusion loss ``sum_i weights_i CE(logits_i, x_i) /
+        (B L)`` over the noised half (``weights``: ``1 / t_b`` where token i
+        was masked, 0 elsewhere; float32) and the step's metrics."""
+        h, _, stats, kept = self.hidden_states(noisy_ids, token_ids)
+
+        @jax.checkpoint
+        def head_loss(hidden, kernel, labels, weights):
+            with jax.named_scope("head_loss"):
+                logits = self._logits(hidden, kernel)
+                picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+                ce = jax.nn.logsumexp(logits, axis=-1) - picked
+                return jnp.sum(ce * weights) / weights.size
+
+        loss = head_loss(h, self._head(), token_ids.astype(jnp.int32), weights)
+        metrics = {"attention_residuals_kept_pct": jnp.float32(100.0 if kept else 0.0)}
+        metrics.update(routing_metrics(stats))
+        return loss, metrics
+
+
+def routing_metrics(stats: list) -> dict:
+    """The expert layers' statistics as the step's ``moe_*`` metrics: the
+    mean over the layers, the dropped assignments summed."""
+    metrics = {}
+    routed = [s for s in stats if s]
+    for name in (routed[0] if routed else ()):
+        reduce = jnp.sum if name.startswith("dropped") else jnp.mean
+        metrics[f"moe_{name}"] = reduce(jnp.stack([s[name] for s in routed]))
+    return metrics
 
 
 def next_token_labels(token_ids: Array, pad_mask: Optional[Array], ahead: int) -> Array:

@@ -12,6 +12,11 @@ turned by the rotary embedding over the WHOLE head in the half-split pairing
 see no position but the causal mask's. Scores are ``q . k / sqrt(head_dim)``,
 causal softmax, ``concat_h(P v) W_o``. No biases.
 
+With ``block_diffusion`` B > 0 (the SDAR family's training) the input is the
+2L positions ``[x_t ; x]`` of a row of L tokens: both halves take the rotary
+positions 0..L-1, and the causal softmax becomes the block-diffusion mask with
+blocks of B (``ops/masking.py``).
+
 The inner product is ``ops.latent_attention.causal_attention``, which takes
 the grouped operands as they are: the Pallas kernel's block index maps send a
 query head to its key/value head, the blocked XLA path contracts (key/value
@@ -43,6 +48,7 @@ class GroupedQueryAttention(nn.Module):
     rms_norm_eps: float = 1e-6
     qk_norm: bool = True  # the per-head RMSNorm of queries and keys
     rotary: bool = True   # the rotary embedding of queries and keys
+    block_diffusion: int = 0  # B > 0: the input is [x_t ; x] under the block-diffusion mask
     attn_impl: str = "auto"
     dtype: Any = jnp.float32
 
@@ -64,7 +70,10 @@ class GroupedQueryAttention(nn.Module):
         with jax.named_scope("gqa_attention"):
             q, k, v = heads("q", h, True), heads("k", kv, True), heads("v", kv, False)
             if self.rotary:
-                cos, sin = rotary_angles(jnp.arange(t), depth, self.rope_theta)
+                positions = jnp.arange(t)
+                if self.block_diffusion:  # a token's position in its row, in both halves
+                    positions = jnp.tile(jnp.arange(t // 2), 2)
+                cos, sin = rotary_angles(positions, depth, self.rope_theta)
                 q, k = apply_rotary_half(q, cos, sin), apply_rotary_half(k, cos, sin)
-            out = causal_attention(q, k, v, self.attn_impl)
+            out = causal_attention(q, k, v, self.attn_impl, block_diffusion=self.block_diffusion)
             return dense("out_proj", d)(out.reshape(b, t, h * depth))

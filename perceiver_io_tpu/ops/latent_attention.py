@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from perceiver_io_tpu.ops.attention import torch_linear_kernel_init
+from perceiver_io_tpu.ops import masking
 from perceiver_io_tpu.ops.rotary import apply_rotary_interleaved, rotary_angles
 
 Array = jax.Array
@@ -83,6 +84,26 @@ def _causal_block(q: Array, k: Array, v: Array, first_row: int) -> Array:
     rows``: float32 logits and softmax, the mask only on the diagonal block.
     With fewer key/value heads than query heads the queries are taken as
     (key/value head, member of its group): no copy of K / V is made."""
+    def visible(t, s):
+        rows = first_row + jnp.arange(t)[:, None]
+        return jnp.arange(s)[None, :] <= rows
+
+    return _masked_block(q, k, v, visible)
+
+
+def _block_diffusion_block(q: Array, k: Array, v: Array, first_row: int, block: int) -> Array:
+    """Rows ``first_row ..`` of the queries of ``[x_t ; x]`` against every
+    key, under the block-diffusion mask (``ops.masking``)."""
+    def visible(t, s):
+        return masking.block_diffusion_visible(
+            first_row + jnp.arange(t)[:, None], jnp.arange(s)[None, :], s // 2, block)
+
+    return _masked_block(q, k, v, visible)
+
+
+def _masked_block(q: Array, k: Array, v: Array, visible) -> Array:
+    """Float32 logits and softmax of the T queries against the S keys
+    where the (T, S) ``visible(T, S)`` is True."""
     b, t, h, d = q.shape
     grouped = k.shape[2] != h
     scores, weighted = "bthd,bshd->bhts", "bhts,bshd->bthd"
@@ -90,8 +111,7 @@ def _causal_block(q: Array, k: Array, v: Array, first_row: int) -> Array:
         q = q.reshape(b, t, k.shape[2], h // k.shape[2], d)
         scores, weighted = "btkgd,bskd->bkgts", "bkgts,bskd->btkgd"
     logits = jnp.einsum(scores, q, k, preferred_element_type=jnp.float32) * d ** -0.5
-    rows = first_row + jnp.arange(t)[:, None]
-    logits = jnp.where(jnp.arange(k.shape[1])[None, :] <= rows, logits, MASK_VALUE)
+    logits = jnp.where(visible(t, k.shape[1]), logits, MASK_VALUE)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     out = jnp.einsum(weighted, probs, v, preferred_element_type=jnp.float32).astype(v.dtype)
     return out.reshape(b, t, h, v.shape[-1]) if grouped else out
@@ -106,21 +126,34 @@ def resolve_causal_impl(impl: str) -> str:
 
 
 def causal_attention(q: Array, k: Array, v: Array, impl: str = "auto",
-                     query_block: int = XLA_QUERY_BLOCK) -> Array:
+                     query_block: int = XLA_QUERY_BLOCK, block_diffusion: int = 0) -> Array:
     """Causal self-attention of (B, T, H, D) q with (B, T, Hkv, D) k and (B,
     T, Hkv, Dv) v: row i sees keys 0..i, query head h reads key/value head
     ``h // (H // Hkv)`` (``Hkv`` = ``H``: every head its own). Returns (B, T,
-    H, Dv). The kernel's blocks follow the score depth (``pallas_blocks``)."""
+    H, Dv). The kernel's blocks follow the score depth (``pallas_blocks``).
+
+    ``block_diffusion`` B > 0: the row is ``[x_t ; x]``, two halves of T / 2,
+    under the block-diffusion mask with blocks of B
+    (``ops.masking.block_diffusion_visible``) in place of the causal rule: the
+    kernel skips the tiles it hides, the blocked XLA path takes blocks of
+    queries against every key."""
     impl = resolve_causal_impl(impl)
     if impl == "pallas":
-        from perceiver_io_tpu.ops.pallas_attention import fused_attention
+        from perceiver_io_tpu.ops.pallas_attention import BlockDiffusion, fused_attention
 
         q_block, kv_block = pallas_blocks(q.shape[-1])
+        if block_diffusion:
+            return fused_attention(q, k, v, q_block_size=q_block, kv_block_size=kv_block,
+                                   block_diffusion=BlockDiffusion(q.shape[1] // 2, block_diffusion))
         return fused_attention(q, k, v, causal_offset=0,
                                q_block_size=q_block, kv_block_size=kv_block)
     if impl != "xla":
         raise ValueError(f"attn_impl must be 'auto', 'pallas' or 'xla', got {impl!r}")
     t = q.shape[1]
+    if block_diffusion:
+        block = jax.checkpoint(_block_diffusion_block, static_argnums=(3, 4))
+        return jnp.concatenate([block(q[:, lo:lo + query_block], k, v, lo, block_diffusion)
+                                for lo in range(0, t, query_block)], axis=1)
     if t <= query_block or t % query_block:
         return _causal_block(q, k, v, 0)
     block = jax.checkpoint(_causal_block, static_argnums=(3,))

@@ -57,6 +57,32 @@ def causal_mask(num_queries: int, num_keys: int, offset: int = 0) -> Array:
     return cols > rows + offset
 
 
+# -- the block-diffusion mask (BD3-LM, arXiv:2503.09573) ----------------------
+#
+# A row of L tokens is trained as 2L positions ``[x_t ; x]``: the noised copy,
+# then the clean one. Position p is noised iff p < L, its token is p mod L,
+# and its block is ``(p mod L) // block``. A noised query reads the noised
+# keys of its own block (both ways) and the clean keys of the blocks before
+# it; a clean query reads the clean keys of its own block and of those
+# before it (causal between blocks); no clean query reads a noised key.
+
+
+def block_diffusion_visible(rows: Array, cols: Array, half: int, block: int) -> Array:
+    """bool, True where query ``rows`` may read key ``cols`` (positions of
+    ``[x_t ; x]``, broadcast against each other) under the block-diffusion
+    mask of two halves of ``half`` and blocks of ``block`` positions, a power
+    of two. Only comparisons, shifts and logical operations: the attention
+    kernels compute it in-kernel (``ops/pallas_attention.py``) and the
+    blocked XLA path from the same function."""
+    shift = block.bit_length() - 1
+    q_clean, k_clean = rows >= half, cols >= half
+    q_block = jnp.where(q_clean, rows - half, rows) >> shift
+    k_block = jnp.where(k_clean, cols - half, cols) >> shift
+    return ((q_clean & k_clean & (k_block <= q_block))
+            | (~q_clean & k_clean & (k_block < q_block))
+            | (~q_clean & ~k_clean & (k_block == q_block)))
+
+
 def combine_attention_masks(
     pad_mask: Optional[Array],
     attn_mask: Optional[Array],

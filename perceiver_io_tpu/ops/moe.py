@@ -3,7 +3,9 @@
 The layer routes every token over ALL ``num_experts`` experts (sigmoid
 scores in float32, the ``top_k`` largest of ``score + selection bias``, gates
 ``scale * score / sum of the selected scores``: the aux-loss-free "noaux_tc"
-router of the DeepSeek-V3 family) and computes the terms of the experts
+router of the DeepSeek-V3 family; or, with ``scoring_func`` 'softmax', the
+Qwen3-MoE form the SDAR family keeps: a softmax over all experts in float32,
+the ``top_k`` largest scores with no bias, the same gates) and computes the terms of the experts
 ``[expert_offset, expert_offset + experts_held)`` only. With all experts held
 it is the whole layer; with a share it is what one expert-parallel rank
 computes, and what the absent experts would add is left out. Nothing stands
@@ -93,12 +95,16 @@ class Routing(NamedTuple):
     gates: Array    # (N, top_k) float32: their weights, normalised over ALL selected
 
 
-def route(scores: Array, selection_bias: Array, top_k: int, scale: float,
+def route(scores: Array, selection_bias: Optional[Array], top_k: int, scale: float,
           normalize: bool = True, gate_eps: float = 1e-20) -> Routing:
     """``scores`` (N, E) float32 in (0, 1). The bias decides the selection
-    and nothing else: it carries no gradient and is not in the gates.
-    ``gate_eps`` is what the family adds to the selected scores' sum."""
-    _, experts = jax.lax.top_k(scores + jax.lax.stop_gradient(selection_bias), top_k)
+    and nothing else: it carries no gradient and is not in the gates (None:
+    the scores alone select). ``gate_eps`` is what the family adds to the
+    selected scores' sum."""
+    biased = scores
+    if selection_bias is not None:
+        biased = scores + jax.lax.stop_gradient(selection_bias)
+    _, experts = jax.lax.top_k(biased, top_k)
     picked = jnp.take_along_axis(scores, experts, axis=-1)
     if normalize:
         picked = picked / (jnp.sum(picked, axis=-1, keepdims=True) + gate_eps)
@@ -366,6 +372,7 @@ class MoELayer(nn.Module):
     norm_topk_prob: bool = True
     gate_eps: float = 1e-20  # added to the selected scores' sum (1e-6 in the LFM2 family)
     expert_bias_buffer: bool = False  # the selection bias is an ``ExpertBias`` (the LFM2 family)
+    scoring_func: str = "sigmoid"  # or 'softmax' over all experts, with no selection bias
     experts_held: Optional[int] = None
     expert_offset: int = 0
     tile_rows: int = TILE_ROWS
@@ -386,13 +393,16 @@ class MoELayer(nn.Module):
 
         with jax.named_scope("moe/router"):
             router = Kernel((d, self.num_experts), name="router")()
-            if self.expert_bias_buffer:
+            if self.scoring_func == "softmax":
+                bias = None
+            elif self.expert_bias_buffer:
                 bias = ExpertBias(self.num_experts, name="expert_bias")()
             else:
                 bias = self.param("e_score_correction_bias", nn.initializers.zeros,
                                   (self.num_experts,))
-            scores = jax.nn.sigmoid(jnp.dot(
-                x.astype(jnp.float32), router, precision=jax.lax.Precision.HIGHEST))
+            logits = jnp.dot(x.astype(jnp.float32), router, precision=jax.lax.Precision.HIGHEST)
+            scores = (jax.nn.softmax(logits, axis=-1) if self.scoring_func == "softmax"
+                      else jax.nn.sigmoid(logits))
             routing = route(scores, bias, top_k, self.routed_scaling_factor,
                             self.norm_topk_prob, self.gate_eps)
 

@@ -52,13 +52,16 @@ dropout, optional key padding mask only.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from perceiver_io_tpu.ops import masking
 
 Array = jax.Array
 
@@ -240,9 +243,112 @@ def _first_visible_q(s_idx, t_blk: int, s_blk: int, offset: int):
     return jnp.maximum(s_idx * s_blk - offset, 0) // t_blk
 
 
+# -- the block-diffusion mask's tiles ----------------------------------------
+#
+# Under ``ops.masking.block_diffusion_visible`` what a tile of queries reads is
+# TWO ranges of keys, and what a tile of keys is read by two ranges of
+# queries: the noised part, then the clean part, in ascending order. A grid
+# axis walks the blocks of the first range and then those of the second, and
+# holds its last block where its tile has fewer than the axis's length (an
+# unchanged block index is not copied again, and the step computes nothing).
+# The functions take ``xp``: ``jnp`` on the grid's scalar indices, ``np`` on
+# every index at once for the axis's static length.
+_NONE = 2**30  # the low end of an empty range
+
+
+class BlockDiffusion(NamedTuple):
+    """The mask of a row ``[x_t ; x]`` of two halves of ``half`` positions,
+    in blocks of ``block`` (a power of two)."""
+    half: int
+    block: int
+
+
+def _bd_keys_read(t_idx, t_blk: int, bd: BlockDiffusion, xp):
+    """The keys query tile ``t_idx`` reads: ``(noised lo, hi, clean lo,
+    hi)``, positions of ``[x_t ; x]``. Query rows past the row (padding) read
+    as clean rows of the last block."""
+    half, blk = bd
+    first, last = t_idx * t_blk, t_idx * t_blk + t_blk - 1
+    noised, clean = first < half, last >= half
+    last_noised = xp.minimum(last, half - 1) // blk * blk  # the first key of its block
+    last_clean = xp.minimum(xp.clip(last - half, 0, half - 1) // blk * blk + blk, half)
+    clean_hi = xp.maximum(xp.where(noised, last_noised, 0), xp.where(clean, last_clean, 0))
+    return (xp.where(noised, first // blk * blk, 0),
+            xp.where(noised, xp.minimum(last_noised + blk, half), 0), half, half + clean_hi)
+
+
+def _bd_queries_reading(s_idx, s_blk: int, rows: int, bd: BlockDiffusion, xp):
+    """The queries that read key tile ``s_idx``: ``(noised lo, hi, clean lo,
+    hi)``, positions of ``[x_t ; x]`` (``rows``: the query axis, padding
+    included). Key columns past the row (padding) are read by none."""
+    half, blk = bd
+    first, last = s_idx * s_blk, s_idx * s_blk + s_blk - 1
+    noised, clean = first < half, (last >= half) & (first < 2 * half)
+    # noised keys: the noised queries of their blocks
+    own_lo = first // blk * blk
+    own_hi = xp.minimum(xp.minimum(last, half - 1) // blk * blk + blk, half)
+    # clean keys: the noised queries of later blocks, the clean ones of theirs and later
+    first_clean = xp.maximum(first - half, 0) // blk * blk
+    later = clean & (first_clean + blk < half)
+    lo = xp.minimum(xp.where(noised, own_lo, _NONE), xp.where(later, first_clean + blk, _NONE))
+    hi = xp.maximum(xp.where(noised, own_hi, 0), xp.where(later, half, 0))
+    return (xp.where(lo < _NONE, lo, 0), hi, xp.where(clean, half + first_clean, 0),
+            xp.where(clean, rows, 0))
+
+
+def _bd_blocks(ranges, size: int, xp):
+    """Two ascending position ranges -> ``(first, count)`` of blocks of
+    ``size`` each, the second range's blocks starting past the first's."""
+    lo_a, hi_a, lo_b, hi_b = ranges
+    count_a = xp.where(hi_a > lo_a, (hi_a - 1) // size - lo_a // size + 1, 0)
+    first_b = xp.where(count_a > 0, xp.maximum(lo_b // size, lo_a // size + count_a), lo_b // size)
+    count_b = xp.where(hi_b > lo_b, xp.maximum((hi_b - 1) // size - first_b + 1, 0), 0)
+    return lo_a // size, count_a, first_b, count_b
+
+
+def _bd_step(blocks, step):
+    """``(block index, visible)`` of grid step ``step`` along the two ranges."""
+    first_a, count_a, first_b, count_b = blocks
+    last = jnp.maximum(jnp.where(count_b > 0, first_b + count_b, first_a + count_a) - 1, 0)
+    index = jnp.where(step < count_a, first_a + step, first_b + step - count_a)
+    visible = step < count_a + count_b
+    return jnp.where(visible, index, last), visible
+
+
+def _bd_kv_step(t_idx, step, t_blk: int, s_blk: int, bd: BlockDiffusion):
+    return _bd_step(_bd_blocks(_bd_keys_read(t_idx, t_blk, bd, jnp), s_blk, jnp), step)
+
+
+def _bd_q_step(s_idx, step, t_blk: int, s_blk: int, rows: int, bd: BlockDiffusion):
+    return _bd_step(_bd_blocks(_bd_queries_reading(s_idx, s_blk, rows, bd, jnp), t_blk, jnp), step)
+
+
+def _bd_steps(tiles: int, ranges_of, size: int) -> int:
+    """A grid axis's static length: the most blocks any tile visits."""
+    _, count_a, _, count_b = _bd_blocks(ranges_of(np.arange(tiles)), size, np)
+    return max((count_a + count_b).tolist())
+
+
+def _bd_kv_steps(t: int, t_blk: int, s_blk: int, bd: BlockDiffusion) -> int:
+    return _bd_steps(t // t_blk, lambda i: _bd_keys_read(i, t_blk, bd, np), s_blk)
+
+
+def _bd_q_steps(s: int, s_blk: int, t: int, t_blk: int, bd: BlockDiffusion) -> int:
+    return _bd_steps(s // s_blk, lambda i: _bd_queries_reading(i, s_blk, t, bd, np), t_blk)
+
+
+def _bd_bias(t_blk: int, s_blk: int, t_idx, s_idx, bd: BlockDiffusion):
+    """(T_blk, S_blk) additive bias of the block-diffusion mask for query
+    tile ``t_idx`` against key block ``s_idx`` (global indices)."""
+    rows = t_idx * t_blk + jax.lax.broadcasted_iota(jnp.int32, (t_blk, s_blk), 0)
+    cols = s_idx * s_blk + jax.lax.broadcasted_iota(jnp.int32, (t_blk, s_blk), 1)
+    return jnp.where(masking.block_diffusion_visible(rows, cols, *bd), 0.0, MASK_VALUE)
+
+
 def _attention_kernel(bias_ref, q_ref, k_ref, v_ref, out_ref, *rest,
                       scale: float, with_lse: bool,
-                      causal_offset: Optional[int], skip_masked: bool):
+                      causal_offset: Optional[int], skip_masked: bool,
+                      block_diffusion: Optional[BlockDiffusion] = None):
     if with_lse:
         m_out, l_out, m_ref, l_ref, acc_ref = rest
         lse_ref = (m_out, l_out)
@@ -251,6 +357,9 @@ def _attention_kernel(bias_ref, q_ref, k_ref, v_ref, out_ref, *rest,
     # read outside the conditional tile body (interpret mode cannot lower a
     # program_id inside a cond)
     t_idx, s_idx = pl.program_id(2), pl.program_id(3)
+    if block_diffusion is not None:  # the grid's KV step -> the key block it reads
+        kv_idx, visible = _bd_kv_step(t_idx, s_idx, q_ref.shape[2], k_ref.shape[2],
+                                      block_diffusion)
 
     @pl.when(s_idx == 0)
     def _init():
@@ -266,6 +375,8 @@ def _attention_kernel(bias_ref, q_ref, k_ref, v_ref, out_ref, *rest,
         if causal_offset is not None:
             logits += _causal_bias(q.shape[0], k.shape[0], t_idx, s_idx,
                                    causal_offset)
+        if block_diffusion is not None:
+            logits += _bd_bias(q.shape[0], k.shape[0], t_idx, kv_idx, block_diffusion)
 
         m_prev = m_ref[:, :1]  # (T_blk, 1)
         l_prev = l_ref[:, :1]
@@ -280,7 +391,9 @@ def _attention_kernel(bias_ref, q_ref, k_ref, v_ref, out_ref, *rest,
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    if skip_masked:
+    if block_diffusion is not None:
+        pl.when(visible)(_tile)
+    elif skip_masked:
         pl.when(_tile_visible(t_idx, s_idx, q_ref.shape[2],
                               k_ref.shape[2], causal_offset))(_tile)
     else:
@@ -298,12 +411,13 @@ def _attention_kernel(bias_ref, q_ref, k_ref, v_ref, out_ref, *rest,
 @functools.partial(
     jax.jit,
     static_argnames=("t_blk", "s_blk", "interpret", "with_lse",
-                     "causal_offset", "skip_masked"),
+                     "causal_offset", "skip_masked", "block_diffusion"),
 )
 def _fused_attention_fwd_impl(
     q: Array, k: Array, v: Array, bias: Array,
     t_blk: int, s_blk: int, interpret: bool, with_lse: bool = False,
     causal_offset: Optional[int] = None, skip_masked: bool = False,
+    block_diffusion: Optional[BlockDiffusion] = None,
 ):
     """(B, H, T, D) q against (B, Hkv, S, D) k and (B, Hkv, S, Dv) v with (B,
     S) additive bias; the output is (B, H, T, Dv). Query head ``h`` reads
@@ -317,7 +431,10 @@ def _fused_attention_fwd_impl(
     still recomputes exactly as exp(logits − m)/l.
     ``skip_masked``: tiles that the causal rule masks whole are neither
     computed nor fetched (the KV index stays at the last visible block, and
-    a block whose index does not change is not copied again)."""
+    a block whose index does not change is not copied again).
+    ``block_diffusion``: the block-diffusion mask in place of the causal
+    rule; the KV axis walks the key blocks each query tile reads (its noised
+    and its clean range) and no other."""
     b, h, t, d = q.shape
     s, dv = k.shape[2], v.shape[3]
     scale = d**-0.5
@@ -326,6 +443,11 @@ def _fused_attention_fwd_impl(
 
     kv_block = functools.partial(_kv_block_index, t_blk=t_blk, s_blk=s_blk,
                                  offset=causal_offset, skip_masked=skip_masked)
+    if block_diffusion is not None:
+        grid = (b, h, t // t_blk, _bd_kv_steps(t, t_blk, s_blk, block_diffusion))
+
+        def kv_block(ti, si):
+            return _bd_kv_step(ti, si, t_blk, s_blk, block_diffusion)[0]
 
     out_shape = jax.ShapeDtypeStruct((b, h, t, dv), q.dtype)
     out_specs = pl.BlockSpec((1, 1, t_blk, dv), lambda bi, hi, ti, si: (bi, hi, ti, 0))
@@ -339,7 +461,8 @@ def _fused_attention_fwd_impl(
     bias = bias[:, None, :]  # (B, 1, S)
     kernel = pl.pallas_call(
         functools.partial(_attention_kernel, scale=scale, with_lse=with_lse,
-                          causal_offset=causal_offset, skip_masked=skip_masked),
+                          causal_offset=causal_offset, skip_masked=skip_masked,
+                          block_diffusion=block_diffusion),
         grid=grid,
         in_specs=[
             # (B, 1, S) so the block's trailing dims satisfy TPU tiling
@@ -372,7 +495,7 @@ def _fused_attention_fwd_impl(
 def _recompute_probs_and_ds(bias_ref, q_ref, k_ref, v_ref, g_ref,
                             m_ref, l_ref, di_ref, *, scale: float,
                             causal_offset: Optional[int],
-                            t_idx, s_idx):
+                            t_idx, s_idx, block_diffusion=None):
     """Shared backward tile math: recompute p = exp(logits − m)/l for this
     (T_blk, S_blk) tile and the softmax gradient ds = p·(dp − delta).
 
@@ -382,7 +505,9 @@ def _recompute_probs_and_ds(bias_ref, q_ref, k_ref, v_ref, g_ref,
     ``causal_offset`` the tile recomputes the same in-kernel causal bias the
     forward applied (``t_idx``/``s_idx`` are the GLOBAL query/key block
     indices — the two backward kernels run swapped grids, so the caller
-    passes whichever program_id carries each axis)."""
+    passes whichever program_id carries each axis). With a
+    ``block_diffusion`` mask the same: ``t_idx``/``s_idx`` are the blocks the
+    grid step reads."""
     q = q_ref[0, 0]  # (T_blk, D)
     k = k_ref[0, 0]  # (S_blk, D)
     g = g_ref[0, 0]  # (T_blk, Dv)
@@ -391,6 +516,8 @@ def _recompute_probs_and_ds(bias_ref, q_ref, k_ref, v_ref, g_ref,
     if causal_offset is not None:
         logits += _causal_bias(q.shape[0], k.shape[0], t_idx, s_idx,
                                causal_offset)
+    if block_diffusion is not None:
+        logits += _bd_bias(q.shape[0], k.shape[0], t_idx, s_idx, block_diffusion)
     m = m_ref[0, 0][:, :1]  # (T_blk, 1)
     l = l_ref[0, 0][:, :1]
     p = jnp.exp(logits - m) / l
@@ -402,8 +529,13 @@ def _recompute_probs_and_ds(bias_ref, q_ref, k_ref, v_ref, g_ref,
 
 def _bwd_dq_kernel(bias_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, di_ref,
                    dq_ref, acc_ref, *, scale: float,
-                   causal_offset: Optional[int], skip_masked: bool):
+                   causal_offset: Optional[int], skip_masked: bool,
+                   block_diffusion: Optional[BlockDiffusion] = None):
     t_idx, s_idx = pl.program_id(2), pl.program_id(3)
+    kv_idx = s_idx
+    if block_diffusion is not None:  # the grid's KV step -> the key block it reads
+        kv_idx, visible = _bd_kv_step(t_idx, s_idx, q_ref.shape[2], k_ref.shape[2],
+                                      block_diffusion)
 
     @pl.when(s_idx == 0)
     def _init():
@@ -413,11 +545,13 @@ def _bwd_dq_kernel(bias_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, di_ref,
         _, ds, _, k, _ = _recompute_probs_and_ds(
             bias_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, di_ref,
             scale=scale, causal_offset=causal_offset,
-            t_idx=t_idx, s_idx=s_idx,
+            t_idx=t_idx, s_idx=kv_idx, block_diffusion=block_diffusion,
         )
         acc_ref[:] += _dot(ds.astype(k.dtype), k, (1, 0))  # (T_blk, D)
 
-    if skip_masked:
+    if block_diffusion is not None:
+        pl.when(visible)(_tile)
+    elif skip_masked:
         pl.when(_tile_visible(t_idx, s_idx, q_ref.shape[2],
                               k_ref.shape[2], causal_offset))(_tile)
     else:
@@ -431,12 +565,18 @@ def _bwd_dq_kernel(bias_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, di_ref,
 def _bwd_dkv_kernel(bias_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, di_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
                     causal_offset: Optional[int], skip_masked: bool,
-                    group: int, q_blocks: int):
+                    group: int, q_blocks: int,
+                    block_diffusion: Optional[BlockDiffusion] = None, rows: int = 0):
     # the sequential axis runs over the query blocks of every query head of
     # this key/value head's group, one head after the other: dk and dv are
     # sums over all of them, and the accumulators live across the whole axis
+    # (with a block-diffusion mask ``q_blocks`` steps a head, walking the
+    # query blocks of the ``rows`` queries that read this key block)
     s_idx, step = pl.program_id(2), pl.program_id(3)
     t_idx = step if group == 1 else step % q_blocks
+    if block_diffusion is not None:
+        t_idx, visible = _bd_q_step(s_idx, t_idx, q_ref.shape[2], k_ref.shape[2], rows,
+                                    block_diffusion)
 
     @pl.when(step == 0)
     def _init():
@@ -447,13 +587,15 @@ def _bwd_dkv_kernel(bias_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, di_ref,
         p, ds, q, _, g = _recompute_probs_and_ds(
             bias_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, di_ref,
             scale=scale, causal_offset=causal_offset,
-            t_idx=t_idx, s_idx=s_idx,
+            t_idx=t_idx, s_idx=s_idx, block_diffusion=block_diffusion,
         )
         # contract the query axis: (T_blk, S_blk)ᵀ·(T_blk, D) → (S_blk, D)
         dv_acc[:] += _dot(p.astype(g.dtype), g, (0, 0))
         dk_acc[:] += _dot(ds.astype(q.dtype), q, (0, 0))
 
-    if skip_masked:
+    if block_diffusion is not None:
+        pl.when(visible)(_tile)
+    elif skip_masked:
         pl.when(_tile_visible(t_idx, s_idx, q_ref.shape[2],
                               k_ref.shape[2], causal_offset))(_tile)
     else:
@@ -468,13 +610,14 @@ def _bwd_dkv_kernel(bias_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, di_ref,
 @functools.partial(
     jax.jit,
     static_argnames=("t_blk", "s_blk", "interpret", "causal_offset",
-                     "skip_masked"),
+                     "skip_masked", "block_diffusion"),
 )
 def _fused_attention_bwd_impl(
     q: Array, k: Array, v: Array, bias: Array, out: Array,
     m: Array, l: Array,
     g: Array, t_blk: int, s_blk: int, interpret: bool,
     causal_offset: Optional[int] = None, skip_masked: bool = False,
+    block_diffusion: Optional[BlockDiffusion] = None,
 ):
     b, h, t, d = q.shape
     s, dv = k.shape[2], v.shape[3]
@@ -489,6 +632,13 @@ def _fused_attention_bwd_impl(
 
     kv_block = functools.partial(_kv_block_index, t_blk=t_blk, s_blk=s_blk,
                                  offset=causal_offset, skip_masked=skip_masked)
+    kv_steps, q_blocks = s // s_blk, t // t_blk
+    if block_diffusion is not None:  # the grids walk the blocks the mask leaves
+        kv_steps = _bd_kv_steps(t, t_blk, s_blk, block_diffusion)
+        q_blocks = _bd_q_steps(s, s_blk, t, t_blk, block_diffusion)
+
+        def kv_block(ti, si):
+            return _bd_kv_step(ti, si, t_blk, s_blk, block_diffusion)[0]
 
     bias = bias[:, None, :]  # (B, 1, S)
     q_spec = pl.BlockSpec((1, 1, t_blk, d), lambda bi, hi, ti, si: (bi, hi, ti, 0))
@@ -504,8 +654,9 @@ def _fused_attention_bwd_impl(
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale,
-                          causal_offset=causal_offset, skip_masked=skip_masked),
-        grid=(b, h, t // t_blk, s // s_blk),  # KV axis sequential
+                          causal_offset=causal_offset, skip_masked=skip_masked,
+                          block_diffusion=block_diffusion),
+        grid=(b, h, t // t_blk, kv_steps),  # KV axis sequential
         in_specs=[bias_spec, q_spec, k_spec, v_spec, g_spec,
                   lm_spec, lm_spec, lm_spec],
         out_specs=q_spec,
@@ -521,13 +672,14 @@ def _fused_attention_bwd_impl(
     # dkv grid: one key/value head a grid row, and innermost (sequential) the
     # query blocks of each query head of its group in turn; the same index
     # maps apply, with the query block and head read from that one position
-    q_blocks = t // t_blk
 
     def q_head(hi, step):
         return hi if group == 1 else hi * group + step // q_blocks
 
     def q_block(si, step):
         ti = step if group == 1 else step % q_blocks
+        if block_diffusion is not None:
+            return _bd_q_step(si, ti, t_blk, s_blk, t, block_diffusion)[0]
         if not skip_masked:
             return ti
         # a KV block past every row's reach has no visible query block: its
@@ -548,7 +700,8 @@ def _fused_attention_bwd_impl(
     dk, dv_out = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale,
                           causal_offset=causal_offset, skip_masked=skip_masked,
-                          group=group, q_blocks=q_blocks),
+                          group=group, q_blocks=q_blocks,
+                          block_diffusion=block_diffusion, rows=t),
         grid=(b, kv_heads, s // s_blk, group * q_blocks),  # query axis sequential
         in_specs=[bias_spec2, q_spec2, k_spec2, v_spec2, g_spec2,
                   lm_spec2, lm_spec2, lm_spec2],
@@ -566,18 +719,21 @@ def _fused_attention_bwd_impl(
     return dq, dk, dv_out
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _fused_attention(q, k, v, bias, t_blk, s_blk, interpret, causal_offset,
-                     skip_masked=False):
+                     skip_masked=False, block_diffusion=None):
     return _fused_attention_fwd_impl(q, k, v, bias, t_blk, s_blk, interpret,
                                      causal_offset=causal_offset,
-                                     skip_masked=skip_masked)
+                                     skip_masked=skip_masked,
+                                     block_diffusion=block_diffusion)
 
 
-def _fwd(q, k, v, bias, t_blk, s_blk, interpret, causal_offset, skip_masked):
+def _fwd(q, k, v, bias, t_blk, s_blk, interpret, causal_offset, skip_masked,
+         block_diffusion):
     out, m, l = _fused_attention_fwd_impl(
         q, k, v, bias, t_blk, s_blk, interpret, with_lse=True,
         causal_offset=causal_offset, skip_masked=skip_masked,
+        block_diffusion=block_diffusion,
     )
     # the primal output is the named one too: whatever reads it in a
     # recomputation then reads the kept copy and needs no kernel
@@ -587,12 +743,14 @@ def _fwd(q, k, v, bias, t_blk, s_blk, interpret, causal_offset, skip_masked):
     return out, (q, k, v, bias, out, m1, l1)
 
 
-def _bwd(t_blk, s_blk, interpret, causal_offset, skip_masked, residuals, g):
+def _bwd(t_blk, s_blk, interpret, causal_offset, skip_masked, block_diffusion,
+         residuals, g):
     q, k, v, bias, out, m1, l1 = residuals
     m, l = (jnp.broadcast_to(x[..., None], x.shape + (_LANES,)) for x in (m1, l1))
     dq, dk, dv = _fused_attention_bwd_impl(
         q, k, v, bias, out, m, l, g, t_blk, s_blk, interpret,
         causal_offset=causal_offset, skip_masked=skip_masked,
+        block_diffusion=block_diffusion,
     )
     return dq, dk, dv, jnp.zeros_like(bias)
 
@@ -669,6 +827,7 @@ def fused_attention(
     q_block_size: Optional[int] = None,
     interpret: Optional[bool] = None,
     causal_offset: Optional[int] = None,
+    block_diffusion: Optional[BlockDiffusion] = None,
 ) -> Array:
     """Fused multi-head attention over (B, T, H, D) q, (B, S, Hkv, D) k and
     (B, S, Hkv, Dv) v; the output is (B, T, H, Dv). Dv may differ from D (a
@@ -703,6 +862,15 @@ def fused_attention(
     exp(-1e30 - m) = 0. With a pad mask a row may see padding only, and then
     owes its uniform softmax to the masked tiles too; under a negative offset
     the first rows see no key at all: both keep every tile.
+
+    ``block_diffusion``: the block-diffusion mask of a row ``[x_t ; x]``
+    (``ops.masking.block_diffusion_visible``) in place of the causal rule,
+    without a ``pad_mask``; T = S = 2 x ``half``. Computed in-kernel from
+    row and column indices, forward and backward, and the tiles it hides
+    whole are neither fetched nor computed: a noised query tile reads its own
+    noised block and the clean prefix before it, a clean one the clean prefix
+    up to its end (about L^2 of the 4 L^2 pairs are visible). Exact for the
+    same reason as the causal skipping: every row reads itself.
     """
     if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
         raise ValueError(f"expected (B, T/S, H, D) tensors, got {q.shape=} {k.shape=}")
@@ -714,6 +882,12 @@ def fused_attention(
     if h % k.shape[2] or v.shape[2] != k.shape[2]:
         raise ValueError(f"{h} query heads cannot share {k.shape[2]} key and "
                          f"{v.shape[2]} value heads")
+    if block_diffusion is not None and (
+            pad_mask is not None or causal_offset is not None or t != s
+            or t != 2 * block_diffusion.half
+            or block_diffusion.block & (block_diffusion.block - 1)):
+        raise ValueError(f"the block-diffusion mask takes T = S = 2 x {block_diffusion.half} "
+                         "and blocks of a power of two, without a pad mask or a causal offset")
     if pad_mask is None:
         bias = jnp.zeros((b, s), jnp.float32)
     else:
@@ -726,7 +900,7 @@ def fused_attention(
     out = _fused_attention(
         q, k, v, bias, t_blk, s_blk, interpret,
         None if causal_offset is None else int(causal_offset),
-        skip_masked,
+        skip_masked, block_diffusion,
     )
     if t_pad:
         out = out[:, :, :t]

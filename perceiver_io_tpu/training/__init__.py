@@ -6,6 +6,7 @@ from perceiver_io_tpu.training.optim import OptimizerConfig, make_optimizer
 from perceiver_io_tpu.training.train_state import TrainState
 from perceiver_io_tpu.training.steps import (
     make_ar_steps,
+    make_block_diffusion_steps,
     make_lm_steps,
     make_mlm_steps,
     make_classifier_steps,
@@ -41,6 +42,7 @@ __all__ = [
     "make_optimizer",
     "TrainState",
     "make_ar_steps",
+    "make_block_diffusion_steps",
     "make_lm_steps",
     "make_mlm_steps",
     "mlm_gather_capacity",

@@ -302,6 +302,59 @@ def make_lm_steps(model, schedule: Optional[Schedule] = None):
     return (*_make_steps(loss_fn, (), schedule), predict_fn)
 
 
+# a block's noise level is one of these many steps of [1/NOISE_LEVELS, 1]: U[~1e-3,
+# 1] on a grid of powers of two, so that every draw and every comparison with
+# it is exact and any implementation of the same draws gets the same mask
+NOISE_LEVELS = 1024
+
+
+def block_diffusion_noise(key: Array, token_ids: Array, pad_mask: Array, block_length: int,
+                          mask_id: int) -> Tuple[Array, Array, Array]:
+    """``(x_t, weights, masked_pct)`` of a batch of (B, L) ids: each block of
+    ``block_length`` tokens draws a level ``t_b`` uniform on ``k /
+    NOISE_LEVELS``, k = 1..NOISE_LEVELS; each of its tokens that is not
+    padding becomes ``mask_id`` with probability ``t_b``. ``weights`` (float32)
+    is ``1 / t_b`` where a token was masked and 0 elsewhere; ``masked_pct`` the
+    masked share of the tokens that are not padding, in percent."""
+    rows, length = token_ids.shape
+    k_level, k_token = jax.random.split(key)
+    level = jax.random.randint(k_level, (rows, length // block_length), 1, NOISE_LEVELS + 1)
+    level = jnp.repeat(level.astype(jnp.float32) * (1.0 / NOISE_LEVELS), block_length, axis=1)
+    masked = (jax.random.uniform(k_token, (rows, length)) < level) & ~pad_mask
+    noisy = jnp.where(masked, jnp.asarray(mask_id, token_ids.dtype), token_ids)
+    weights = jnp.where(masked, 1.0 / level, 0.0)
+    real = jnp.maximum(jnp.sum(~pad_mask, dtype=jnp.float32), 1.0)
+    return noisy, weights, 100.0 * jnp.sum(masked, dtype=jnp.float32) / real
+
+
+def make_block_diffusion_steps(model, schedule: Optional[Schedule] = None):
+    """(train_step, eval_step, predict_fn) for a ``DecoderLM`` trained by
+    block diffusion (``objective`` 'block_diffusion'): batches as
+    :func:`make_lm_steps`'. Each step draws its blocks' noise levels and its
+    masked tokens on the device from its ``masking`` stream, under the
+    ``bd_noise`` scope (:func:`block_diffusion_noise`), and runs the model
+    over ``[x_t ; x]`` (``model.diffusion_loss``); the metrics are the loss,
+    ``bd_masked_pct`` and the model's own (the expert layers' statistics,
+    ``attention_residuals_kept_pct``). ``predict_fn(params, x_t, x)`` gives
+    the noised half's logits."""
+    c = model.config
+
+    def loss_fn(params, batch, rngs, deterministic):
+        ids = batch["token_ids"]
+        with jax.named_scope("bd_noise"):
+            noisy, weights, masked_pct = block_diffusion_noise(
+                rngs["masking"], ids, batch["pad_mask"], c.block_length, c.mask_token_id)
+        loss, metrics = model.apply({"params": params}, noisy, ids, weights,
+                                    method=model.diffusion_loss)
+        return loss, {**metrics, "bd_masked_pct": masked_pct}
+
+    def predict_fn(params, noisy_ids, token_ids):
+        return model.apply({"params": params}, noisy_ids, token_ids,
+                           method=model.diffusion_logits)
+
+    return (*_make_steps(loss_fn, ("masking",), schedule), predict_fn)
+
+
 def make_classifier_steps(
     model,
     schedule: Optional[Schedule] = None,

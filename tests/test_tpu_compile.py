@@ -60,12 +60,13 @@ def _sum_sq(fn, grad):
 
 
 def _attention(b, t, s, h, d, grad=False, causal_offset=None, dv=None,
-               blocks=(None, None), kv_heads=None):
-    from perceiver_io_tpu.ops.pallas_attention import fused_attention
+               blocks=(None, None), kv_heads=None, block_diffusion=None):
+    from perceiver_io_tpu.ops.pallas_attention import BlockDiffusion, fused_attention
 
     fn = functools.partial(
         fused_attention, causal_offset=causal_offset, interpret=False,
-        q_block_size=blocks[0], kv_block_size=blocks[1])
+        q_block_size=blocks[0], kv_block_size=blocks[1],
+        block_diffusion=block_diffusion and BlockDiffusion(t // 2, block_diffusion))
     qkv = [((b, t, h, d), jnp.bfloat16), ((b, s, kv_heads or h, d), jnp.bfloat16),
            ((b, s, kv_heads or h, dv or d), jnp.bfloat16)]
     return _sum_sq(fn, grad), qkv
@@ -185,6 +186,17 @@ CASES = {
         1, 8192, 8192, 32, 128, causal_offset=0, blocks=_gqa16_blocks(), kv_heads=2),
     "attn-gqa16-causal-grad": lambda: _attention(
         1, 8192, 8192, 32, 128, causal_offset=0, blocks=_gqa16_blocks(), kv_heads=2, grad=True),
+    # the sdar cell's: 8,192 tokens as the 16,384 positions [x_t ; x] under the
+    # block-diffusion mask with blocks of 4, 32 query heads over 4 of 128
+    "attn-sdar-block-diffusion-fwd": lambda: _attention(
+        1, 16384, 16384, 32, 128, blocks=_gqa16_blocks(), kv_heads=4, block_diffusion=4),
+    "attn-sdar-block-diffusion-grad": lambda: _attention(
+        1, 16384, 16384, 32, 128, blocks=_gqa16_blocks(), kv_heads=4, block_diffusion=4,
+        grad=True),
+    "gmm-sdar-experts-gate-grad": lambda: _grouped_matmul(
+        16384, 8, 16, 2048, 768, grad=True, of_experts=128),
+    "gmm-sdar-experts-down-grad-worst-case": lambda: _grouped_matmul(
+        16384, 8, 16, 768, 2048, grad=True),
     "gmm-nemotron-experts-up-grad": lambda: _grouped_matmul(
         8192, 6, 8, 2688, 1856, grad=True, of_experts=128),
     "gmm-nemotron-experts-down-grad": lambda: _grouped_matmul(
