@@ -53,7 +53,7 @@ from perceiver_io_tpu.models.decoder_lm import (
     DecoderLM,
     DecoderLMConfig,
 )
-from perceiver_io_tpu.ops import mamba2, moe
+from perceiver_io_tpu.ops import latent_attention, mamba2, moe
 from perceiver_io_tpu.training import TrainState, make_block_diffusion_steps, make_lm_steps
 from perceiver_io_tpu.training.trainer import Trainer
 
@@ -163,6 +163,11 @@ def build_model(args, vocab_size: int) -> DecoderLM:
     scan_kernel = scans and mamba2.scan_impl(
         config.mamba_num_heads, config.mamba_head_dim, config.n_groups,
         config.ssm_state_size, config.chunk_size, args.max_seq_len) == "pallas"
+    # the share of the block-diffusion kernels' tiles that take no mask, by the
+    # classes they branch on (0 on the XLA path: no kernel runs there)
+    unmasked_tiles = latent_attention.block_diffusion_unmasked_tiles_pct(
+        2 * args.max_seq_len, config.head_dim, config.block_length,
+        args.attn_impl) if diffusion else 0.0
     obs.event("lm.layers", mixers=list(config.mixers),
               one_sublayer_blocks=config.one_sublayer_blocks,
               dense_layers=config.first_k_dense_replace,
@@ -176,7 +181,8 @@ def build_model(args, vocab_size: int) -> DecoderLM:
                          config.ssm_state_size] if scans else [],
               ssd_scan_kernel=scan_kernel,
               tied_head=config.tie_word_embeddings,
-              objective=config.objective, block_length=config.block_length)
+              objective=config.objective, block_length=config.block_length,
+              bd_unmasked_tiles_pct=unmasked_tiles)
     # float32 bytes of the states that a Mamba-2 layer's backward pass holds for
     # one row: one a chunk, not one a token (0 without such a layer)
     obs.get_registry().gauge("ssd_state_bytes").set(mamba2.state_bytes(
@@ -184,6 +190,8 @@ def build_model(args, vocab_size: int) -> DecoderLM:
         config.ssm_state_size) if scans else 0)
     if scans:  # a constant of the traced program: 100 the kernel pair, 0 the einsums
         obs.get_registry().gauge("ssd_scan_kernel_pct").set(100.0 if scan_kernel else 0.0)
+    if diffusion:  # a constant of the traced program
+        obs.get_registry().gauge("bd_unmasked_tiles_pct").set(unmasked_tiles)
     # the model rematerialises every block (``--remat`` is the Perceiver
     # encoders' switch) and the experts' path follows the backend
     return DecoderLM(config, attn_impl=args.attn_impl, dtype=common.DTYPES[args.dtype])

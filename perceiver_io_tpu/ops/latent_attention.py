@@ -125,6 +125,20 @@ def resolve_causal_impl(impl: str) -> str:
     return impl
 
 
+def block_diffusion_unmasked_tiles_pct(t: int, depth: int, block: int, impl: str = "auto") -> float:
+    """Share, in %, of the tiles that ``causal_attention`` visits over T = ``t``
+    positions of ``[x_t ; x]``, scores ``depth`` deep, under the mask of
+    blocks of ``block``, that run with no mask: 0 on the blocked XLA path,
+    which runs no kernel."""
+    if resolve_causal_impl(impl) != "pallas":
+        return 0.0
+    from perceiver_io_tpu.ops.pallas_attention import (BlockDiffusion,
+                                                       block_diffusion_unmasked_tiles_pct as pct)
+
+    q_block, kv_block = pallas_blocks(depth)
+    return pct(t, depth, BlockDiffusion(t // 2, block), kv_block_size=kv_block, q_block_size=q_block)
+
+
 def causal_attention(q: Array, k: Array, v: Array, impl: str = "auto",
                      query_block: int = XLA_QUERY_BLOCK, block_diffusion: int = 0) -> Array:
     """Causal self-attention of (B, T, H, D) q with (B, T, Hkv, D) k and (B,

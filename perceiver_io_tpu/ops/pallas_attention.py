@@ -52,6 +52,7 @@ dropout, optional key padding mask only.
 from __future__ import annotations
 
 import functools
+import operator
 from typing import NamedTuple, Optional
 
 import jax
@@ -253,7 +254,17 @@ def _first_visible_q(s_idx, t_blk: int, s_blk: int, offset: int):
 # unchanged block index is not copied again, and the step computes nothing).
 # The functions take ``xp``: ``jnp`` on the grid's scalar indices, ``np`` on
 # every index at once for the axis's static length.
+#
+# What a visited tile pays for the mask follows its class, decided once a
+# grid step from the step's scalar indices (``_bd_tile_class``): a WHOLE tile,
+# every pair of it visible (most of the clean prefix a query tile reads), adds
+# no mask; a tile inside one quadrant of ``[x_t ; x]`` x ``[x_t ; x]`` that
+# the mask cuts (CUT_NN noised queries against noised keys, CUT_NC noised
+# against clean, CUT_CC clean against clean) adds one compare of shifted row
+# and column indices; a MIXED tile, across the two halves or over padding,
+# adds ``masking.block_diffusion_visible`` as a whole.
 _NONE = 2**30  # the low end of an empty range
+WHOLE, CUT_NN, CUT_NC, CUT_CC, MIXED = range(5)
 
 
 class BlockDiffusion(NamedTuple):
@@ -275,6 +286,28 @@ def _bd_keys_read(t_idx, t_blk: int, bd: BlockDiffusion, xp):
     clean_hi = xp.maximum(xp.where(noised, last_noised, 0), xp.where(clean, last_clean, 0))
     return (xp.where(noised, first // blk * blk, 0),
             xp.where(noised, xp.minimum(last_noised + blk, half), 0), half, half + clean_hi)
+
+
+def _bd_tile_class(t_idx, s_idx, t_blk: int, s_blk: int, bd: BlockDiffusion, xp):
+    """The class of the tile of query tile ``t_idx`` against key tile
+    ``s_idx`` (global indices): WHOLE, CUT_NN, CUT_NC, CUT_CC or MIXED. A
+    tile of clean queries against noised keys, which no grid visits, is
+    MIXED."""
+    half, blk = bd
+    shift = blk.bit_length() - 1
+    r0, r1 = t_idx * t_blk, t_idx * t_blk + t_blk - 1
+    c0, c1 = s_idx * s_blk, s_idx * s_blk + s_blk - 1
+    mixed = (((r0 < half) & (r1 >= half)) | ((c0 < half) & (c1 >= half))
+             | (r1 >= 2 * half) | (c1 >= 2 * half))
+    # whole where its least visible pair is: the last key against the first query
+    one_block = (((r0 >> shift) == (r1 >> shift)) & ((c0 >> shift) == (c1 >> shift))
+                 & ((r0 >> shift) == (c0 >> shift)))
+    nn = xp.where(one_block, WHOLE, CUT_NN)
+    nc = xp.where(((c1 - half) >> shift) < (r0 >> shift), WHOLE, CUT_NC)
+    cc = xp.where(((c1 - half) >> shift) <= ((r0 - half) >> shift), WHOLE, CUT_CC)
+    clean_keys = c0 >= half
+    kind = xp.where(r0 >= half, xp.where(clean_keys, cc, MIXED), xp.where(clean_keys, nc, nn))
+    return xp.where(mixed, MIXED, kind)
 
 
 def _bd_queries_reading(s_idx, s_blk: int, rows: int, bd: BlockDiffusion, xp):
@@ -306,13 +339,13 @@ def _bd_blocks(ranges, size: int, xp):
     return lo_a // size, count_a, first_b, count_b
 
 
-def _bd_step(blocks, step):
+def _bd_step(blocks, step, xp=jnp):
     """``(block index, visible)`` of grid step ``step`` along the two ranges."""
     first_a, count_a, first_b, count_b = blocks
-    last = jnp.maximum(jnp.where(count_b > 0, first_b + count_b, first_a + count_a) - 1, 0)
-    index = jnp.where(step < count_a, first_a + step, first_b + step - count_a)
+    last = xp.maximum(xp.where(count_b > 0, first_b + count_b, first_a + count_a) - 1, 0)
+    index = xp.where(step < count_a, first_a + step, first_b + step - count_a)
     visible = step < count_a + count_b
-    return jnp.where(visible, index, last), visible
+    return xp.where(visible, index, last), visible
 
 
 def _bd_kv_step(t_idx, step, t_blk: int, s_blk: int, bd: BlockDiffusion):
@@ -337,6 +370,21 @@ def _bd_q_steps(s: int, s_blk: int, t: int, t_blk: int, bd: BlockDiffusion) -> i
     return _bd_steps(s // s_blk, lambda i: _bd_queries_reading(i, s_blk, t, bd, np), t_blk)
 
 
+def _bd_tile_classes(t: int, t_blk: int, s_blk: int, bd: BlockDiffusion):
+    """The class of every tile the kernels visit, over the forward's grid
+    (the backward's grids visit the same tiles)."""
+    t_idx = np.arange(t // t_blk)[:, None]
+    step = np.arange(_bd_kv_steps(t, t_blk, s_blk, bd))[None, :]
+    s_idx, visible = _bd_step(_bd_blocks(_bd_keys_read(t_idx, t_blk, bd, np), s_blk, np), step, np)
+    return np.broadcast_to(_bd_tile_class(t_idx, s_idx, t_blk, s_blk, bd, np), visible.shape)[visible]
+
+
+def _bd_kinds(t: int, t_blk: int, s_blk: int, bd: BlockDiffusion):
+    """The classes that occur among the visited tiles: a kernel builds a
+    branch for each and no other."""
+    return tuple(int(kind) for kind in np.unique(_bd_tile_classes(t, t_blk, s_blk, bd)))
+
+
 def _bd_bias(t_blk: int, s_blk: int, t_idx, s_idx, bd: BlockDiffusion):
     """(T_blk, S_blk) additive bias of the block-diffusion mask for query
     tile ``t_idx`` against key block ``s_idx`` (global indices)."""
@@ -345,10 +393,41 @@ def _bd_bias(t_blk: int, s_blk: int, t_idx, s_idx, bd: BlockDiffusion):
     return jnp.where(masking.block_diffusion_visible(rows, cols, *bd), 0.0, MASK_VALUE)
 
 
+def _bd_logits(logits, bias_ref, kind: int, t_idx, s_idx, bd: BlockDiffusion):
+    """A visited tile's (T_blk, S_blk) logits under the block-diffusion mask,
+    at the cost of its class ``kind`` (static). Only a MIXED tile can hold a
+    padded key, so only it adds the pad bias (zero on every other key); a cut
+    tile compares a row's block with a column's, each counted from the start
+    of its half: a (T_blk, 1) column of blocks against a (1, S_blk) row of
+    them, so the one compare is the only operation a score. Every class
+    gives the logits the MIXED path gives: a masked score MASK_VALUE, a
+    visible one unchanged."""
+    if kind == WHOLE:
+        return logits
+    t_blk, s_blk = logits.shape
+    if kind == MIXED:
+        return logits + bias_ref[0] + _bd_bias(t_blk, s_blk, t_idx, s_idx, bd)
+    half, blk = bd
+    shift = blk.bit_length() - 1
+    row0 = t_idx * t_blk - (half if kind == CUT_CC else 0)
+    col0 = s_idx * s_blk - (0 if kind == CUT_NN else half)
+    rows = (row0 + jax.lax.broadcasted_iota(jnp.int32, (t_blk, 1), 0)) >> shift
+    cols = (col0 + jax.lax.broadcasted_iota(jnp.int32, (1, s_blk), 1)) >> shift
+    visible = {CUT_NN: operator.eq, CUT_NC: operator.lt, CUT_CC: operator.le}[kind](cols, rows)
+    return jnp.where(visible, logits, MASK_VALUE)
+
+
+def _bd_branches(tile, kinds, visible, t_idx, s_idx, t_blk: int, s_blk: int, bd: BlockDiffusion):
+    """Run ``tile(kind)`` on a visible step, under the branch of its class."""
+    kind = _bd_tile_class(t_idx, s_idx, t_blk, s_blk, bd, jnp)
+    for each in kinds:
+        pl.when(visible & (kind == each))(functools.partial(tile, each))
+
+
 def _attention_kernel(bias_ref, q_ref, k_ref, v_ref, out_ref, *rest,
                       scale: float, with_lse: bool,
                       causal_offset: Optional[int], skip_masked: bool,
-                      block_diffusion: Optional[BlockDiffusion] = None):
+                      block_diffusion: Optional[BlockDiffusion] = None, bd_kinds=()):
     if with_lse:
         m_out, l_out, m_ref, l_ref, acc_ref = rest
         lse_ref = (m_out, l_out)
@@ -367,16 +446,17 @@ def _attention_kernel(bias_ref, q_ref, k_ref, v_ref, out_ref, *rest,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def _tile():
+    def _tile(kind=None):
         q = q_ref[0, 0]  # (T_blk, D)
         k = k_ref[0, 0]  # (S_blk, D)
         logits = _dot(q, k, (1, 1)) * scale  # (T_blk, S_blk)
-        logits += bias_ref[0]  # (1, S_blk) broadcasts over T_blk
+        if block_diffusion is not None:
+            logits = _bd_logits(logits, bias_ref, kind, t_idx, kv_idx, block_diffusion)
+        else:
+            logits += bias_ref[0]  # (1, S_blk) broadcasts over T_blk
         if causal_offset is not None:
             logits += _causal_bias(q.shape[0], k.shape[0], t_idx, s_idx,
                                    causal_offset)
-        if block_diffusion is not None:
-            logits += _bd_bias(q.shape[0], k.shape[0], t_idx, kv_idx, block_diffusion)
 
         m_prev = m_ref[:, :1]  # (T_blk, 1)
         l_prev = l_ref[:, :1]
@@ -392,7 +472,8 @@ def _attention_kernel(bias_ref, q_ref, k_ref, v_ref, out_ref, *rest,
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
     if block_diffusion is not None:
-        pl.when(visible)(_tile)
+        _bd_branches(_tile, bd_kinds, visible, t_idx, kv_idx, q_ref.shape[2], k_ref.shape[2],
+                     block_diffusion)
     elif skip_masked:
         pl.when(_tile_visible(t_idx, s_idx, q_ref.shape[2],
                               k_ref.shape[2], causal_offset))(_tile)
@@ -434,7 +515,8 @@ def _fused_attention_fwd_impl(
     a block whose index does not change is not copied again).
     ``block_diffusion``: the block-diffusion mask in place of the causal
     rule; the KV axis walks the key blocks each query tile reads (its noised
-    and its clean range) and no other."""
+    and its clean range) and no other, and a step masks as its tile's class
+    asks (``_bd_logits``)."""
     b, h, t, d = q.shape
     s, dv = k.shape[2], v.shape[3]
     scale = d**-0.5
@@ -443,8 +525,10 @@ def _fused_attention_fwd_impl(
 
     kv_block = functools.partial(_kv_block_index, t_blk=t_blk, s_blk=s_blk,
                                  offset=causal_offset, skip_masked=skip_masked)
+    bd_kinds = ()
     if block_diffusion is not None:
         grid = (b, h, t // t_blk, _bd_kv_steps(t, t_blk, s_blk, block_diffusion))
+        bd_kinds = _bd_kinds(t, t_blk, s_blk, block_diffusion)
 
         def kv_block(ti, si):
             return _bd_kv_step(ti, si, t_blk, s_blk, block_diffusion)[0]
@@ -462,7 +546,7 @@ def _fused_attention_fwd_impl(
     kernel = pl.pallas_call(
         functools.partial(_attention_kernel, scale=scale, with_lse=with_lse,
                           causal_offset=causal_offset, skip_masked=skip_masked,
-                          block_diffusion=block_diffusion),
+                          block_diffusion=block_diffusion, bd_kinds=bd_kinds),
         grid=grid,
         in_specs=[
             # (B, 1, S) so the block's trailing dims satisfy TPU tiling
@@ -495,7 +579,7 @@ def _fused_attention_fwd_impl(
 def _recompute_probs_and_ds(bias_ref, q_ref, k_ref, v_ref, g_ref,
                             m_ref, l_ref, di_ref, *, scale: float,
                             causal_offset: Optional[int],
-                            t_idx, s_idx, block_diffusion=None):
+                            t_idx, s_idx, block_diffusion=None, bd_kind=None):
     """Shared backward tile math: recompute p = exp(logits − m)/l for this
     (T_blk, S_blk) tile and the softmax gradient ds = p·(dp − delta).
 
@@ -507,17 +591,18 @@ def _recompute_probs_and_ds(bias_ref, q_ref, k_ref, v_ref, g_ref,
     indices — the two backward kernels run swapped grids, so the caller
     passes whichever program_id carries each axis). With a
     ``block_diffusion`` mask the same: ``t_idx``/``s_idx`` are the blocks the
-    grid step reads."""
+    grid step reads, and ``bd_kind`` (static) the tile's class."""
     q = q_ref[0, 0]  # (T_blk, D)
     k = k_ref[0, 0]  # (S_blk, D)
     g = g_ref[0, 0]  # (T_blk, Dv)
     logits = _dot(q, k, (1, 1)) * scale  # (T_blk, S_blk)
-    logits += bias_ref[0]  # (1, S_blk) broadcasts over T_blk
+    if block_diffusion is not None:
+        logits = _bd_logits(logits, bias_ref, bd_kind, t_idx, s_idx, block_diffusion)
+    else:
+        logits += bias_ref[0]  # (1, S_blk) broadcasts over T_blk
     if causal_offset is not None:
         logits += _causal_bias(q.shape[0], k.shape[0], t_idx, s_idx,
                                causal_offset)
-    if block_diffusion is not None:
-        logits += _bd_bias(q.shape[0], k.shape[0], t_idx, s_idx, block_diffusion)
     m = m_ref[0, 0][:, :1]  # (T_blk, 1)
     l = l_ref[0, 0][:, :1]
     p = jnp.exp(logits - m) / l
@@ -530,7 +615,7 @@ def _recompute_probs_and_ds(bias_ref, q_ref, k_ref, v_ref, g_ref,
 def _bwd_dq_kernel(bias_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, di_ref,
                    dq_ref, acc_ref, *, scale: float,
                    causal_offset: Optional[int], skip_masked: bool,
-                   block_diffusion: Optional[BlockDiffusion] = None):
+                   block_diffusion: Optional[BlockDiffusion] = None, bd_kinds=()):
     t_idx, s_idx = pl.program_id(2), pl.program_id(3)
     kv_idx = s_idx
     if block_diffusion is not None:  # the grid's KV step -> the key block it reads
@@ -541,16 +626,17 @@ def _bwd_dq_kernel(bias_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, di_ref,
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def _tile():
+    def _tile(kind=None):
         _, ds, _, k, _ = _recompute_probs_and_ds(
             bias_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, di_ref,
             scale=scale, causal_offset=causal_offset,
-            t_idx=t_idx, s_idx=kv_idx, block_diffusion=block_diffusion,
+            t_idx=t_idx, s_idx=kv_idx, block_diffusion=block_diffusion, bd_kind=kind,
         )
         acc_ref[:] += _dot(ds.astype(k.dtype), k, (1, 0))  # (T_blk, D)
 
     if block_diffusion is not None:
-        pl.when(visible)(_tile)
+        _bd_branches(_tile, bd_kinds, visible, t_idx, kv_idx, q_ref.shape[2], k_ref.shape[2],
+                     block_diffusion)
     elif skip_masked:
         pl.when(_tile_visible(t_idx, s_idx, q_ref.shape[2],
                               k_ref.shape[2], causal_offset))(_tile)
@@ -566,7 +652,8 @@ def _bwd_dkv_kernel(bias_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, di_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
                     causal_offset: Optional[int], skip_masked: bool,
                     group: int, q_blocks: int,
-                    block_diffusion: Optional[BlockDiffusion] = None, rows: int = 0):
+                    block_diffusion: Optional[BlockDiffusion] = None, rows: int = 0,
+                    bd_kinds=()):
     # the sequential axis runs over the query blocks of every query head of
     # this key/value head's group, one head after the other: dk and dv are
     # sums over all of them, and the accumulators live across the whole axis
@@ -583,18 +670,19 @@ def _bwd_dkv_kernel(bias_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, di_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def _tile():
+    def _tile(kind=None):
         p, ds, q, _, g = _recompute_probs_and_ds(
             bias_ref, q_ref, k_ref, v_ref, g_ref, m_ref, l_ref, di_ref,
             scale=scale, causal_offset=causal_offset,
-            t_idx=t_idx, s_idx=s_idx, block_diffusion=block_diffusion,
+            t_idx=t_idx, s_idx=s_idx, block_diffusion=block_diffusion, bd_kind=kind,
         )
         # contract the query axis: (T_blk, S_blk)ᵀ·(T_blk, D) → (S_blk, D)
         dv_acc[:] += _dot(p.astype(g.dtype), g, (0, 0))
         dk_acc[:] += _dot(ds.astype(q.dtype), q, (0, 0))
 
     if block_diffusion is not None:
-        pl.when(visible)(_tile)
+        _bd_branches(_tile, bd_kinds, visible, t_idx, s_idx, q_ref.shape[2], k_ref.shape[2],
+                     block_diffusion)
     elif skip_masked:
         pl.when(_tile_visible(t_idx, s_idx, q_ref.shape[2],
                               k_ref.shape[2], causal_offset))(_tile)
@@ -633,9 +721,11 @@ def _fused_attention_bwd_impl(
     kv_block = functools.partial(_kv_block_index, t_blk=t_blk, s_blk=s_blk,
                                  offset=causal_offset, skip_masked=skip_masked)
     kv_steps, q_blocks = s // s_blk, t // t_blk
+    bd_kinds = ()
     if block_diffusion is not None:  # the grids walk the blocks the mask leaves
         kv_steps = _bd_kv_steps(t, t_blk, s_blk, block_diffusion)
         q_blocks = _bd_q_steps(s, s_blk, t, t_blk, block_diffusion)
+        bd_kinds = _bd_kinds(t, t_blk, s_blk, block_diffusion)
 
         def kv_block(ti, si):
             return _bd_kv_step(ti, si, t_blk, s_blk, block_diffusion)[0]
@@ -655,7 +745,7 @@ def _fused_attention_bwd_impl(
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale,
                           causal_offset=causal_offset, skip_masked=skip_masked,
-                          block_diffusion=block_diffusion),
+                          block_diffusion=block_diffusion, bd_kinds=bd_kinds),
         grid=(b, h, t // t_blk, kv_steps),  # KV axis sequential
         in_specs=[bias_spec, q_spec, k_spec, v_spec, g_spec,
                   lm_spec, lm_spec, lm_spec],
@@ -701,7 +791,7 @@ def _fused_attention_bwd_impl(
         functools.partial(_bwd_dkv_kernel, scale=scale,
                           causal_offset=causal_offset, skip_masked=skip_masked,
                           group=group, q_blocks=q_blocks,
-                          block_diffusion=block_diffusion, rows=t),
+                          block_diffusion=block_diffusion, rows=t, bd_kinds=bd_kinds),
         grid=(b, kv_heads, s // s_blk, group * q_blocks),  # query axis sequential
         in_specs=[bias_spec2, q_spec2, k_spec2, v_spec2, g_spec2,
                   lm_spec2, lm_spec2, lm_spec2],
@@ -763,15 +853,25 @@ def _prepare_blocks(q, k, v, bias, kv_block_size, q_block_size, interpret):
     tiling-legal padding. Returns ``(q, k, v, bias, t_blk, s_blk, t_pad)``
     with q/k/v in (B, H, T/S, D) layout. ``q_block_size=None`` resolves per
     shape after s_blk is known (see LONG_KV_Q_BLOCK)."""
-    t = q.shape[1]
-    s = k.shape[1]
-    d = q.shape[-1]
-
     # heads-major layout so each (b, h) grid step reads contiguous KV rows
     q = jnp.transpose(q, (0, 2, 1, 3))
     k = jnp.transpose(k, (0, 2, 1, 3))
     v = jnp.transpose(v, (0, 2, 1, 3))
 
+    t_blk, t_pad, s_blk, s_pad = _block_sizes(q.shape[2], k.shape[2], q.shape[-1],
+                                              kv_block_size, q_block_size, interpret)
+    if s_pad:
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, s_pad), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, s_pad), (0, 0)))
+        bias = jnp.pad(bias, ((0, 0), (0, s_pad)), constant_values=PAD_BIAS)
+    if t_pad:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, t_pad), (0, 0)))
+    return q, k, v, bias, t_blk, s_blk, t_pad
+
+
+def _block_sizes(t: int, s: int, d: int, kv_block_size, q_block_size, interpret: bool):
+    """``(t_blk, t_pad, s_blk, s_pad)`` of T queries against S keys, ``d``
+    deep: the blocks and the padding ``_prepare_blocks`` gives them."""
     # Stream the KV axis in blocks. Compiled TPU blocks must be lane-aligned
     # (a multiple of 128, or the full dim); when S has no aligned divisor, pad
     # it up to a block multiple with PAD_BIAS keys (excluded from the softmax
@@ -779,17 +879,14 @@ def _prepare_blocks(q, k, v, bias, kv_block_size, q_block_size, interpret):
     alignment = _TEST_ALIGNMENT or (1 if interpret else _LANES)
     if kv_block_size is None:
         kv_block_size = _auto_kv_block(s, d, t, alignment, q_block_size)
+    s_pad = 0
     s_blk = _kv_block_size(s, kv_block_size, alignment)
     if s_blk == 0:
         if s <= 4 * kv_block_size:
             s_blk = s  # full-dim blocks are always tiling-legal; skip padding
         else:
-            block = max(kv_block_size - kv_block_size % alignment, alignment)
-            s_pad = -s % block
-            k = jnp.pad(k, ((0, 0), (0, 0), (0, s_pad), (0, 0)))
-            v = jnp.pad(v, ((0, 0), (0, 0), (0, s_pad), (0, 0)))
-            bias = jnp.pad(bias, ((0, 0), (0, s_pad)), constant_values=PAD_BIAS)
-            s_blk = block
+            s_blk = max(kv_block_size - kv_block_size % alignment, alignment)
+            s_pad = -s % s_blk
 
     if q_block_size is None:
         # auto: the big query block only in its measured-safe regime (see
@@ -813,9 +910,22 @@ def _prepare_blocks(q, k, v, bias, kv_block_size, q_block_size, interpret):
         else:
             t_blk = max(q_block_size - q_block_size % alignment, alignment)
             t_pad = -t % t_blk
-            q = jnp.pad(q, ((0, 0), (0, 0), (0, t_pad), (0, 0)))
+    return t_blk, t_pad, s_blk, s_pad
 
-    return q, k, v, bias, t_blk, s_blk, t_pad
+
+def block_diffusion_unmasked_tiles_pct(t: int, d: int, bd: BlockDiffusion,
+                                       kv_block_size: Optional[int] = None,
+                                       q_block_size: Optional[int] = None,
+                                       interpret: Optional[bool] = None) -> float:
+    """Share, in %, of the tiles that ``fused_attention(..., block_diffusion=
+    bd)`` visits at T = S = ``t`` positions, ``d`` deep, with these blocks,
+    that run with no mask (the WHOLE class): the classes the kernels branch on,
+    over the grid they build."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    t_blk, t_pad, s_blk, _ = _block_sizes(t, t, d, kv_block_size, q_block_size, interpret)
+    classes = _bd_tile_classes(t + t_pad, t_blk, s_blk, bd)
+    return 100.0 * np.count_nonzero(classes == WHOLE) / classes.size
 
 
 def fused_attention(
@@ -870,7 +980,12 @@ def fused_attention(
     whole are neither fetched nor computed: a noised query tile reads its own
     noised block and the clean prefix before it, a clean one the clean prefix
     up to its end (about L^2 of the 4 L^2 pairs are visible). Exact for the
-    same reason as the causal skipping: every row reads itself.
+    same reason as the causal skipping: every row reads itself. A visited tile
+    pays for the mask by its class (``_bd_tile_class``, once a grid step): a
+    tile wholly visible adds no mask, a tile that the mask cuts inside one
+    quadrant of noised / clean queries by noised / clean keys adds one compare
+    of shifted row and column indices, and only a tile across the two halves
+    or over padding adds the general rule.
     """
     if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
         raise ValueError(f"expected (B, T/S, H, D) tensors, got {q.shape=} {k.shape=}")

@@ -197,11 +197,17 @@ def test_mask_function_is_the_four_rules(block):
     assert want.sum() == half * (half + block)
 
 
-@pytest.mark.parametrize("half, block, q_blk, kv_blk, heads, kv_heads", [
+# (half, block, query tile, key tile, query heads, key/value heads)
+KERNEL_CASES = [
     (24, 4, 16, 16, 4, 2), (24, 8, 16, 8, 2, 1), (24, 1, 16, 16, 4, 4), (32, 4, 16, 32, 4, 2),
-    (32, 8, 64, 16, 4, 2)],
-    ids=["straddling_tiles", "blocks_of_8_keys_by_8", "blocks_of_1", "wide_key_tiles",
-         "one_query_tile_over_both_halves"])
+    (32, 8, 64, 16, 4, 2), (36, 4, 8, 8, 4, 2), (37, 4, 16, 16, 2, 1)]
+KERNEL_CASE_IDS = ["straddling_tiles", "blocks_of_8_keys_by_8", "blocks_of_1", "wide_key_tiles",
+                   "one_query_tile_over_both_halves", "every_tile_class", "padded_queries_and_keys"]
+TILINGS = [case[:4] for case in KERNEL_CASES]
+
+
+@pytest.mark.parametrize("half, block, q_blk, kv_blk, heads, kv_heads", KERNEL_CASES,
+                         ids=KERNEL_CASE_IDS)
 def test_block_diffusion_kernels_match_the_blocked_xla_path(half, block, q_blk, kv_blk, heads,
                                                             kv_heads):
     """Forward, dq and dk / dv (summed over a group in the kernel) in
@@ -209,7 +215,9 @@ def test_block_diffusion_kernels_match_the_blocked_xla_path(half, block, q_blk, 
     positions in query tiles of 16 put a tile across the boundary of the two
     halves (rows 16-31 hold noised 16-23 and clean 0-7); tiles of 8 keys
     against tiles of 16 queries, and one query tile of 64 rows over both
-    halves, walk the other ways the grids can cut the mask."""
+    halves, walk the other ways the grids can cut the mask. Tiles of 8 over
+    rows of 72 visit tiles of all five classes in one grid; rows of 74 have no
+    divisor near 16, so queries and keys are padded to 80."""
     t = 2 * half
     keys = jax.random.split(jax.random.key(0), 4)
     q, weight = (jax.random.normal(key, (2, t, heads, 8)) for key in keys[:2])
@@ -253,6 +261,118 @@ def test_kernel_grids_walk_only_the_tiles_the_mask_shows():
     walked = [(int(t), si) for si in range(2 * half // kv_blk) for j in range(steps)
               for t, on in [pa._bd_q_step(si, j, q_blk, kv_blk, 2 * half, bd)] if on]
     assert sorted(walked) == sorted(shown) and len(walked) == len(set(walked))
+
+
+def test_tile_classes_at_the_cells_shape():
+    """A head visits 160 tiles: 112 wholly visible (the clean prefixes), and
+    16 cut ones of each kind (the noised diagonal, the noised queries' last
+    clean tile, the clean diagonal); no tile lies across the two halves. The
+    kernels' case `every_tile_class` (rows of 72 in tiles of 8) visits all
+    five classes."""
+    classes = pa._bd_tile_classes(16384, 512, 1024, pa.BlockDiffusion(8192, 4))
+    assert np.bincount(classes, minlength=5).tolist() == [112, 16, 16, 16, 0]
+    assert pa.block_diffusion_unmasked_tiles_pct(
+        16384, 128, pa.BlockDiffusion(8192, 4), kv_block_size=1024, q_block_size=512,
+        interpret=False) == 70.0
+    small = pa._bd_tile_classes(72, 8, 8, pa.BlockDiffusion(36, 4))
+    assert np.bincount(small, minlength=5).tolist() == [9, 4, 3, 4, 13]
+
+
+@pytest.mark.parametrize("half, block, q_blk, kv_blk", TILINGS, ids=KERNEL_CASE_IDS)
+def test_tile_classes_follow_the_mask(half, block, q_blk, kv_blk):
+    """On every tile a grid visits: MIXED where and only where the tile lies
+    across the two halves or over padding (shown whole or not), WHOLE where
+    and only where the explicit mask shows every pair of a tile that is not
+    MIXED, and the mask a class applies is the mask's own on the tile: none
+    for WHOLE, one compare for each cut kind."""
+    bd = pa.BlockDiffusion(half, block)
+    t_blk, t_pad, s_blk, s_pad = pa._block_sizes(2 * half, 2 * half, 8, kv_blk, q_blk, True)
+    t, s = 2 * half + t_pad, 2 * half + s_pad
+    explicit = explicit_mask(half, block)
+    seen = set()
+    for ti in range(t // t_blk):
+        for j in range(pa._bd_kv_steps(t, t_blk, s_blk, bd)):
+            si, on = pa._bd_kv_step(ti, j, t_blk, s_blk, bd)
+            if not on:
+                continue
+            si = int(si)
+            kind = int(pa._bd_tile_class(ti, si, t_blk, s_blk, bd, np))
+            seen.add(kind)
+            rows = np.arange(ti * t_blk, (ti + 1) * t_blk)
+            cols = np.arange(si * s_blk, (si + 1) * s_blk)
+            across = any(lo < half <= hi for lo, hi in ((rows[0], rows[-1]), (cols[0], cols[-1])))
+            padded = rows[-1] >= 2 * half or cols[-1] >= 2 * half
+            assert (kind == pa.MIXED) == (across or padded)
+            want = np.asarray(masking.block_diffusion_visible(
+                jnp.asarray(rows)[:, None], jnp.asarray(cols)[None, :], half, block))
+            if not padded:
+                assert np.array_equal(want, explicit[np.ix_(rows, cols)])
+            assert (kind == pa.WHOLE) == (not (across or padded) and bool(want.all()))
+            got = pa._bd_logits(jnp.zeros((t_blk, s_blk)), jnp.zeros((1, 1, s_blk)), kind,
+                                ti, si, bd)
+            assert np.array_equal(np.asarray(got) == 0, want)
+    assert seen == set(np.unique(pa._bd_tile_classes(t, t_blk, s_blk, bd)).tolist())
+
+
+@pytest.mark.parametrize("half, block, q_blk, kv_blk", TILINGS[-2:], ids=KERNEL_CASE_IDS[-2:])
+def test_tile_classes_agree_with_the_general_mask_to_rounding(half, block, q_blk, kv_blk,
+                                                             monkeypatch):
+    """The kernels' output and gradients with a branch for each class are the
+    numbers they give when every tile takes the general mask, to float32
+    rounding (a few units in the last place of the largest value: in
+    interpret mode XLA's CPU compiler may round the scaled scores of the two
+    programs apart)."""
+    keys = jax.random.split(jax.random.key(1), 4)
+    q, weight = (jax.random.normal(key, (1, 2 * half, 4, 8)) for key in keys[:2])
+    k, v = (jax.random.normal(key, (1, 2 * half, 2, 8)) for key in keys[2:])
+
+    def run():
+        out, vjp = jax.vjp(lambda *a: pa.fused_attention(
+            *a, block_diffusion=pa.BlockDiffusion(half, block), q_block_size=q_blk,
+            kv_block_size=kv_blk, interpret=True), q, k, v)
+        return [np.asarray(x) for x in (out, *vjp(weight))]
+
+    by_class = run()
+    monkeypatch.setattr(pa, "_bd_tile_class", lambda *a: pa.MIXED)
+    monkeypatch.setattr(pa, "_bd_kinds", lambda *a: (pa.MIXED,))
+    jax.clear_caches()  # the kernels are jitted on their static arguments
+    general = run()
+    jax.clear_caches()
+    for got, want in zip(by_class, general):
+        assert worst(got, want) < 1e-6
+
+
+def _gauge(cfg, width=40):
+    from perceiver_io_tpu import obs
+
+    obs.get_registry().remove("bd_unmasked_tiles_pct")
+    builder = importlib.import_module(f"benchmarks.configs.{cfg['builder']}")
+    builder.build_model(cfg, 1, width)
+    return obs.get_registry().snapshot()["gauges"]["bd_unmasked_tiles_pct"]
+
+
+def test_unmasked_tiles_gauge_is_the_built_kernels_share(monkeypatch):
+    """Where the model is built for the kernels, the gauge is the WHOLE share
+    of the tiles over the grid the forward kernel is then built with (read
+    from the kernel's own call); on the XLA path it is 0."""
+    from perceiver_io_tpu.ops import latent_attention
+
+    cfg, _, builder = tiny_cell()
+    assert _gauge(dict(cfg, attn_impl="xla")) == 0.0
+    monkeypatch.setattr(latent_attention, "pallas_blocks", lambda depth: (16, 16))
+    gauge = _gauge(dict(cfg, attn_impl="pallas"))
+    built, kinds = [], pa._bd_kinds
+    monkeypatch.setattr(pa, "_bd_kinds", lambda *a: built.append(a) or kinds(*a))
+    jax.clear_caches()
+    layer = GroupedQueryAttention(num_heads=4, num_kv_heads=2, head_dim=8, rope_theta=1e6,
+                                  block_diffusion=cfg["block_length"], attn_impl="pallas")
+    x = jax.random.normal(jax.random.key(0), (1, 80, 16))
+    layer.apply(layer.init(jax.random.key(1), x), x)
+    jax.clear_caches()
+    t, t_blk, s_blk, bd = built[0]
+    classes = pa._bd_tile_classes(t, t_blk, s_blk, bd)
+    assert (t, t_blk, s_blk, bd) == (80, 16, 16, pa.BlockDiffusion(40, 4))
+    assert 0.0 < gauge == 100.0 * np.count_nonzero(classes == pa.WHOLE) / classes.size < 100.0
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
@@ -430,6 +550,8 @@ def test_train_lm_cli_trains_the_family_by_block_diffusion(tmp_path):
     layers = events["lm.layers"]
     assert layers["mixers"] == ["full_attention", "full_attention"]
     assert (layers["objective"], layers["block_length"]) == ("block_diffusion", 4)
+    # off a TPU the attention is the blocked XLA path: no kernel tile runs unmasked
+    assert layers["bd_unmasked_tiles_pct"] == 0.0
     assert (layers["kv_group"], layers["tied_head"], layers["shared_expert_width"]) == (2, False, 0)
     assert (layers["experts_held"], layers["experts_published"]) == (4, 8)
     # a row runs as two: 8 rows of 2 x 32 positions route
@@ -442,6 +564,7 @@ def test_train_lm_cli_trains_the_family_by_block_diffusion(tmp_path):
     assert "loss_main" not in row and 0.0 < row["bd_masked_pct"] < 100.0
     gauges = obs.get_registry().snapshot()["gauges"]
     assert 0.0 < gauges["bd_masked_pct"] < 100.0 and gauges["moe_bounded_path_pct"] == 100.0
+    assert gauges["bd_unmasked_tiles_pct"] == 0.0
 
 
 def test_noise_draws_are_exact_and_skip_padding():
