@@ -7,7 +7,10 @@ head; for ``nemotron_h``, one sublayer a block by
 squared-ReLU experts beside a shared expert of its own width; and for
 ``sdar_moe``, grouped-query attention and 128 softmax-routed experts in every
 layer, trained by block diffusion when the configuration's ``objective`` says
-so: ``training.make_block_diffusion_steps``).
+so: ``training.make_block_diffusion_steps``; and for ``ouro``, a LOOPED stack:
+multi-head attention and a dense SwiGLU under sandwich norms, the blocks run
+``total_ut_steps`` times over one weight set, an exit gate and a head read
+after every step, trained by the expected-exit loss).
 
 The model's sizes are the keys of a published ``config.json`` (``--config``),
 each also a flag of its own name that overrides the file; the published
@@ -30,6 +33,8 @@ Usage:
         --hybrid_override_pattern 'MEMEM*EME' ...
     python -m perceiver_io_tpu.cli.train_lm --synthetic --model_type sdar_moe \
         --block_length 4 ...
+    python -m perceiver_io_tpu.cli.train_lm --synthetic --model_type ouro \
+        --total_ut_steps 4 ...
 """
 
 from __future__ import annotations
@@ -49,6 +54,7 @@ from perceiver_io_tpu.data.imdb import IMDBDataModule
 from perceiver_io_tpu.models.decoder_lm import (
     LFM2_MOE,
     NEMOTRON_H,
+    OURO,
     SDAR_MOE,
     DecoderLM,
     DecoderLMConfig,
@@ -96,7 +102,15 @@ SMALL_SDAR = dict(
     norm_topk_prob=True, rope_theta=1000000.0, rms_norm_eps=1e-6, objective="block_diffusion",
     block_length=4,
 )
-SMALL_SIZES = {LFM2_MOE: SMALL_LFM2, NEMOTRON_H: SMALL_NEMOTRON_H, SDAR_MOE: SMALL_SDAR}
+# the same for ``--model_type ouro``: two sandwich-norm layers of multi-head
+# attention looped three times
+SMALL_OURO = dict(
+    hidden_size=64, intermediate_size=128, num_hidden_layers=2, num_attention_heads=4,
+    num_key_value_heads=4, head_dim=16, rope_theta=1000000.0, rms_norm_eps=1e-6,
+    total_ut_steps=3,
+)
+SMALL_SIZES = {LFM2_MOE: SMALL_LFM2, NEMOTRON_H: SMALL_NEMOTRON_H, SDAR_MOE: SMALL_SDAR,
+               OURO: SMALL_OURO}
 Flag = collections.namedtuple("Flag", "name type")
 # every size is a flag: the skeleton's fields (the vocabulary is the
 # tokenizer's, ``--vocab_size`` of the data flags), and the published keys
@@ -152,12 +166,14 @@ def build_model(args, vocab_size: int) -> DecoderLM:
     diffusion = config.objective == "block_diffusion"
     # a row of block diffusion runs as two: its noised and its clean copy
     tokens = args.batch_size * args.max_seq_len * (2 if diffusion else 1)
-    obs.event("moe.share", held=held, total=config.n_routed_experts,
-              offset=config.expert_offset,
-              capacity_rows=moe.TILE_ROWS * moe.capacity_tiles(
-                  tokens, config.num_experts_per_tok, held, config.n_routed_experts, moe.TILE_ROWS),
-              worst_case_rows=moe.TILE_ROWS * moe.worst_case_tiles(
-                  tokens * config.num_experts_per_tok, held, moe.TILE_ROWS))
+    if config.n_routed_experts:
+        obs.event("moe.share", held=held, total=config.n_routed_experts,
+                  offset=config.expert_offset,
+                  capacity_rows=moe.TILE_ROWS * moe.capacity_tiles(
+                      tokens, config.num_experts_per_tok, held, config.n_routed_experts,
+                      moe.TILE_ROWS),
+                  worst_case_rows=moe.TILE_ROWS * moe.worst_case_tiles(
+                      tokens * config.num_experts_per_tok, held, moe.TILE_ROWS))
     scans = "mamba2" in config.mixers
     # the mixer's own question: the kernel pair or the einsums
     scan_kernel = scans and mamba2.scan_impl(
@@ -168,6 +184,8 @@ def build_model(args, vocab_size: int) -> DecoderLM:
     unmasked_tiles = latent_attention.block_diffusion_unmasked_tiles_pct(
         2 * args.max_seq_len, config.head_dim, config.block_length,
         args.attn_impl) if diffusion else 0.0
+    # every block runs once a step, or R times in a looped stack
+    applications = max(config.total_ut_steps, 1) * config.num_hidden_layers
     obs.event("lm.layers", mixers=list(config.mixers),
               one_sublayer_blocks=config.one_sublayer_blocks,
               dense_layers=config.first_k_dense_replace,
@@ -182,7 +200,8 @@ def build_model(args, vocab_size: int) -> DecoderLM:
               ssd_scan_kernel=scan_kernel,
               tied_head=config.tie_word_embeddings,
               objective=config.objective, block_length=config.block_length,
-              bd_unmasked_tiles_pct=unmasked_tiles)
+              bd_unmasked_tiles_pct=unmasked_tiles, total_ut_steps=config.total_ut_steps,
+              sandwich_norm=config.sandwich_norm, block_applications=applications)
     # float32 bytes of the states that a Mamba-2 layer's backward pass holds for
     # one row: one a chunk, not one a token (0 without such a layer)
     obs.get_registry().gauge("ssd_state_bytes").set(mamba2.state_bytes(
@@ -192,6 +211,8 @@ def build_model(args, vocab_size: int) -> DecoderLM:
         obs.get_registry().gauge("ssd_scan_kernel_pct").set(100.0 if scan_kernel else 0.0)
     if diffusion:  # a constant of the traced program
         obs.get_registry().gauge("bd_unmasked_tiles_pct").set(unmasked_tiles)
+    if config.total_ut_steps:  # a constant of the traced program: R x N
+        obs.get_registry().gauge("ut_block_applications").set(applications)
     # the model rematerialises every block (``--remat`` is the Perceiver
     # encoders' switch) and the experts' path follows the backend
     return DecoderLM(config, attn_impl=args.attn_impl, dtype=common.DTYPES[args.dtype])

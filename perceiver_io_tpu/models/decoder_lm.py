@@ -1,4 +1,4 @@
-"""A token-level causal decoder: ONE skeleton for four public families.
+"""A token-level causal decoder: ONE skeleton for five public families.
 
 The skeleton: an embedding, a Python loop of rematerialised pre-norm blocks
 
@@ -50,7 +50,31 @@ Each family's published ``config.json`` maps onto the skeleton in a
   rotary in every layer, the routed expert layer in every layer, 128 experts
   under a SOFTMAX router (top 8 of the scores, no bias, renormalised), NO
   shared expert, an untied head, no MTP module; its ``objective`` is
-  ``'block_diffusion'`` (below).
+  ``'block_diffusion'`` (below);
+- ``ouro`` (the Ouro LoopLM family, arXiv:2510.25741): multi-head
+  ``'full_attention'`` with half-split rotary and no q/k norm, a dense SwiGLU
+  in every layer, SANDWICH norms (an RMSNorm before and after each sublayer),
+  an untied head, and the stack LOOPED (below).
+
+A looped stack (``total_ut_steps`` R >= 1): the N blocks run R times over ONE
+weight set, the final norm between steps, its output the next step's input,
+
+    h^0 = Emb(ids),   h^t = RMSNorm_f(Block_N o ... o Block_1(h^{t-1})),  t = 1..R
+
+as ONE ``nn.scan`` over the steps with the parameters broadcast, so the stack
+is traced and compiled once, whatever R (scope ``ut_stack``). Each step is an
+exit: a gate ``lambda_t = sigmoid(h^t w_g + b_g)``, the probability of
+leaving at step t ``p_t = lambda_t prod_{j<t} (1 - lambda_j)`` (t < R) and
+``p_R = prod_{j<R} (1 - lambda_j)``, and the next-token cross-entropy
+``CE_t`` of ``h^t W_head``. The loss is the expected loss over the exits less
+``exit_entropy_beta`` times the exit distribution's entropy,
+
+    L = mean over tokens of [ sum_t p_t CE_t + beta sum_t p_t log p_t ]
+
+computed one exit at a time (a ``lax.scan`` of checkpointed heads: one
+exit's (tokens, vocabulary) float32 logits alive at a time; scope
+``ut_exits``). R = 1 is one sandwich-norm stack with p_1 = 1 and no entropy
+term.
 
 Block diffusion (BD3-LM, arXiv:2503.09573, the vectorised form). A row ``x``
 of L tokens is cut into blocks of ``block_length``; each block draws a noise
@@ -97,8 +121,10 @@ vocabulary) float32 logits wait for the backward pass.
 Scopes a device trace is cut by: ``embed``, ``mla_attention``,
 ``gqa_attention``, ``short_conv``, ``mamba2`` (with ``mamba2/ssd_scan`` around
 the scan alone, its backward kernel included), ``moe/*``, ``mtp`` (everything the module runs, its attention
-and experts included) and ``head_loss``; no operation of the main stack lies
-outside a layer's scope but norms, residual adds and the dense SwiGLU.
+and experts included) and ``head_loss``, and in a looped stack ``ut_stack``
+(the R passes: forward, recomputation and backward) and ``ut_exits`` (gates,
+heads and the loss); no operation of the main stack lies outside a layer's
+scope but norms, residual adds and the dense SwiGLU.
 """
 
 from __future__ import annotations
@@ -122,7 +148,7 @@ from perceiver_io_tpu.ops.mamba2 import Mamba2Mixer
 from perceiver_io_tpu.ops.masking import IGNORE_LABEL
 from perceiver_io_tpu.ops.moe import Kernel, MoELayer, SwiGLU
 from perceiver_io_tpu.ops.short_conv import GatedShortConv
-from perceiver_io_tpu.training.losses import cross_entropy_with_ignore
+from perceiver_io_tpu.training.losses import cross_entropy_with_ignore, softmax_ce_integer
 
 Array = jax.Array
 
@@ -130,7 +156,8 @@ MIXERS = ("mla", "full_attention", "conv", "mamba2")
 EXPERTS = "moe"  # the routed expert layer: an FFN's kind, and a one-sublayer block's
 LFM2_MOE = "lfm2_moe"      # the published ``model_type`` of the second family
 NEMOTRON_H = "nemotron_h"  # ... of the third
-SDAR_MOE = "sdar_moe"      # ... and of the fourth
+SDAR_MOE = "sdar_moe"      # ... of the fourth
+OURO = "ouro"              # ... and of the fifth
 OBJECTIVES = ("next_token", "block_diffusion")
 # a character of ``hybrid_override_pattern`` -> the block's one sublayer (the
 # family's ``-``, a dense MLP block, is in no configuration here)
@@ -154,6 +181,10 @@ _REQUIRED_NEMOTRON_H = {
     "attention_bias": False, "mlp_hidden_act": "relu2", "mamba_hidden_act": "silu",
     "n_group": 1, "topk_group": 1, "tie_word_embeddings": False, "sliding_window": None,
 }
+_REQUIRED_OURO = {
+    "use_sliding_window": False, "rope_scaling": None, "tie_word_embeddings": False,
+    "hidden_act": "silu",
+}
 # the LFM2 family's names for what the skeleton already has a field for
 _LFM2_NAMES = {"num_dense_layers": "first_k_dense_replace", "num_experts": "n_routed_experts",
                "norm_eps": "rms_norm_eps"}
@@ -168,6 +199,8 @@ _NEMOTRON_H_KEYS = ("mamba_num_heads", "mamba_head_dim", "n_groups", "ssm_state_
 _NEMOTRON_H_ONLY = _NEMOTRON_H_KEYS + ("one_sublayer_blocks", "qk_norm", "rotary")
 # ... nor any of those: what only the SDAR family's ``from_dict`` reads or sets
 _SDAR_ONLY = ("objective", "block_length", "mask_token_id", "scoring_func")
+# ... nor any of those: what only the Ouro family's ``from_dict`` reads or sets
+_OURO_ONLY = ("total_ut_steps", "sandwich_norm", "exit_entropy_beta")
 
 
 def _require(config: Dict[str, Any], required: Dict[str, Any]) -> None:
@@ -234,6 +267,12 @@ class DecoderLMConfig:
     objective: str = "next_token"
     block_length: int = 0
     mask_token_id: int = 0
+    # a looped stack: the blocks run ``total_ut_steps`` times over one weight
+    # set, each step an exit (0: once, no exits); ``sandwich_norm``: an RMSNorm
+    # after each sublayer too; the exit distribution's entropy weight
+    total_ut_steps: int = 0
+    sandwich_norm: bool = False
+    exit_entropy_beta: float = 0.1
     # 'conv' layers: the taps of the short convolution
     conv_L_cache: int = 0
     tie_word_embeddings: bool = False
@@ -256,9 +295,11 @@ class DecoderLMConfig:
             return cls._from_nemotron_h(config)
         if config.get("model_type") == SDAR_MOE:
             return cls._from_sdar_moe(config)
+        if config.get("model_type") == OURO:
+            return cls._from_ouro(config)
         _require(config, _REQUIRED)
         names = {f.name for f in dataclasses.fields(cls)} - set(
-            _LFM2_ONLY + _NEMOTRON_H_ONLY + _SDAR_ONLY)
+            _LFM2_ONLY + _NEMOTRON_H_ONLY + _SDAR_ONLY + _OURO_ONLY)
         return cls(**{k: v for k, v in config.items() if k in names})
 
     @classmethod
@@ -331,6 +372,32 @@ class DecoderLMConfig:
                    n_shared_experts=0, scoring_func="softmax",
                    layer_types=("full_attention",) * config["num_hidden_layers"], **sizes)
 
+    @classmethod
+    def _from_ouro(cls, config: Dict[str, Any]) -> "DecoderLMConfig":
+        """Ouro's keys (``ouro``): multi-head ``full_attention`` of the
+        published ``head_dim`` with half-split rotary at ``rope_theta`` and no
+        q/k norm, a dense SwiGLU of ``intermediate_size`` in every layer,
+        sandwich norms, an untied head, the stack looped ``total_ut_steps``
+        times. ``early_exit_threshold`` is read by nothing: it decides where
+        inference stops, and training runs every step. ``exit_entropy_beta``
+        is the configuration's (no key of the published file names it)."""
+        _require(config, _REQUIRED_OURO)
+        if config.get("total_ut_steps", 0) < 1:
+            raise ValueError(f"total_ut_steps={config.get('total_ut_steps')!r}: at least 1")
+        layers = config["num_hidden_layers"]
+        kinds = tuple(config.get("layer_types") or ("full_attention",) * layers)
+        if kinds != ("full_attention",) * layers:
+            raise ValueError(f"layer_types {kinds}: only full_attention is supported")
+        names = {"vocab_size", "hidden_size", "intermediate_size", "num_attention_heads",
+                 "num_key_value_heads", "rope_theta", "rms_norm_eps", "total_ut_steps",
+                 "exit_entropy_beta"}
+        sizes = {k: v for k, v in config.items() if k in names}
+        return cls(num_hidden_layers=layers, layer_types=kinds, first_k_dense_replace=layers,
+                   head_dim=config.get("head_dim") or config["hidden_size"]
+                   // config["num_attention_heads"],
+                   moe_intermediate_size=0, n_routed_experts=0, num_experts_per_tok=0,
+                   qk_norm=False, sandwich_norm=True, **sizes)
+
     def __post_init__(self):
         if self.objective not in OBJECTIVES or self.scoring_func not in ("sigmoid", "softmax"):
             raise ValueError(f"objective {self.objective!r} / scoring_func {self.scoring_func!r}")
@@ -355,6 +422,11 @@ class DecoderLMConfig:
             raise ValueError(f"{self.mamba_num_heads} Mamba heads over {self.n_groups} groups")
         if self.one_sublayer_blocks and self.num_nextn_predict_layers:
             raise ValueError("the MTP module is a two-sublayer block")
+        if self.total_ut_steps < 0 or self.total_ut_steps and (
+                self.num_nextn_predict_layers or self.objective != "next_token"
+                or EXPERTS in {ffn for _, ffn in self.blocks}):
+            raise ValueError("a looped stack takes total_ut_steps >= 1, dense blocks, "
+                             "next-token exits and no MTP module")
 
     @property
     def mixers(self) -> Tuple[str, ...]:
@@ -432,14 +504,39 @@ class DecoderBlock(nn.Module):
         def norm(name):
             return RMSNorm(c.rms_norm_eps, self.dtype, name=name)
 
+        def post(name, y):  # the sandwich's second norm, on the sublayer's output
+            return norm(name)(y) if c.sandwich_norm else y
+
         if not self.ffn:  # ONE sublayer under one norm
             return x + self._mixer()(norm("norm")(x)), {}
         if not self.mixer:
             y, stats = self._ffn(norm("norm")(x))
             return x + y, stats
-        h = x + self._mixer()(norm("attn_norm")(x))
+        h = x + post("attn_post_norm", self._mixer()(norm("attn_norm")(x)))
         y, stats = self._ffn(norm("ffn_norm")(h))
-        return h + y, stats
+        return h + post("ffn_post_norm", y), stats
+
+
+class LoopStep(nn.Module):
+    """One step of a looped stack: the blocks, then the final norm, whose
+    output is both the next step's input and this step's exit state. Run
+    under ``nn.scan`` with the parameters broadcast (``DecoderLM``)."""
+
+    config: DecoderLMConfig
+    policy: Any = None  # the blocks' ``jax.checkpoint`` policy
+    attn_impl: str = "auto"
+    expert_impl: str = "auto"
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: Array, _) -> Tuple[Array, Array]:
+        c = self.config
+        block = nn.remat(DecoderBlock, policy=self.policy)
+        for i, (mixer, ffn) in enumerate(c.blocks):
+            x, _ = block(c, mixer, ffn, self.attn_impl, self.expert_impl, self.dtype,
+                         name=f"layer_{i}")(x)
+        h = RMSNorm(c.rms_norm_eps, self.dtype, name="final_norm")(x)
+        return h, h
 
 
 class DecoderLM(nn.Module):
@@ -461,6 +558,9 @@ class DecoderLM(nn.Module):
             self.mtp_eh_proj = nn.Dense(c.hidden_size, use_bias=False, dtype=self.dtype,
                                         kernel_init=torch_linear_kernel_init, name="mtp_eh_proj")
             self.mtp_final_norm = RMSNorm(c.rms_norm_eps, self.dtype, name="mtp_final_norm")
+        if c.total_ut_steps:
+            self.exit_gate = nn.Dense(1, dtype=jnp.float32, precision=jax.lax.Precision.HIGHEST,
+                                      kernel_init=torch_linear_kernel_init, name="exit_gate")
 
     def _remat_policy(self, b: int, t: int):
         """The ``jax.checkpoint`` policy of the blocks for (B, T) ids; None
@@ -470,10 +570,13 @@ class DecoderLM(nn.Module):
         Kept where the causal attention is the Pallas kernel: its output, B x
         T x H x the value depth in ``dtype``, and its two float32 statistics a
         row and head, for every block with an attention mixer (the MTP
-        module's too; a convolution keeps nothing). The blocked XLA path names
+        module's too; a convolution keeps nothing), in a looped stack for
+        each of its R x N block applications. The blocked XLA path names
         nothing (its blocks sit under checkpoints of their own)."""
         c = self.config
-        mixers = c.mixers + c.mixers[-1:] * c.num_nextn_predict_layers  # the MTP block's too
+        # every application of a block (R of each in a looped stack), the MTP block's too
+        mixers = (c.mixers * max(c.total_ut_steps, 1)
+                  + c.mixers[-1:] * c.num_nextn_predict_layers)
         depths = [c.value_depth(mixer) for mixer in mixers if c.value_depth(mixer)]
         saved = 0
         if resolve_causal_impl(self.attn_impl) == "pallas":
@@ -493,7 +596,8 @@ class DecoderLM(nn.Module):
         the expert layers' statistics, one dict a layer, and whether the
         blocks keep the causal kernel's residuals (``_remat_policy``). With
         ``clean_ids`` (block diffusion) ``token_ids`` are the noised ``x_t``:
-        the stack runs over ``[x_t ; x]`` and ``h`` is the noised half's."""
+        the stack runs over ``[x_t ; x]`` and ``h`` is the noised half's. In a
+        looped stack ``h`` is every step's state, (R, B, T, D)."""
         c = self.config
         if clean_ids is not None:
             token_ids = jnp.concatenate([token_ids, clean_ids], axis=1)
@@ -506,6 +610,13 @@ class DecoderLM(nn.Module):
 
         with jax.named_scope("embed"):
             x = self.embed(token_ids)
+        if c.total_ut_steps:
+            loop = nn.scan(LoopStep, variable_broadcast="params", split_rngs={"params": False},
+                           length=c.total_ut_steps)
+            with jax.named_scope("ut_stack"):
+                _, hs = loop(c, policy, self.attn_impl, self.expert_impl, self.dtype,
+                             name="loop")(x, None)
+            return hs, None, [], kept
         stats = []
         for i, (mixer, ffn) in enumerate(c.blocks):
             x, s = make(mixer, ffn, f"layer_{i}")(x)
@@ -536,13 +647,70 @@ class DecoderLM(nn.Module):
 
     def __call__(self, token_ids: Array) -> Tuple[Array, Optional[Array]]:
         """(B, T) ids -> float32 logits ``(main, mtp)``, each (B, T, vocab):
-        ``main[:, i]`` scores ``t_{i+1}``, ``mtp[:, i]`` scores ``t_{i+2}``."""
+        ``main[:, i]`` scores ``t_{i+1}``, ``mtp[:, i]`` scores ``t_{i+2}``.
+        A looped stack's ``main`` is its last exit's (what inference that runs
+        every step reads)."""
+        if self.config.total_ut_steps:
+            hs, _, _, _ = self.hidden_states(token_ids)
+            self._exit_log_probs(hs)  # the gate's parameters are made at init like the rest
+            return self._logits(hs[-1], self._head()), None
         h, h_mtp, _, _ = self.hidden_states(token_ids)
         kernel = self._head()
         return self._logits(h, kernel), None if h_mtp is None else self._logits(h_mtp, kernel)
 
+    def _exit_log_probs(self, hs: Array) -> Array:
+        """(R, B, T) float32 ``log p_t`` of leaving at step t from the gates
+        of the R states: ``log lambda_t + sum_{j<t} log(1 - lambda_j)``, the
+        last step ``sum_{j<R} log(1 - lambda_j)`` (log-sigmoids: no 0 x
+        log 0)."""
+        z = self.exit_gate(hs.astype(jnp.float32))[..., 0]
+        stay = jax.nn.log_sigmoid(-z)
+        before = jnp.cumsum(stay, axis=0) - stay
+        return jnp.concatenate([jax.nn.log_sigmoid(z[:-1]) + before[:-1], before[-1:]], axis=0)
+
+    def exit_logits(self, token_ids: Array) -> Tuple[Array, Array]:
+        """A looped stack's every exit: float32 logits (R, B, T, vocab) and
+        the exit probabilities ``p_t`` (R, B, T)."""
+        hs, _, _, _ = self.hidden_states(token_ids)
+        kernel = self._head()
+        return (jnp.stack([self._logits(h, kernel) for h in hs]),
+                jnp.exp(self._exit_log_probs(hs)))
+
+    def _looped_loss(self, token_ids: Array, pad_mask: Optional[Array]) -> Tuple[Array, dict]:
+        """The expected-exit loss less the entropy term, and the step's
+        metrics: each exit's mean CE (``loss_ut_<t>``) and the mean exit step
+        ``ut_expected_exit``."""
+        c = self.config
+        hs, _, _, kept = self.hidden_states(token_ids)
+        labels = next_token_labels(token_ids, pad_mask, 1)
+        valid = labels != IGNORE_LABEL
+        safe = jnp.where(valid, labels, 0)
+        count = jnp.maximum(jnp.sum(valid, dtype=jnp.float32), 1.0)
+
+        @jax.checkpoint
+        def exit_ce(kernel, h):  # one exit's per-token CE, its logits never kept
+            return kernel, softmax_ce_integer(self._logits(h, kernel), safe)
+
+        with jax.named_scope("ut_exits"):
+            log_p = self._exit_log_probs(hs)
+            _, ce = jax.lax.scan(exit_ce, self._head(), hs)
+            p = jnp.exp(log_p)
+            per_token = jnp.sum(p * ce, axis=0) + c.exit_entropy_beta * jnp.sum(p * log_p, axis=0)
+
+            def mean(x):
+                return jnp.sum(jnp.where(valid, x, 0.0)) / count
+
+            steps = jnp.arange(1, c.total_ut_steps + 1, dtype=jnp.float32)[:, None, None]
+            metrics = {f"loss_ut_{t + 1}": mean(ce[t]) for t in range(c.total_ut_steps)}
+            metrics["ut_expected_exit"] = mean(jnp.sum(steps * p, axis=0))
+            metrics["attention_residuals_kept_pct"] = jnp.float32(100.0 if kept else 0.0)
+            return mean(per_token), metrics
+
     def loss(self, token_ids: Array, pad_mask: Optional[Array] = None) -> Tuple[Array, dict]:
-        """The two-term loss and the step's metrics (float32 scalars)."""
+        """The two-term loss and the step's metrics (float32 scalars); a
+        looped stack's is the expected-exit loss (``_looped_loss``)."""
+        if self.config.total_ut_steps:
+            return self._looped_loss(token_ids, pad_mask)
         h, h_mtp, stats, kept = self.hidden_states(token_ids)
         kernel = self._head()
 

@@ -193,6 +193,13 @@ CASES = {
     "attn-sdar-block-diffusion-grad": lambda: _attention(
         1, 16384, 16384, 32, 128, blocks=_gqa16_blocks(), kv_heads=4, block_diffusion=4,
         grad=True),
+    # the ouro cell's multi-head attention (1 row x 4096, 16 query heads over
+    # 16 key/value heads of 128: a group of 1), run 32 times a step by the loop
+    "attn-ouro-mha-causal-fwd": lambda: _attention(
+        1, 4096, 4096, 16, 128, causal_offset=0, blocks=_gqa16_blocks(), kv_heads=16),
+    "attn-ouro-mha-causal-grad": lambda: _attention(
+        1, 4096, 4096, 16, 128, causal_offset=0, blocks=_gqa16_blocks(), kv_heads=16,
+        grad=True),
     "gmm-sdar-experts-gate-grad": lambda: _grouped_matmul(
         16384, 8, 16, 2048, 768, grad=True, of_experts=128),
     "gmm-sdar-experts-down-grad-worst-case": lambda: _grouped_matmul(
